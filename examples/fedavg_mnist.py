@@ -88,6 +88,10 @@ def main():
         return
     import multiprocessing as mp
 
+    # Every party on this one host: a chip belongs to one process at a
+    # time, so the local demonstration runs on the CPU.  (On the chip:
+    # `python chip_smoke.py`; one party per host: pass the party name.)
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ctx = mp.get_context("spawn")
     procs = [ctx.Process(target=run, args=(p,)) for p in ("alice", "bob")]
     for p in procs:

@@ -1,14 +1,15 @@
 """FedAvg between MESH parties (BASELINE config #3's program shape).
 
-Each party is a multi-device mesh (8 virtual CPU devices stand in for a
-pod slice): its model is fsdp-sharded over the party mesh, contributions
-cross the wire shard-streamed, land on the peer's mesh via the sender's
-sharding description (`resolve_sharding` — per-shard device_put, no host
-re-assembly), and the round average runs as jitted sharded tree
-arithmetic.  The cross-party hop is the only "DCN" traffic; everything
-inside a party rides the mesh.
+Each party is a multi-device mesh: its model is fsdp-sharded over the
+party mesh, contributions cross the wire shard-streamed, land on the
+peer's mesh via the sender's sharding description (`resolve_sharding` —
+per-shard device_put, no host re-assembly), and the round average runs
+as jitted sharded tree arithmetic.  The cross-party hop is the only
+"DCN" traffic; everything inside a party rides the mesh.
 
-Run both parties in one go (spawns two processes):
+This file is the CPU / multi-host demonstration: one OS process per
+party, 8 virtual CPU devices standing in for a pod slice.  Run both
+parties in one go (spawns two processes):
 
     python examples/mesh_fedavg.py
 
@@ -16,6 +17,11 @@ or one party per terminal:
 
     python examples/mesh_fedavg.py alice
     python examples/mesh_fedavg.py bob
+
+A chip belongs to one process at a time, so on a machine with chips the
+same program (:func:`fedavg_rounds`) runs with both parties as threads
+of one process, each on its own two-chip mesh: ``python chip_smoke.py
+--chips 4``.
 """
 
 import os
@@ -27,15 +33,26 @@ CLUSTER = {
     "alice": {"address": "127.0.0.1:12040"},
     "bob": {"address": "127.0.0.1:12041"},
 }
+PARTIES = tuple(CLUSTER)
 
 ROUNDS = 3
 ROWS, COLS = 2048, 1024  # 8.4 MB f32 leaf — rides the wire per shard
 
 
-def run(party: str, rounds: int = ROUNDS) -> float:
-    from rayfed_tpu.utils import force_cpu_devices
+def make_train_step(delta: float):
+    """The party-local step: sharding-preserving (inputs sharded over
+    ``fsdp`` stay sharded — no gather)."""
+    import jax
 
-    force_cpu_devices(8)
+    return jax.jit(
+        lambda p: jax.tree_util.tree_map(lambda x: x + delta, p)
+    )
+
+
+def fedavg_rounds(party: str, rounds: int = ROUNDS):
+    """The federated program, between ``fed.init`` and ``fed.shutdown``:
+    ``rounds`` of FedAvg over the calling party's mesh.  Returns the
+    final params (sharded over that mesh)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -44,9 +61,6 @@ def run(party: str, rounds: int = ROUNDS) -> float:
     from rayfed_tpu.api import get_runtime
     from rayfed_tpu.fl import aggregate
 
-    fed.init(
-        address="local", cluster=CLUSTER, party=party, mesh_shape={"fsdp": 8}
-    )
     mesh = get_runtime().mesh
 
     @fed.remote
@@ -54,19 +68,19 @@ def run(party: str, rounds: int = ROUNDS) -> float:
         """Party-pinned trainer; params stay sharded on the party mesh."""
 
         def __init__(self, delta: float):
-            self._delta = delta
-            self._step = jax.jit(
-                lambda p: jax.tree_util.tree_map(lambda x: x + self._delta, p)
-            )
+            self._step = make_train_step(delta)
 
         def train(self, params):
             # The incoming tree landed sharded over THIS party's mesh.
-            assert len(params["w"].addressable_shards) == 8
+            mine = set(get_runtime().mesh.devices.flat)
+            assert params["w"].sharding.device_set == mine, (
+                params["w"].sharding, mine
+            )
             return self._step(params)
 
     trainers = {
         p: Trainer.party(p).remote(float(i + 1))
-        for i, p in enumerate(("alice", "bob"))
+        for i, p in enumerate(PARTIES)
     }
 
     w = jnp.zeros((ROWS, COLS), jnp.float32)
@@ -78,14 +92,27 @@ def run(party: str, rounds: int = ROUNDS) -> float:
 
     got = float(jnp.mean(params["w"]))
     expected = 1.5 * rounds
-    assert abs(got - expected) < 1e-4, (got, expected)
+    assert abs(got - expected) < 1e-4, (party, got, expected)
+    return params
+
+
+def run(party: str, rounds: int = ROUNDS) -> None:
+    from rayfed_tpu.utils import force_cpu_devices
+
+    force_cpu_devices(8)
+
+    import rayfed_tpu as fed
+
+    fed.init(
+        address="local", cluster=CLUSTER, party=party, mesh_shape={"fsdp": 8}
+    )
+    params = fedavg_rounds(party, rounds)
     print(
-        f"[{party}] {rounds} mesh-party rounds ok: mean={got:.2f}, "
-        f"result sharded {params['w'].sharding.spec} over {mesh.shape}",
+        f"[{party}] {rounds} mesh-party rounds ok: result sharded "
+        f"{params['w'].sharding.spec} over {params['w'].sharding.mesh.shape}",
         flush=True,
     )
     fed.shutdown()
-    return got
 
 
 def main():

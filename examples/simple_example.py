@@ -73,6 +73,10 @@ def run(party):
 
 
 def main():
+    # Every party on this one host: a chip belongs to one process at a
+    # time, so the local demonstration runs on the CPU.  (On the chip:
+    # `python chip_smoke.py`; one party per host: pass the party name.)
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     procs = [
         multiprocessing.get_context("spawn").Process(target=run, args=(p,))
         for p in ("alice", "bob")

@@ -58,7 +58,7 @@ class FedActorHandle:
                 self._body,
                 cls_args,
                 cls_kwargs,
-                bind_runtime_fn=self._runtime._bind_to_current_thread,
+                bind_runtime_fn=self._runtime.bind_thread,
                 name=f"{self._body.__name__}-{self._fed_class_task_id}",
             )
             self._runtime.register_actor(self._actor_instance)

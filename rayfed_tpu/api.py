@@ -13,6 +13,7 @@ from __future__ import annotations
 import functools
 import inspect
 import logging
+import threading
 import time
 from typing import Any, Dict, List, Optional, Union
 
@@ -31,14 +32,19 @@ from rayfed_tpu.executor import LocalRef, is_local_refs
 from rayfed_tpu.fed_object import FedObject
 from rayfed_tpu.runtime import (
     Runtime,
+    clear_current_runtime,
     get_runtime,
     get_runtime_or_none,
     set_current_runtime,
 )
 from rayfed_tpu.transport.manager import TransportManager
-from rayfed_tpu.utils.logging_utils import set_thread_party, setup_logger
+from rayfed_tpu.utils.logging_utils import setup_logger
 
 logger = logging.getLogger(__name__)
+
+# fed.init installs process-wide state (chaos schedule, flight recorder,
+# root logger); in-process parties call it from concurrent threads.
+_init_lock = threading.Lock()
 
 
 def init(
@@ -96,7 +102,13 @@ def init(
       raises :class:`~rayfed_tpu.exceptions.RemoteError` naming the dead
       party instead of waiting out the recv backstop;
     - ``process_default``: also register this runtime as the process-wide
-      default (disable when simulating multiple parties in one process);
+      default.  Pass ``False`` when several parties share one process
+      (one thread per party — :mod:`rayfed_tpu.inprocess`; the only way
+      several parties can compute on one chip, which belongs to one
+      process at a time): each party's runtime is then reachable only
+      from the threads bound to it, and the process-wide flight
+      recorder and logger attribute by record instead of adopting one
+      party's name;
     - ``coordinator_address`` + ``num_party_processes`` +
       ``party_process_id``: this party spans several JAX processes (a
       multi-host pod slice).  ``jax.distributed`` is initialized so the
@@ -107,21 +119,6 @@ def init(
     assert cluster, "Cluster should be provided."
     assert party, "Party should be provided."
     assert party in cluster, f"Party {party} is not in cluster {cluster}."
-
-    # Deterministic fault injection (tests/benches): a JSON schedule in
-    # $RAYFED_CHAOS arms the transport/driver chaos hooks for this
-    # process.  A no-op unless the variable is set.
-    from rayfed_tpu import chaos as _chaos
-
-    _chaos.maybe_install_from_env()
-
-    # Flight recorder (rayfed_tpu/telemetry.py): RAYFED_TRACE=1 arms the
-    # span ring like RAYFED_CHAOS arms faults; an env-armed (or
-    # pre-armed) recorder without a party adopts this one.  The
-    # JobConfig knob arms it below, once job_config exists.
-    from rayfed_tpu import telemetry as _telemetry
-
-    _telemetry.maybe_install_from_env(party=party)
 
     fed_utils.validate_address(address)
     fed_utils.validate_cluster_info(cluster)
@@ -169,13 +166,33 @@ def init(
         job_config.trace = bool(trace)
     if trace_capacity is not None:
         job_config.trace_capacity = int(trace_capacity)
-    if job_config.trace and _telemetry.installed() is None:
-        _telemetry.install(party=party, capacity=job_config.trace_capacity)
-    elif trace_capacity is not None and _telemetry.installed() is not None:
-        # An env-armed (or test-installed) recorder already exists; an
-        # EXPLICIT capacity request must still take effect — resize in
-        # place (newest records kept) instead of silently ignoring it.
-        _telemetry.installed().resize(int(trace_capacity))
+    from rayfed_tpu import chaos as _chaos
+    from rayfed_tpu import telemetry as _telemetry
+
+    # With several parties in this process no single one owns the
+    # process-wide recorder/logger: they attribute per record (every
+    # span carries its party, every bound thread its log tag).
+    owner = party if process_default else None
+    with _init_lock:
+        # Deterministic fault injection (tests/benches): a JSON schedule
+        # in $RAYFED_CHAOS arms the transport/driver chaos hooks for
+        # this process.  A no-op unless the variable is set.
+        _chaos.maybe_install_from_env()
+        # Flight recorder (rayfed_tpu/telemetry.py): RAYFED_TRACE=1 arms
+        # the span ring like RAYFED_CHAOS arms faults; an env-armed (or
+        # pre-armed) recorder without a party adopts the owner.
+        _telemetry.maybe_install_from_env(party=owner)
+        if job_config.trace and _telemetry.installed() is None:
+            _telemetry.install(
+                party=owner, capacity=job_config.trace_capacity
+            )
+        elif trace_capacity is not None and _telemetry.installed() is not None:
+            # An env-armed (or test-installed) recorder already exists;
+            # an EXPLICIT capacity request must still take effect —
+            # resize in place (newest records kept) instead of silently
+            # ignoring it.
+            _telemetry.installed().resize(int(trace_capacity))
+        setup_logger(logging_level=logging_level, party=owner)
 
     party_group = None
     if coordinator_address is not None:
@@ -196,6 +213,10 @@ def init(
         from rayfed_tpu.parallel.mesh import create_mesh
 
         mesh = create_mesh(mesh_shape)
+    if mesh is not None:
+        from rayfed_tpu.utils.platform import guard_subslice_mesh
+
+        guard_subslice_mesh(mesh)
 
     runtime = Runtime(
         cluster_config=cluster_config,
@@ -204,9 +225,6 @@ def init(
         mesh=mesh,
     )
     set_current_runtime(runtime, process_default=process_default)
-    set_thread_party(party)
-
-    setup_logger(logging_level=logging_level, party=party)
 
     runtime.cleanup_manager = CleanupManager(
         exit_on_failure_sending=exit_on_failure_cross_silo_sending
@@ -220,6 +238,7 @@ def init(
         if party_group.is_leader:
             inner = TransportManager(cluster_config, job_config)
             inner.mesh_provider = lambda: runtime.mesh
+            inner.bind_thread = runtime.bind_thread
             # NOT started here: MultiHostTransport must install its
             # republish hook before the listener accepts the first frame.
         transport = MultiHostTransport(
@@ -247,6 +266,7 @@ def init(
     else:
         transport = TransportManager(cluster_config, job_config)
         transport.mesh_provider = lambda: runtime.mesh
+        transport.bind_thread = runtime.bind_thread
         transport.start()
     runtime.send_proxy = transport
     runtime.recv_proxy = transport
@@ -477,8 +497,7 @@ def shutdown() -> None:
         runtime.transport.stop()
     runtime.shutdown_actors()
     runtime.executor.shutdown(wait=False)
-    set_current_runtime(None)
-    set_thread_party(None)
+    clear_current_runtime(runtime)
     logger.info("Shutdowned rayfed_tpu.")
 
 

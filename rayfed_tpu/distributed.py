@@ -98,7 +98,7 @@ class PartyProcessGroup:
             raise RuntimeError(
                 "rayfed_tpu's multi-host control bridge uses the private "
                 "jax._src.distributed.global_state.client API (verified on "
-                "jax 0.4.30-0.9.x); this JAX build "
+                "jax 0.9.0); this JAX build "
                 f"({jax.__version__}) no longer exposes it — pin a tested "
                 "JAX or port PartyProcessGroup to the replacement API"
             ) from e
@@ -156,7 +156,7 @@ class PartyProcessGroup:
             return
         try:
             self._client.key_value_delete(f"{_BRIDGE_PREFIX}_addr")
-        except Exception:  # pragma: no cover - older jax w/o dir delete
+        except Exception:  # pragma: no cover - the service may be gone
             logger.debug("bridge key cleanup not supported", exc_info=True)
 
     def shutdown(self) -> None:

@@ -505,11 +505,7 @@ class _RawStripeAggregator(StripeAggregator):
     def _finalize(self):
         # The donated accumulator holds Σ w_p·widen(q_p) on the padded
         # block grid; trim the pad, keep the exact integers.
-        import jax
-
-        acc = self._acc
-        jax.block_until_ready(acc)
-        return np.asarray(acc)[: self._total_elems]
+        return np.asarray(self._acc)[: self._total_elems]
 
 
 from rayfed_tpu.fl.streaming import StreamingAggregator  # noqa: E402
@@ -525,18 +521,13 @@ class _RegionHubAggregator(StreamingAggregator):
     up (:attr:`RegionSumTree.arrived_w`)."""
 
     def _finalize(self):
-        import jax
-
         members = (
             self._participating
             if self._participating is not None
             else list(range(self._n))
         )
         self._verify_quant_members(members)
-        acc = self._acc
-        if not self._np_fold:  # pragma: no cover - cpu benches use numpy
-            jax.block_until_ready(acc)
-        return np.asarray(acc)[: self._total_elems]
+        return np.asarray(self._acc)[: self._total_elems]
 
 
 class _NodeAggregator(StreamingAggregator):
@@ -562,8 +553,6 @@ class _NodeAggregator(StreamingAggregator):
         )
 
     def _finalize(self):
-        import jax
-
         members = self._fold_members()
         self._verify_quant_members(members)
         arrived = 0
@@ -583,10 +572,7 @@ class _NodeAggregator(StreamingAggregator):
             # the headroom bound (checked at grid construction).
             self._total_w = float(arrived)
             return super()._finalize()
-        acc = self._acc
-        if not self._np_fold:  # pragma: no cover - cpu benches use numpy
-            jax.block_until_ready(acc)
-        return np.asarray(acc)[: self._total_elems]
+        return np.asarray(self._acc)[: self._total_elems]
 
 
 # Stripe geometry (compaction + short-tail arithmetic) is the SAME

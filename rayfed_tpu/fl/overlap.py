@@ -540,7 +540,7 @@ class PipelinedRoundRunner:
             round_base = _np.asarray(pack_tree(params, jnp.float32).buf)
         lane = CommsLane(
             name=f"rayfed-comms-{me}",
-            bind_runtime_fn=runtime._bind_to_current_thread,
+            bind_runtime_fn=runtime.bind_thread,
         )
         try:
             inputs: Dict[str, Any] = {p: outgoing for p in parties}

@@ -687,13 +687,19 @@ class RoundCodec:
     unquantized path needs no branches at call sites).
     """
 
-    __slots__ = ("grid", "ref", "descriptor", "_scope")
+    __slots__ = ("grid", "ref", "descriptor", "_comp")
 
     def __init__(self, grid: Optional[QuantGrid],
                  ref: Optional[Any] = None,
                  scope: Optional[str] = None) -> None:
         self.grid = grid
-        self._scope = scope
+        # Resolved HERE, on the constructing (driver) thread: to_wire
+        # may run on whichever thread resolves the update, and all three
+        # phases must reach the same party's error-feedback state.
+        self._comp = (
+            compressor(scope)
+            if grid is not None and scope is not None else None
+        )
         self.ref: Optional[np.ndarray] = None
         self.descriptor: Optional[Dict[str, Any]] = None
         if grid is not None:
@@ -723,19 +729,17 @@ class RoundCodec:
                 "compressed-domain aggregation consumes PackedTree "
                 f"contributions, got {type(value).__name__}"
             )
-        if self._scope is not None:
-            return compressor(self._scope).quantize(
-                value, self.grid, ref=self.ref
-            )
+        if self._comp is not None:
+            return self._comp.quantize(value, self.grid, ref=self.ref)
         return quantize_packed(value, self.grid, ref=self.ref)
 
     def commit(self) -> None:
-        if self.grid is not None and self._scope is not None:
-            compressor(self._scope).commit()
+        if self._comp is not None:
+            self._comp.commit()
 
     def rollback(self) -> None:
-        if self.grid is not None and self._scope is not None:
-            compressor(self._scope).rollback()
+        if self._comp is not None:
+            self._comp.rollback()
 
 
 def quantize_downlink(
@@ -788,22 +792,30 @@ def quantize_downlink(
     return wire_result, decoded, grid_descriptor(down_grid)
 
 
-# Per-process compressor registry, keyed by stream scope (one EF state
-# per outgoing quantized stream, like the delta caches' stream keying).
+# Compressor registry, keyed by stream scope (one EF state per outgoing
+# quantized stream, like the delta caches' stream keying).  The state
+# is per SENDER: it lives on the calling party's Runtime, so several
+# parties in one process (rayfed_tpu.inprocess) never fold one
+# another's residuals; code running with no runtime (unit tests of the
+# codec) shares this module-level registry.
 _COMPRESSORS: Dict[str, QuantCompressor] = {}
 
 
+def _registry() -> Dict[str, QuantCompressor]:
+    from rayfed_tpu.runtime import get_runtime_or_none
+
+    runtime = get_runtime_or_none()
+    return _COMPRESSORS if runtime is None else runtime.quant_compressors
+
+
 def compressor(scope: str) -> QuantCompressor:
-    """The process-wide :class:`QuantCompressor` for ``scope`` (created
-    on first use).  Scope by stream name, e.g. ``"fedavg"`` for the
-    round loop's uplink and ``"fedavg/down"`` for the coordinator's
+    """The calling party's :class:`QuantCompressor` for ``scope``
+    (created on first use).  Scope by stream name, e.g. ``"fedavg"`` for
+    the round loop's uplink and ``"fedavg/down"`` for the coordinator's
     broadcast."""
-    comp = _COMPRESSORS.get(scope)
-    if comp is None:
-        comp = _COMPRESSORS[scope] = QuantCompressor()
-    return comp
+    return _registry().setdefault(scope, QuantCompressor())
 
 
 def reset_compressors() -> None:
     """Drop every registered compressor's state (tests / model swap)."""
-    _COMPRESSORS.clear()
+    _registry().clear()

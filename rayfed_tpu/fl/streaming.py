@@ -183,6 +183,12 @@ class StreamingAggregator:
         # served by EVERY manager's trace window and the merged
         # timeline would duplicate it under each party's clock offset.
         self._party = None if party is None else str(party)
+        # The fold worker allocates the accumulator and dispatches the
+        # fold/finalize kernels: it runs bound to the constructing
+        # party's runtime so they land on THAT party's device.
+        from rayfed_tpu.runtime import get_runtime_or_none
+
+        self._runtime = get_runtime_or_none()
         if quorum is not None and not 1 <= int(quorum) <= n_sources:
             raise ValueError(
                 f"quorum must be in [1, {n_sources}], got {quorum}"
@@ -945,6 +951,8 @@ class StreamingAggregator:
 
     def _run(self) -> None:
         try:
+            if self._runtime is not None:
+                self._runtime.bind_thread()
             self._run_inner()
         # fedlint: disable=FED004 — transferred, not swallowed: fail(e) poisons every result waiter; this is the aggregator's dedicated worker thread, not the driver
         except BaseException as e:  # pragma: no cover - defensive
@@ -1132,6 +1140,16 @@ class StreamingAggregator:
                     detail={
                         "busy_ms": round(self._busy_s * 1e3, 3),
                         "parties": len(self._streams),
+                        # What was folded and where: the wire dtype,
+                        # host numpy slice-adds (the CPU backend's
+                        # integer fold) or the jitted accumulate kernel,
+                        # and the devices holding the accumulator.
+                        "codes": str(self._wire_dtype),
+                        "fold": "numpy" if self._np_fold else "jit",
+                        "devices": (
+                            [] if self._np_fold
+                            else sorted(d.id for d in self._acc.devices())
+                        ),
                     },
                 )
             _tr.emit(

@@ -908,9 +908,8 @@ def make_train_loop(
     """N full-param Adam steps in ONE compiled program (lax.scan).
 
     (params, opt, ids) → (params, opt, losses[num_steps]).  One dispatch
-    covers all N steps — on hosts where the accelerator sits behind a
-    high-latency link, per-call dispatch would otherwise dominate and
-    make wall-clock throughput unmeasurable.
+    covers all N steps, which keeps per-call dispatch out of a
+    throughput measurement.
     """
 
     def loss_fn(params, ids):

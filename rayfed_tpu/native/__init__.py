@@ -1,7 +1,7 @@
 """ctypes bindings for the native (C++) wire data plane.
 
-Builds ``libwirecodec.so`` from :file:`wirecodec.cc` on first use if
-missing (g++, ~1s) and exposes:
+Builds ``libwirecodec-<digest>.so`` from :file:`wirecodec.cc` on first
+use if missing (g++, ~1s) and exposes:
 
 - :func:`crc32c` — CRC32-C checksum (slicing-by-8 in C++, GIL released)
 - :func:`gather_copy` — assemble many buffers into one ``bytearray``,
@@ -12,7 +12,15 @@ missing (g++, ~1s) and exposes:
   the event loop
 - :func:`is_available` — False when no toolchain; every consumer keeps a
   pure-Python fallback (the transport works without native code, just
-  slower on multi-MB payloads).
+  slower on multi-MB payloads).  The decision is logged once at
+  ``warning`` and kept in :func:`status`.
+
+The library is built for a FIXED ISA (x86-64 + SSE4.2, what the
+hardware CRC32-C path needs), never ``-march=native``: a checkout's
+files are copied between machines, and a library tuned to the CPU that
+built it can die with SIGILL on the one that loads it.  The artefact's
+name carries a digest of the source and the build flags, so a library
+built from other source or with other flags is never picked up.
 
 The reference's native layer is third-party (gRPC C-core, Ray core —
 SURVEY §2.9); ours is first-party and scoped to the byte hot path.
@@ -21,9 +29,11 @@ SURVEY §2.9); ours is first-party and scoped to the byte hot path.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
+import tempfile
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -31,25 +41,71 @@ logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "wirecodec.cc")
-_LIB = os.path.join(_HERE, "libwirecodec.so")
+_BASE_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17"]
+# Tried in order: hardware CRC32-C where the compiler targets x86,
+# else the generic build (the source's slicing-by-8 path).
+_ISA_FLAGS = (["-msse4.2"], [])
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+_status = "not loaded"
 _build_lock = threading.Lock()
 
 
-def _build() -> bool:
-    base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _LIB + ".tmp"]
-    # Prefer the host ISA (hardware CRC32-C on x86); fall back to generic.
-    for extra in (["-march=native"], []):
-        cmd = base[:2] + extra + base[2:]
+def _lib_path(isa: List[str]) -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + " ".join(_BASE_FLAGS + isa).encode()
+        ).hexdigest()[:12]
+    return os.path.join(_HERE, f"libwirecodec-{digest}.so")
+
+
+def _build(isa: List[str], out: str) -> Optional[str]:
+    """Compile to a private temp file, then rename into place: parties
+    starting together on a fresh checkout each build their own and the
+    last rename wins (the files are identical).  Returns the failure."""
+    fd, tmp = tempfile.mkstemp(dir=_HERE, suffix=".so.tmp")
+    os.close(fd)
+    try:
+        subprocess.run(
+            ["g++", *_BASE_FLAGS, *isa, _SRC, "-o", tmp],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, out)
+        return None
+    except (OSError, subprocess.SubprocessError) as e:
+        return repr(e)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _open() -> Optional[ctypes.CDLL]:
+    global _status
+    failures = []
+    for isa in _ISA_FLAGS:
+        path = _lib_path(isa)
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(_LIB + ".tmp", _LIB)
-            return True
-        except (OSError, subprocess.SubprocessError) as e:
-            logger.debug("native build %s failed: %s", extra, e)
-    return False
+            if not os.path.exists(path):
+                err = _build(isa, path)
+                if err is not None:
+                    failures.append(f"g++ {' '.join(isa)}: {err}")
+                    continue
+                how = "built"
+            else:
+                how = "found"
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            failures.append(f"{os.path.basename(path)}: {e!r}")
+            continue
+        _status = f"native ({how} {os.path.basename(path)})"
+        return lib
+    _status = "pure-python (" + "; ".join(failures)[:300] + ")"
+    logger.warning(
+        "native wire codec unavailable — the transport runs on its "
+        "pure-Python byte path (slower on multi-MB payloads): %s", _status,
+    )
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -60,15 +116,8 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        try:
-            stale = not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            )
-            if stale and not _build():
-                return None
-            lib = ctypes.CDLL(_LIB)
-        except OSError as e:
-            logger.debug("native wirecodec unavailable: %s", e)
+        lib = _open()
+        if lib is None:
             return None
         lib.rf_crc32c.restype = ctypes.c_uint32
         lib.rf_crc32c.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_uint64]
@@ -101,6 +150,13 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def is_available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """How the byte path was decided: ``native (built|found <file>)`` or
+    ``pure-python (<why>)``."""
+    _load()
+    return _status
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,10 @@
 // callable from Python via ctypes with the GIL released, so the asyncio
 // loop and codec threads never serialize on big memcpys.
 //
-// Build: g++ -O3 -march=native -shared -fPIC wirecodec.cc -o libwirecodec.so
-// (see build.py; pure-Python fallbacks exist for every entry point).
+// Built on first use by rayfed_tpu/native/__init__.py (g++ -O3 -msse4.2
+// -shared -fPIC: a fixed ISA, never -march=native, because checkouts
+// are copied between machines; pure-Python fallbacks exist for every
+// entry point).
 
 #include <cstdint>
 #include <cstring>

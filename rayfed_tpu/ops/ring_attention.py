@@ -39,8 +39,6 @@ import functools
 from typing import Optional
 
 import jax
-
-from rayfed_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -58,8 +56,8 @@ from rayfed_tpu.ops.flash_attention import (
     _fit_block,
     _flash_backward_pallas,
     _flash_forward,
+    _interpret_default,
     _lse_delta_lanes,
-    _on_tpu,
 )
 
 
@@ -302,7 +300,7 @@ def ring_flash_attention(
     :func:`rayfed_tpu.ops.flash_attention.flash_attention`.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     t_local = q.shape[1]
     block_q = _fit_block(t_local, block_q)
@@ -542,7 +540,7 @@ def zigzag_ring_flash_attention(
     ring has no imbalance to fix).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     if q.shape[1] % 2:
         raise ValueError(
             f"zigzag shards hold a (low, high) chunk pair — T_local "
@@ -598,7 +596,7 @@ def make_ring_attention(
     ``seq_axis`` (T must divide evenly).  Batch stays replicated here;
     compose with dp by vmapping/sharding outside.  ``use_flash=True``
     runs the Pallas flash kernel per ring step (the TPU-fast path;
-    interpreted off-TPU so the CPU test mesh exercises it too).
+    interpreted on the CPU backend so the CPU test mesh exercises it too).
 
     ``layout="zigzag"`` (requires ``causal=True, use_flash=True``)
     balances the causal triangle across devices — each shard holds
@@ -617,7 +615,7 @@ def make_ring_attention(
                 "(a non-causal ring has no imbalance to fix)"
             )
         n_shards = mesh.shape[seq_axis]
-        sharded = shard_map(
+        sharded = jax.shard_map(
             functools.partial(
                 zigzag_ring_flash_attention,
                 axis_name=seq_axis,
@@ -656,7 +654,7 @@ def make_ring_attention(
         fn = functools.partial(
             ring_attention, axis_name=seq_axis, causal=causal, sm_scale=sm_scale
         )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

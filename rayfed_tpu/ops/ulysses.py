@@ -20,8 +20,6 @@ import functools
 from typing import Optional
 
 import jax
-
-from rayfed_tpu.utils.jax_compat import shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -81,7 +79,7 @@ def make_ulysses_attention(
         sm_scale=sm_scale,
         attn_fn=attn_fn,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -24,8 +24,6 @@ import functools
 from typing import Any, Callable
 
 import jax
-
-from rayfed_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -96,7 +94,7 @@ def make_pipeline(
     collective = functools.partial(
         pipeline_collective, stage_fn=stage_fn, axis_name=axis_name
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         collective,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
@@ -459,7 +457,7 @@ def make_pipeline_train(
             axis_name=axis_name,
             num_chunks=v,
         )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         collective,
         mesh=mesh,
         in_specs=(P(axis_name), P(), P()),

@@ -15,8 +15,6 @@ import re
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
-
-from rayfed_tpu.utils.jax_compat import set_mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rayfed_tpu import tree_util
@@ -141,7 +139,7 @@ class ShardingStrategy:
         jitted = jax.jit(step_fn, donate_argnums=donate_argnums, **jit_kwargs)
 
         def _call(*args, **kwargs):
-            with set_mesh(self.mesh):
+            with jax.sharding.set_mesh(self.mesh):
                 return jitted(*args, **kwargs)
 
         _call.lower = jitted.lower  # expose for AOT/compile checks

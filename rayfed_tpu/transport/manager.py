@@ -310,7 +310,8 @@ class TransportManager:
         self._clients: Dict[str, TransportClient] = {}
         self._clients_lock = threading.Lock()
         self._codec_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=4, thread_name_prefix=f"rayfed-codec-{self._party}"
+            max_workers=4, thread_name_prefix=f"rayfed-codec-{self._party}",
+            initializer=self._bind_pool_thread,
         )
         self.stats: Dict[str, Any] = {
             "send_op_count": 0,
@@ -389,6 +390,15 @@ class TransportManager:
         # shard-encoded leaves whose sender sharding fits this mesh are
         # device_put with the equivalent local NamedSharding.
         self.mesh_provider = None
+        # Set by api.init: Runtime.bind_thread.  Codec/fetch pool workers
+        # decode received leaves onto devices, so they must run bound to
+        # the owning party (its default device) — pool threads start
+        # lazily, after api.init set this.
+        self.bind_thread = None
+
+    def _bind_pool_thread(self) -> None:
+        if self.bind_thread is not None:
+            self.bind_thread()
 
     # -- lifecycle ------------------------------------------------------------
 

@@ -241,6 +241,8 @@ class ObjectPlane:
                 self._fetch_pool = _futures.ThreadPoolExecutor(
                     max_workers=4,
                     thread_name_prefix=f"rayfed-blob-{self.party}",
+                    # Resolved handles decode onto the party's device.
+                    initializer=self._manager._bind_pool_thread,
                 )
             return self._fetch_pool
 

@@ -1410,8 +1410,19 @@ class TransportServer:
     async def stop(self) -> None:
         local.unregister_server(self._local_sid)
         self._local_sid = None
+        # Stop both listeners, then abort the established connections,
+        # and only then wait: Server.close() stops the LISTENER alone —
+        # established connections would linger half-open (a peer's
+        # in-flight send then waits out its full ACK deadline instead of
+        # seeing EOF and reconnecting) — and since Python 3.12
+        # wait_closed() does not return while any connection is open.
+        for server in (self._uds_server, self._server):
+            if server is not None:
+                server.close()
+        for proto in list(self._protocols):
+            proto._abort()
+        self._protocols.clear()
         if self._uds_server is not None:
-            self._uds_server.close()
             await self._uds_server.wait_closed()
             self._uds_server = None
         if self._uds_path is not None:
@@ -1423,13 +1434,5 @@ class TransportServer:
                 pass
             self._uds_path = None
         if self._server is not None:
-            self._server.close()
             await self._server.wait_closed()
             self._server = None
-        # Server.close() only stops the LISTENER; established
-        # connections would linger half-open (a peer's in-flight send
-        # then waits out its full ACK deadline instead of seeing EOF
-        # and reconnecting).  Abort them explicitly.
-        for proto in list(self._protocols):
-            proto._abort()
-        self._protocols.clear()

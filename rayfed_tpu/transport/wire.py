@@ -664,7 +664,10 @@ def decode_payload(
     ``allowed`` is the serializing allowlist (applied to every pickled
     sub-blob including the skeleton).  With ``device_put=True``, leaves
     that were device arrays on the sender are placed back onto local
-    devices (``device``: a Device or Sharding, defaults to JAX default).
+    devices (``device``: a Device or Sharding; None is the CALLING
+    THREAD's JAX default device — the transport's decode threads run
+    bound to their party, so that is the party's own chip, not
+    ``jax.devices()[0]``; see ``Runtime.bind_thread``).
     ``mesh``: the receiver's party mesh — shard-encoded leaves whose
     sender sharding fits it are device_put with the equivalent local
     NamedSharding (per-shard placement instead of replication).
@@ -719,7 +722,7 @@ def decode_payload(
             if spec.get("dev") and device_put:
                 # Zero-copy path: device_put copies host→HBM directly from
                 # the received buffer; no intermediate host materialization.
-                arr = jax.device_put(arr, device) if device is not None else jax.device_put(arr)
+                arr = jax.device_put(arr, device)
             elif not as_view:
                 # Host-array leaves must be writable (reference's pickle
                 # path returned writable arrays) and must not pin the whole
@@ -767,11 +770,7 @@ def decode_payload(
                 out = np.frombuffer(region, dtype=dtype).reshape(shape)
                 offset += total
                 if device_put:
-                    out = (
-                        jax.device_put(out, sharding)
-                        if sharding is not None
-                        else jax.device_put(out)
-                    )
+                    out = jax.device_put(out, sharding)
                 leaves.append(out)
             else:
                 out = np.empty(shape, dtype)
@@ -784,14 +783,8 @@ def decode_payload(
                     ).reshape(extents)
                     offset += n
                 if device_put:
-                    arr = (
-                        jax.device_put(out, sharding)
-                        if sharding is not None
-                        else jax.device_put(out)
-                    )
-                    leaves.append(arr)
-                else:
-                    leaves.append(out)
+                    out = jax.device_put(out, sharding)
+                leaves.append(out)
         elif kind == "pkl":
             n = spec["n"]
             leaves.append(serialization.loads(bytes(mv[offset : offset + n]), allowed))

@@ -3,8 +3,13 @@
 Mirrors the reference's dominant test pattern (SURVEY §4): simulate N
 parties as processes on one host, each running the same ``run(party, ...)``
 function, assert both exit 0.  Uses the ``spawn`` start method so each
-child gets a clean interpreter (safe with JAX/threads), and sets the CPU
-JAX environment before any heavy import.
+child gets a clean interpreter (safe with JAX/threads), and pins the child
+to the CPU platform before any backend initialization.
+
+Every child is CPU-only BY CONSTRUCTION: an accelerator chip belongs to
+one process at a time, so one-process-per-party cannot put party compute
+on a chip.  Parties that must share a chip run as threads of one process
+(``rayfed_tpu.inprocess``; ``tests/test_inprocess_parties.py``).
 """
 
 from __future__ import annotations
@@ -14,10 +19,7 @@ import os
 import socket
 from typing import Callable, Dict, Iterable, Optional, Sequence
 
-_CHILD_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-}
+_CHILD_ENV = {"JAX_PLATFORMS": "cpu"}
 
 
 def get_free_ports(n: int) -> list[int]:

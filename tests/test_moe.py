@@ -1,8 +1,6 @@
 """MoE layer: routing correctness + expert-parallel sharding."""
 
 import jax
-
-from rayfed_tpu.utils.jax_compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 
@@ -105,6 +103,6 @@ def test_moe_expert_parallel_sharding():
     sharded = jax.device_put(params, shardings)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
     expected = moe.apply_moe(params, x, cfg)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(lambda p, x: moe.apply_moe(p, x, cfg))(sharded, x)
     np.testing.assert_allclose(out, expected, atol=1e-5, rtol=1e-5)

@@ -1,0 +1,172 @@
+"""Compile the main path's device programs for the real chip — without one.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is DESCRIBED (``v5e:2x2``), not attached: what Mosaic or XLA
+would refuse on the chip (a mis-tiled Pallas block, too much VMEM, a
+program that does not fit or cannot be partitioned) is refused here, at
+no chip time.  Nothing runs, so these say nothing about results or
+speed — ``chip_smoke.py`` is the run.  Skipped where the topology cannot
+be described.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@functools.lru_cache(maxsize=None)
+def _topology():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this host
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run would warn
+    and recompile): keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _on_chip(tree, sharding=None):
+    """Shapes of ``tree`` placed on the described chip 0 (or ``sharding``)."""
+    sharding = sharding or SingleDeviceSharding(_topology().devices[0])
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _flash_fwd_bwd(t, d, heads=2, **kw):
+    from rayfed_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(
+            flash_attention(q, k, v, causal=True, interpret=False, **kw)
+            .astype(jnp.float32) ** 2
+        )
+
+    q = jax.ShapeDtypeStruct((1, t, heads, d), jnp.bfloat16)
+    q, k, v = _on_chip((q, q, q))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
+
+
+def _resnet18_bundle():
+    from rayfed_tpu.fl import compress
+    from rayfed_tpu.models import resnet
+
+    cfg = resnet.resnet18(num_classes=10)
+    bundle = jax.eval_shape(
+        lambda: compress(
+            resnet.init_resnet(jax.random.PRNGKey(0), cfg), packed=True
+        )
+    )
+    return cfg, bundle
+
+
+def _resnet18_fed_step():
+    from rayfed_tpu.models import resnet
+
+    cfg, bundle = _resnet18_bundle()
+    x = jax.ShapeDtypeStruct((32, 32, 32, 3), jnp.float32)
+    y = jax.ShapeDtypeStruct((32,), jnp.int32)
+    return resnet.make_fed_train_step(cfg, lr=0.05).lower(
+        *_on_chip((bundle, x, y))
+    )
+
+
+def _resnet18_grid():
+    from rayfed_tpu.fl.fedavg import packed_block_grid
+    from rayfed_tpu.fl.streaming import DEFAULT_CHUNK_ELEMS
+
+    _, bundle = _resnet18_bundle()
+    total = int(bundle.buf.size)
+    return total, DEFAULT_CHUNK_ELEMS, packed_block_grid(total)
+
+
+def _quantized_accum():
+    from rayfed_tpu.fl.fedavg import quantized_accum_kernel
+
+    _, chunk, nblocks = _resnet18_grid()
+    acc = jax.ShapeDtypeStruct((nblocks * chunk,), jnp.int32)
+    codes = jax.ShapeDtypeStruct((chunk,), jnp.uint8)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    return quantized_accum_kernel(chunk, "uint8").lower(
+        *_on_chip((acc, codes, scalar, scalar))
+    )
+
+
+def _quantized_finalize():
+    from rayfed_tpu.fl.fedavg import _quant_finalize_jit
+
+    total, chunk, nblocks = _resnet18_grid()
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    args = (
+        jax.ShapeDtypeStruct((nblocks * chunk,), jnp.int32),
+        f32((total,)), f32((nblocks,)), f32((nblocks,)), f32(()),
+    )
+    # finalize_packed_quantized's program for a delta-coded f32 round.
+    return _quant_finalize_jit(chunk, total, "float32", True).lower(
+        *_on_chip(args)
+    )
+
+
+def _mesh_fedavg_step():
+    from examples import mesh_fedavg
+
+    mesh = Mesh(np.asarray(_topology().devices).reshape(4), ("fsdp",))
+    w = jax.ShapeDtypeStruct(
+        (mesh_fedavg.ROWS, mesh_fedavg.COLS), jnp.float32
+    )
+    params = _on_chip({"w": w}, NamedSharding(mesh, P("fsdp", None)))
+    return mesh_fedavg.make_train_step(1.0).lower(params)
+
+
+# name -> (lowering, must the program contain a compiled Pallas kernel)
+CASES = {
+    # The chip_smoke.py Llama widths: T=2048, 128-wide heads.
+    "flash_t2048_d128": (lambda: _flash_fwd_bwd(2048, 128), True),
+    "flash_t4096_d64": (lambda: _flash_fwd_bwd(4096, 64), True),
+    "flash_t2048_d128_window1024": (
+        lambda: _flash_fwd_bwd(2048, 128, window=1024), True,
+    ),
+    "resnet18_fed_train_step": (_resnet18_fed_step, False),
+    "quantized_accum_kernel_resnet18": (_quantized_accum, False),
+    "finalize_packed_quantized_resnet18": (_quantized_finalize, False),
+    "mesh_fedavg_step_4chip_mesh": (_mesh_fedavg_step, False),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compiles_for_v5e(case):
+    lower, wants_kernel = CASES[case]
+    _topology()  # skip before any work where it cannot be described
+    lowered = lower()
+    compiled = lowered.compile()  # raises what the chip's compiler raises
+    assert ("tpu_custom_call" in compiled.as_text()) == wants_kernel
+    if case.startswith("mesh_fedavg"):
+        # Each of the four chips holds its quarter of the 8 MiB leaf.
+        out_bytes = compiled.memory_analysis().output_size_in_bytes
+        assert out_bytes == 2048 * 1024 * 4 // 4
