@@ -5758,18 +5758,11 @@ def main() -> None:
             wall_pr = sum(v[6] for v in res.values()) / len(res)
             extra["resnet_round_step_ms"] = round(step_ms, 1)
             extra["resnet_round_cpu_s_total"] = round(cpu_pr, 2)
-            extra["resnet_round_busy_frac"] = round(cpu_pr / wall_pr, 3)
             extra["resnet_round_step_wall_frac"] = round(
                 step_ms / 1e3 / wall_pr, 3
             )
             # Decompression cost of the wire bundle, measured directly
-            # (packed fast path vs per-leaf tree_map), and its share of
-            # the round.  resnet_decomp_step_frac previously recorded
-            # step-wall/round-wall (≈0.97 — dominated by training
-            # compute, not decompression); it now measures what its name
-            # says: the round fraction spent decompressing the wire
-            # form, with the old ratio kept as
-            # resnet_round_step_wall_frac.
+            # (packed fast path vs per-leaf tree_map).
             decomp_ms = sum(v[9] for v in res.values()) / len(res)
             decomp_perleaf_ms = sum(v[10] for v in res.values()) / len(res)
             extra["resnet_decomp_ms"] = round(decomp_ms, 2)
@@ -5777,9 +5770,6 @@ def main() -> None:
             extra["resnet_decomp_speedup"] = round(
                 decomp_perleaf_ms / decomp_ms, 3
             ) if decomp_ms > 0 else None
-            extra["resnet_decomp_step_frac"] = round(
-                decomp_ms / 1e3 / wall_pr, 3
-            )
             _log(
                 f"  resnet: {rps:.3f} rounds/s, goodput {xgbps:.3f} GB/s, "
                 f"wire-session {extra.get('cross_party_GBps')} GB/s; "
@@ -5787,8 +5777,7 @@ def main() -> None:
                 f"{coord[3]:.1f} ms per round; decomp packed "
                 f"{decomp_ms:.1f} ms vs per-leaf {decomp_perleaf_ms:.1f} "
                 f"ms; step {step_ms/1e3:.2f}s of {wall_pr:.2f}s wall "
-                f"({step_ms/1e3/wall_pr:.0%}), 4-party CPU {cpu_pr:.2f}s "
-                f"({cpu_pr/wall_pr:.0%} busy)"
+                f"({step_ms/1e3/wall_pr:.0%}), 4-party CPU {cpu_pr:.2f}s"
             )
             _settle()
 

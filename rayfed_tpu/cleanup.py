@@ -45,20 +45,27 @@ class CleanupManager:
     def _signal_exit(self) -> None:
         os.kill(os.getpid(), signal.SIGTERM)
 
+    def _check_one(self, item: LocalRef) -> bool:
+        """Wait for one tracked send; False once a failure has signalled
+        this process to exit."""
+        try:
+            res = item.resolve()
+        except Exception as e:
+            logger.warning("Failed to send %s with error: %s", item, e)
+            res = False
+        if not res and self._exit_on_failure:
+            logger.warning("Signal self to exit.")
+            self._signal_exit()
+            return False
+        return True
+
     def _check_sending_objs(self) -> None:
         while True:
             item = self._q.get()
             if item is _SENTINEL:
                 break
             assert isinstance(item, LocalRef)
-            try:
-                res = item.resolve()
-            except Exception as e:
-                logger.warning("Failed to send %s with error: %s", item, e)
-                res = False
-            if not res and self._exit_on_failure:
-                logger.warning("Signal self to exit.")
-                self._signal_exit()
+            if not self._check_one(item):
                 break
         logger.debug("Check sending thread exited.")
 
@@ -89,11 +96,28 @@ class CleanupManager:
         self._q.put(_SENTINEL)
 
     def wait_sending(self) -> None:
-        """Block until every tracked send completed (ref ``cleanup.py:115-119``)."""
+        """Block until every tracked send completed (ref ``cleanup.py:115-119``).
+
+        A send's result ref resolves only after its done-callback has
+        billed ``send_bytes`` (``TransportManager.send_many``), so on
+        return every send tracked before the call is counted: the
+        parties' ``send_bytes`` then sum to their peers'
+        ``receive_bytes``.  A ref pushed while the watchdog was already
+        draining lands behind its sentinel; it is waited for here, not
+        left for the next push to find.
+        """
         with self._lock:
             thread = self._check_thread
         if thread is not None and thread.is_alive():
             self.notify_to_exit()
             thread.join()
         with self._lock:
-            self._check_thread = None
+            if self._check_thread is thread:
+                self._check_thread = None
+        while True:
+            try:
+                item = self._q.get_nowait()
+            except queue.Empty:
+                return
+            if item is not _SENTINEL and not self._check_one(item):
+                return

@@ -28,7 +28,10 @@ from __future__ import annotations
 import concurrent.futures
 import logging
 import threading
+import time
 from typing import Any, Callable, Optional, Sequence
+
+from rayfed_tpu import telemetry
 
 logger = logging.getLogger(__name__)
 
@@ -159,6 +162,40 @@ def _materialize_arg(arg: Any) -> Any:
     return arg
 
 
+def _submit_mark() -> Optional[float]:
+    """``perf_counter`` at submit while the flight recorder is armed
+    (``task.wait``'s ``queue_ms`` counts from it), else ``None``."""
+    return None if telemetry.active() is None else time.perf_counter()
+
+
+def _resolve_args(
+    args: tuple, kwargs: dict, name: str, t_submit: Optional[float]
+) -> tuple:
+    """Materialize a task's top-level arguments on its worker thread.
+    ``task.wait`` is what the task waited for them (for a trainer, the
+    aggregate's arrival); ``queue_ms`` is submit to start."""
+    with telemetry.span("task.wait") as sp:
+        if sp is not None:
+            sp.detail = {"name": name}
+            if t_submit is not None:
+                sp.detail["queue_ms"] = round(
+                    (time.perf_counter() - t_submit) * 1e3, 3
+                )
+        return (
+            tuple(_materialize_arg(a) for a in args),
+            {k: _materialize_arg(v) for k, v in kwargs.items()},
+        )
+
+
+def _run_body(fn: Callable, args: tuple, kwargs: dict, name: str) -> Any:
+    """The task's own time (``task.run``): party compute, for a
+    trainer.  Spans the body opens on this thread name it as parent."""
+    with telemetry.span("task.run") as sp:
+        if sp is not None:
+            sp.detail = {"name": name}
+        return fn(*args, **kwargs)
+
+
 class TaskExecutor:
     """Thread-pool dispatch of party-local work.
 
@@ -199,6 +236,7 @@ class TaskExecutor:
         if self._shutdown:
             raise RuntimeError("TaskExecutor has been shut down")
         task_name = name or getattr(fn, "__name__", None) or repr(fn)
+        t_submit = _submit_mark()
 
         def _run():
             if self._bind_runtime_fn is not None:
@@ -207,11 +245,12 @@ class TaskExecutor:
             base_name = thread.name
             thread.name = f"{base_name}[{task_name}]"
             try:
-                resolved_args = tuple(_materialize_arg(a) for a in args)
-                resolved_kwargs = {
-                    k: _materialize_arg(v) for k, v in kwargs.items()
-                }
-                return fn(*resolved_args, **resolved_kwargs)
+                resolved_args, resolved_kwargs = _resolve_args(
+                    args, kwargs, task_name, t_submit
+                )
+                return _run_body(
+                    fn, resolved_args, resolved_kwargs, task_name
+                )
             except BaseException as e:
                 # The exception also travels to the LocalRef; this log
                 # line is the one place that pairs it with the task name.
@@ -353,6 +392,7 @@ class ActorInstance:
             max_workers=1, thread_name_prefix=f"rayfed-actor-{name}"
         )
         self._bind_runtime_fn = bind_runtime_fn
+        self._cls_name = getattr(cls, "__name__", name)
         self._instance: Any = None
         self._killed = False
         self._lock = threading.Lock()
@@ -377,18 +417,21 @@ class ActorInstance:
         with self._lock:
             if self._killed:
                 raise RuntimeError("actor has been killed")
+            task_name = f"{self._cls_name}.{method_name}"
+            t_submit = _submit_mark()
 
             def _run():
                 if self._bind_runtime_fn is not None:
                     self._bind_runtime_fn()
                 # Surface constructor failure on first method call.
                 self._ready_ref.resolve()
-                resolved_args = tuple(_materialize_arg(a) for a in args)
-                resolved_kwargs = {
-                    k: _materialize_arg(v) for k, v in kwargs.items()
-                }
+                resolved_args, resolved_kwargs = _resolve_args(
+                    args, kwargs, task_name, t_submit
+                )
                 method = getattr(self._instance, method_name)
-                return method(*resolved_args, **resolved_kwargs)
+                return _run_body(
+                    method, resolved_args, resolved_kwargs, task_name
+                )
 
             future = self._pool.submit(_run)
         if num_returns == 1:

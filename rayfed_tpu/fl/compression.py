@@ -73,6 +73,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from rayfed_tpu import telemetry
+
 
 def cast_floats(tree: Any, dtype) -> Any:
     """Cast every floating leaf to ``dtype`` (ints/bools untouched).
@@ -318,23 +320,26 @@ class ErrorFeedback:
 
     def compress(self, tree: Any) -> PackedTree:
         """Pack ``tree`` with error feedback; returns the wire PackedTree."""
-        packed32 = pack_tree(tree, jnp.float32)
-        buf32 = packed32.buf
-        if self._resid is None:
-            self._resid = jnp.zeros(buf32.shape, jnp.float32)
-        elif self._resid.shape != buf32.shape:
-            raise ValueError(
-                f"tree structure changed under error feedback "
-                f"({self._resid.shape} residual vs {buf32.shape} buffer) "
-                f"— call reset() when switching models"
+        with telemetry.span("fl.pack") as sp:
+            packed32 = pack_tree(tree, jnp.float32)
+            buf32 = packed32.buf
+            if self._resid is None:
+                self._resid = jnp.zeros(buf32.shape, jnp.float32)
+            elif self._resid.shape != buf32.shape:
+                raise ValueError(
+                    f"tree structure changed under error feedback "
+                    f"({self._resid.shape} residual vs {buf32.shape} buffer) "
+                    f"— call reset() when switching models"
+                )
+            wire_buf, self._resid = _ef_kernel(self._wire_name)(
+                buf32, self._resid
             )
-        wire_buf, self._resid = _ef_kernel(self._wire_name)(
-            buf32, self._resid
-        )
-        spec = PackSpec(
-            packed32.spec.entries, packed32.spec.treedef, self._wire_name
-        )
-        return PackedTree(wire_buf, packed32.passthrough, spec)
+            spec = PackSpec(
+                packed32.spec.entries, packed32.spec.treedef, self._wire_name
+            )
+            if sp is not None:
+                sp.nbytes = wire_buf.nbytes
+            return PackedTree(wire_buf, packed32.passthrough, spec)
 
 
 def compress(tree: Any, *, packed: bool = False, wire_dtype: Any = jnp.bfloat16):
@@ -343,10 +348,18 @@ def compress(tree: Any, *, packed: bool = False, wire_dtype: Any = jnp.bfloat16)
     ``packed=True`` selects the fused single-buffer form
     (:class:`PackedTree`): one cast kernel, one wire buffer, zero-copy
     decode — the fast path for whole-model pushes.
+
+    Flight recorder: ``fl.pack`` (the dispatch, not the device's end;
+    its parent tells the engine's pack, under ``driver.round``, from a
+    trainer's own, under ``task.run``).
     """
-    if packed:
-        return pack_tree(tree, wire_dtype)
-    return cast_floats(tree, wire_dtype)
+    with telemetry.span("fl.pack") as sp:
+        if not packed:
+            return cast_floats(tree, wire_dtype)
+        out = pack_tree(tree, wire_dtype)
+        if sp is not None:
+            sp.nbytes = out.nbytes
+        return out
 
 
 def decompress(tree: Any, dtype=jnp.float32) -> Any:
@@ -354,11 +367,12 @@ def decompress(tree: Any, dtype=jnp.float32) -> Any:
 
     Dispatches through ``tree.unpack`` so subclasses with their own
     decode (the shared-grid integer form dequantizes first) restore
-    correctly.
+    correctly.  Flight recorder: ``fl.unpack``, as ``fl.pack``.
     """
-    if isinstance(tree, PackedTree):
-        return tree.unpack(dtype)
-    return cast_floats(tree, dtype)
+    with telemetry.span("fl.unpack"):
+        if isinstance(tree, PackedTree):
+            return tree.unpack(dtype)
+        return cast_floats(tree, dtype)
 
 
 # Re-export the shared-grid integer codec: one import surface for wire

@@ -138,12 +138,12 @@ def packed_stripe_schedule(
 @functools.lru_cache(maxsize=None)
 def _stripe_finalize_jit(total_elems: int, out_dtype_name: str):
     @jax.jit
-    def _finish(acc, total_w):
+    def fed_finalize_f32(acc, total_w):
         return (acc[:total_elems] / total_w).astype(
             jnp.dtype(out_dtype_name)
         )
 
-    return _finish
+    return fed_finalize_f32
 
 
 def finalize_packed_stripe(acc, total_w: float, total_elems: int, out_dtype):
@@ -218,6 +218,11 @@ def quantized_accum_kernel(chunk_elems: int, wire_dtype: str):
     them agree bit-for-bit in ANY fold order, and the single fused
     rescale (:func:`finalize_packed_quantized`) is the only place
     floats appear.
+
+    The jitted function's name is the program's name in a device trace
+    (``jit_fed_fold_i32``): the benchmark's ``fold_roofline`` finds the
+    fold kernels by it, like ``fed_fold_f32``, ``fed_finalize_*`` and
+    ``fed_quant_*``.
     """
     import jax
     import jax.numpy as jnp
@@ -225,13 +230,13 @@ def quantized_accum_kernel(chunk_elems: int, wire_dtype: str):
     del wire_dtype  # codes widen to i32 whatever the wire width
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def _apply(acc, chunk, off, w):
+    def fed_fold_i32(acc, chunk, off, w):
         seg = jax.lax.dynamic_slice(acc, (off,), (chunk_elems,))
         return jax.lax.dynamic_update_slice(
             acc, seg + w * chunk.astype(jnp.int32), (off,)
         )
 
-    return _apply
+    return fed_fold_i32
 
 
 @functools.lru_cache(maxsize=None)
@@ -307,7 +312,7 @@ def _quant_finalize_jit(chunk_elems: int, total_elems: int,
     import jax.numpy as jnp
 
     @jax.jit
-    def _finish(acc, ref, scales, zps, total_w):
+    def fed_finalize_i32(acc, ref, scales, zps, total_w):
         a = acc.reshape(-1, chunk_elems).astype(jnp.float32)
         x = scales[:, None] * (a - zps[:, None] * total_w)
         x = x.reshape(-1)[:total_elems] / total_w
@@ -318,7 +323,7 @@ def _quant_finalize_jit(chunk_elems: int, total_elems: int,
             x = ref + x
         return x.astype(jnp.dtype(out_dtype_name))
 
-    return _finish
+    return fed_finalize_i32
 
 
 def finalize_packed_quantized(

@@ -80,6 +80,7 @@ from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from rayfed_tpu import telemetry
 from rayfed_tpu.fl.compression import PackedTree, PackSpec
 
 # Version of the shared-grid descriptor/semantics.  Bump when the grid
@@ -320,46 +321,55 @@ def make_round_grid(
     dispersion-proportional floor keeps it from degenerating into a
     clip-everything trap), then mapped affinely onto the integer
     range.  ``min_scale`` floors the fully-degenerate all-zero case.
+
+    Flight recorder: ``fl.quant.grid`` (numpy min/max over every
+    block; ``detail.side`` is ``up``, or ``down`` under a recode).
     """
-    if isinstance(reference, PackedTree):
-        reference = reference.buf
-    arr = np.asarray(reference).reshape(-1).astype(np.float32)
-    if arr.size == 0:
-        raise ValueError(
-            "cannot derive a quantization grid from an empty buffer"
+    with telemetry.span("fl.quant.grid") as sp:
+        if sp is not None:
+            # The coordinator's downlink recode derives its own grid.
+            sp.detail = {
+                "side": "down" if sp.parent == "fl.quant.recode" else "up"
+            }
+        if isinstance(reference, PackedTree):
+            reference = reference.buf
+        arr = np.asarray(reference).reshape(-1).astype(np.float32)
+        if arr.size == 0:
+            raise ValueError(
+                "cannot derive a quantization grid from an empty buffer"
+            )
+        if chunk_elems is None:
+            from rayfed_tpu.fl.streaming import DEFAULT_CHUNK_ELEMS
+
+            chunk_elems = DEFAULT_CHUNK_ELEMS
+        ce = int(chunk_elems)
+        qmin, qmax = _qrange(wire_dtype)
+        from rayfed_tpu.fl.fedavg import packed_block_grid
+
+        nb = packed_block_grid(arr.size, ce)
+        total = arr.size
+        rms = float(np.sqrt(np.mean(np.square(arr, dtype=np.float64))))
+        # Pad the tail block with its last value: min/max of the padded row
+        # equal the true block min/max (a zero pad would drag the range
+        # toward 0 for tail blocks that never contain 0).
+        pad = nb * ce - total
+        if pad:
+            arr = np.concatenate([arr, np.full(pad, arr[-1], np.float32)])
+        a2 = arr.reshape(nb, ce)
+        lo = a2.min(axis=1)
+        hi = a2.max(axis=1)
+        mid = 0.5 * (hi + lo)
+        half = np.maximum(
+            0.5 * (hi - lo) * np.float32(expand),
+            np.float32(float(floor_frac) * rms),
         )
-    if chunk_elems is None:
-        from rayfed_tpu.fl.streaming import DEFAULT_CHUNK_ELEMS
-
-        chunk_elems = DEFAULT_CHUNK_ELEMS
-    ce = int(chunk_elems)
-    qmin, qmax = _qrange(wire_dtype)
-    from rayfed_tpu.fl.fedavg import packed_block_grid
-
-    nb = packed_block_grid(arr.size, ce)
-    total = arr.size
-    rms = float(np.sqrt(np.mean(np.square(arr, dtype=np.float64))))
-    # Pad the tail block with its last value: min/max of the padded row
-    # equal the true block min/max (a zero pad would drag the range
-    # toward 0 for tail blocks that never contain 0).
-    pad = nb * ce - total
-    if pad:
-        arr = np.concatenate([arr, np.full(pad, arr[-1], np.float32)])
-    a2 = arr.reshape(nb, ce)
-    lo = a2.min(axis=1)
-    hi = a2.max(axis=1)
-    mid = 0.5 * (hi + lo)
-    half = np.maximum(
-        0.5 * (hi - lo) * np.float32(expand),
-        np.float32(float(floor_frac) * rms),
-    )
-    lo = mid - half
-    hi = mid + half
-    scales = np.maximum(
-        (hi - lo) / np.float32(qmax - qmin), np.float32(min_scale)
-    ).astype(np.float32)
-    zps = (qmin - lo / scales).astype(np.float32)
-    return QuantGrid(scales, zps, ce, total, wire_dtype, mode)
+        lo = mid - half
+        hi = mid + half
+        scales = np.maximum(
+            (hi - lo) / np.float32(qmax - qmin), np.float32(min_scale)
+        ).astype(np.float32)
+        zps = (qmin - lo / scales).astype(np.float32)
+        return QuantGrid(scales, zps, ce, total, wire_dtype, mode)
 
 
 class QuantizedPackedTree(PackedTree):
@@ -416,20 +426,22 @@ class QuantizedPackedTree(PackedTree):
                    ref: Optional[Any] = None) -> PackedTree:
         """ONE fused rescale (+ reference add, for ``mode="delta"``
         codes) of the whole buffer back to ``out_dtype`` — the decode
-        half of the codec."""
-        grid = self.grid()
-        ref = _check_ref(grid, ref)
-        out_name = np.dtype(out_dtype).name
-        if ref is None:
-            import jax.numpy as jnp
+        half of the codec (flight recorder: ``fl.quant.decode``, the
+        dispatch)."""
+        with telemetry.span("fl.quant.decode"):
+            grid = self.grid()
+            ref = _check_ref(grid, ref)
+            out_name = np.dtype(out_dtype).name
+            if ref is None:
+                import jax.numpy as jnp
 
-            ref = jnp.zeros(0, jnp.float32)
-        buf = _dequantize_kernel(
-            self.gmeta.chunk_elems, self.gmeta.total_elems,
-            self.gmeta.wire_dtype, out_name, grid.mode == "delta",
-        )(self.buf, ref, np.asarray(self.scales), np.asarray(self.zps))
-        spec = PackSpec(self.spec.entries, self.spec.treedef, out_name)
-        return PackedTree(buf, self.passthrough, spec)
+                ref = jnp.zeros(0, jnp.float32)
+            buf = _dequantize_kernel(
+                self.gmeta.chunk_elems, self.gmeta.total_elems,
+                self.gmeta.wire_dtype, out_name, grid.mode == "delta",
+            )(self.buf, ref, np.asarray(self.scales), np.asarray(self.zps))
+            spec = PackSpec(self.spec.entries, self.spec.treedef, out_name)
+            return PackedTree(buf, self.passthrough, spec)
 
     def unpack(self, dtype: Any = None) -> Any:
         """Dequantize + unpack.  ``dtype=None`` decodes to f32 (integer
@@ -486,7 +498,7 @@ def _quantize_kernel(chunk_elems: int, total_elems: int, wire_name: str,
     pad = nb * chunk_elems - total_elems
 
     @jax.jit
-    def _q(buf, ref, scales, zps, resid):
+    def fed_quant_encode(buf, ref, scales, zps, resid):
         value = buf.astype(jnp.float32)
         if with_ref:
             value = value - ref
@@ -500,7 +512,7 @@ def _quantize_kernel(chunk_elems: int, total_elems: int, wire_name: str,
         new_resid = corrected - deq.reshape(-1)[:total_elems]
         return qbuf, new_resid
 
-    return _q
+    return fed_quant_encode
 
 
 @functools.lru_cache(maxsize=None)
@@ -515,7 +527,7 @@ def _dequantize_kernel(chunk_elems: int, total_elems: int,
     pad = nb * chunk_elems - total_elems
 
     @jax.jit
-    def _dq(qbuf, ref, scales, zps):
+    def fed_quant_decode(qbuf, ref, scales, zps):
         a = jnp.pad(qbuf.astype(jnp.float32), (0, pad)).reshape(
             nb, chunk_elems
         )
@@ -525,7 +537,7 @@ def _dequantize_kernel(chunk_elems: int, total_elems: int,
             x = ref + x
         return x.astype(jnp.dtype(out_name))
 
-    return _dq
+    return fed_quant_decode
 
 
 def _check_ref(grid: QuantGrid, ref: Optional[Any]):
@@ -582,15 +594,21 @@ def _quantize_with_resid(
         resid = jnp.zeros(grid.total_elems, jnp.float32)
     if ref is None:
         ref = jnp.zeros(0, jnp.float32)  # unused placeholder arg
-    qbuf, new_resid = _quantize_kernel(
-        grid.chunk_elems, grid.total_elems, grid.wire_dtype,
-        grid.mode == "delta",
-    )(buf, ref, grid.scales, grid.zps, resid)
+    # The encode kernel and the d2h of its codes (the wire ships host
+    # bytes): one span, the host's time in both.
+    with telemetry.span("fl.quant.encode") as sp:
+        qbuf, new_resid = _quantize_kernel(
+            grid.chunk_elems, grid.total_elems, grid.wire_dtype,
+            grid.mode == "delta",
+        )(buf, ref, grid.scales, grid.zps, resid)
+        codes = np.asarray(qbuf)
+        if sp is not None:
+            sp.nbytes = codes.nbytes
     spec = PackSpec(
         packed.spec.entries, packed.spec.treedef, grid.wire_dtype
     )
     qt = QuantizedPackedTree(
-        np.asarray(qbuf), grid.scales, grid.zps, packed.passthrough,
+        codes, grid.scales, grid.zps, packed.passthrough,
         spec, grid.meta(),
     )
     return qt, new_resid
@@ -767,29 +785,31 @@ def quantize_downlink(
     automatically ranged by the post-step delta — no new metadata key,
     no schema change.  ``scope`` keys the downlink's own
     error-feedback residual (``{scope}/down``); None quantizes
-    statelessly.
+    statelessly.  Flight recorder: ``fl.quant.recode``, the parent of
+    its grid, encode and decode spans.
     """
-    if ref is not None:
-        down_src = np.asarray(result.buf).astype(np.float32) - ref
-        down_grid = make_round_grid(
-            down_src, chunk_elems=grid.chunk_elems,
-            wire_dtype=grid.wire_dtype, mode="delta",
+    with telemetry.span("fl.quant.recode"):
+        if ref is not None:
+            down_src = np.asarray(result.buf).astype(np.float32) - ref
+            down_grid = make_round_grid(
+                down_src, chunk_elems=grid.chunk_elems,
+                wire_dtype=grid.wire_dtype, mode="delta",
+            )
+        else:
+            down_grid = make_round_grid(
+                result.buf, chunk_elems=grid.chunk_elems,
+                wire_dtype=grid.wire_dtype, mode="abs",
+            )
+        dcomp = compressor(f"{scope}/down") if scope is not None else None
+        wire_result = (
+            dcomp.quantize(result, down_grid, ref=ref)
+            if dcomp is not None
+            else quantize_packed(result, down_grid, ref=ref)
         )
-    else:
-        down_grid = make_round_grid(
-            result.buf, chunk_elems=grid.chunk_elems,
-            wire_dtype=grid.wire_dtype, mode="abs",
-        )
-    dcomp = compressor(f"{scope}/down") if scope is not None else None
-    wire_result = (
-        dcomp.quantize(result, down_grid, ref=ref)
-        if dcomp is not None
-        else quantize_packed(result, down_grid, ref=ref)
-    )
-    decoded = wire_result.dequantize(np.dtype(out_dtype), ref=ref)
-    if dcomp is not None:
-        dcomp.commit()
-    return wire_result, decoded, grid_descriptor(down_grid)
+        decoded = wire_result.dequantize(np.dtype(out_dtype), ref=ref)
+        if dcomp is not None:
+            dcomp.commit()
+        return wire_result, decoded, grid_descriptor(down_grid)
 
 
 # Compressor registry, keyed by stream scope (one EF state per outgoing

@@ -75,13 +75,13 @@ def _accum_kernel(chunk_elems: int, acc_dtype: str, wire_dtype: str):
     acc_dt = jnp.dtype(acc_dtype)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def _apply(acc, chunk, off, w):
+    def fed_fold_f32(acc, chunk, off, w):
         seg = jax.lax.dynamic_slice(acc, (off,), (chunk_elems,))
         return jax.lax.dynamic_update_slice(
             acc, seg + w * chunk.astype(acc_dt), (off,)
         )
 
-    return _apply
+    return fed_fold_f32
 
 
 # Finalize (divide + cast) is shared with the one-shot path and the
@@ -1112,6 +1112,19 @@ class StreamingAggregator:
                 with self._cond:
                     s.applied_blocks = hi
 
+        from rayfed_tpu import telemetry as _telemetry
+
+        _tr = _telemetry.active()
+        drain_ms = None
+        if _tr is not None and not self._np_fold:
+            # The device's end of the fold, counted from the last
+            # block's arrival: the one sync tracing adds, armed only
+            # (``agg.fold`` itself ends when the bytes have arrived).
+            self._acc.block_until_ready()
+            drain_ms = round(1e3 * (time.perf_counter() - (
+                self._t_all_complete
+                or max(self._streams[i].t_complete for i in order)
+            )), 3)
         t0 = time.perf_counter()
         t0_wall = time.time()
         result = self._finalize()
@@ -1122,9 +1135,6 @@ class StreamingAggregator:
             self._t_all_complete = self._t_done
         tail_s = max(0.0, self._t_done - self._t_all_complete)
         busy = max(self._busy_s, 1e-9)
-        from rayfed_tpu import telemetry as _telemetry
-
-        _tr = _telemetry.active()
         if _tr is not None:
             # The fold window (first byte → every block folded) and the
             # single finalize, as spans.  Wall anchors derive from the
@@ -1150,6 +1160,12 @@ class StreamingAggregator:
                             [] if self._np_fold
                             else sorted(d.id for d in self._acc.devices())
                         ),
+                        # What one fold kernel moves (the benchmark's
+                        # ``fold_roofline``), and the fold's end on
+                        # the device.
+                        "chunk_elems": self._chunk_elems,
+                        "acc": str(self._acc.dtype),
+                        "drain_ms": drain_ms,
                     },
                 )
             _tr.emit(

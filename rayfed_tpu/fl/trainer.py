@@ -962,25 +962,19 @@ def run_fedavg_rounds(
     # been observed, so the first round always runs unquantized.
     quant_prev_delta = None
 
-    me = None
     sa_keys = None
     sa_session = None
-    # Flight recorder (rayfed_tpu/telemetry.py): armed, every
-    # materialized round emits driver-side spans carrying the SAME
-    # round/epoch keys the transport stamps on frames, so the driver's
-    # view and the wire's view join on one timeline.  The lazy pipelined
-    # path stays untraced (no per-round boundary), exactly like
-    # ``timings``.
+    # Flight recorder (rayfed_tpu/telemetry.py): armed, every round is
+    # a driver-side span carrying the SAME round/epoch keys the
+    # transport stamps on frames, so the driver's view and the wire's
+    # view join on one timeline.
+    import time as _time
+
     from rayfed_tpu import telemetry as _telemetry
+    from rayfed_tpu.runtime import get_runtime
 
-    trace_rounds = _telemetry.armed() and not pipeline
-    if timings is not None or trace_rounds:
-        import time as _time
-    if timings is not None or secure_agg or trace_rounds:
-        from rayfed_tpu.runtime import get_runtime
-
-        _rt = get_runtime()
-        me = _rt.party
+    _rt = get_runtime()
+    me = _rt.party
     if secure_agg:
         _transport = _rt.transport
         sa_keys = getattr(_transport, "secagg_keys", None)
@@ -1002,347 +996,359 @@ def run_fedavg_rounds(
         sa_session = str(_rt.next_seq_id())
 
     for r in range(start_round, rounds):
-        active = round_parties(r)
-        # Wire form: a driver-held tree is compressed before the push
-        # (with the carried error-feedback residual folded in, when
-        # enabled); a lazy FedObject from a pipelined round is already
-        # the trainers' own (compressed) wire form.
-        if compress_wire and not isinstance(current, FedObject):
-            outgoing = (
-                ef.compress(current)
-                if ef is not None
-                else compress(
-                    current, packed=packed_wire, wire_dtype=wire_dt
-                )
-            )
-        else:
-            outgoing = current
-        rec = None
-        if timings is not None or trace_rounds:
-            # Per-round breakdown (satellite of the overlap work): the
-            # synchronous path exposes local/push/agg walls with
-            # hidden_s pinned at 0 — comms fully serialize behind
-            # compute here, which is exactly what overlap=True removes.
-            rec = {
-                "local_s": 0.0, "push_s": 0.0, "agg_s": 0.0,
-                "hidden_s": 0.0,
-            }
-            t_r0 = _time.perf_counter()
-            t_r0_wall = _time.time()
-        updates = [trainers[p].train.remote(outgoing) for p in active]
-        if rec is not None and me in active:
-            my_ref = updates[active.index(me)].get_local_ref()
-            if my_ref is not None:
-                my_ref.add_done_callback(
-                    lambda _ref, rec=rec, t0=t_r0: rec.__setitem__(
-                        "local_s", _time.perf_counter() - t0
+        # A scope around the whole round.  The armed state is read here,
+        # once a round: a recorder armed in the middle of the call is
+        # seen from the next round.  On the lazy pipelined path the
+        # driver thread only enqueues round r (``driver.dispatch``);
+        # nothing is materialized for the span's sake.
+        with _telemetry.span(
+            "driver.dispatch" if pipeline else "driver.round",
+            round=r, party=me, peer=coord,
+        ) as round_span:
+            active = round_parties(r)
+            # Wire form: a driver-held tree is compressed before the push
+            # (with the carried error-feedback residual folded in, when
+            # enabled); a lazy FedObject from a pipelined round is already
+            # the trainers' own (compressed) wire form.
+            if compress_wire and not isinstance(current, FedObject):
+                outgoing = (
+                    ef.compress(current)
+                    if ef is not None
+                    else compress(
+                        current, packed=packed_wire, wire_dtype=wire_dt
                     )
                 )
-        if pipeline:
-            last = r == rounds - 1
-            current = aggregate(
-                updates,
-                weights,
-                mode="coordinator",
-                coordinator=coord,
-                materialize=last,
-            )
-            if last and compress_wire:
-                current = decompress(current)
-            continue
-
-        # aggregate() owns the wire topology for both the mean and a
-        # custom reducer (coordinator-side reduce + broadcast at N>2) —
-        # one place decides who talks to whom.  The streaming path rides
-        # the same coordinator topology but folds contributions in as
-        # their chunks arrive; the ring path replaces the hub with a
-        # reduce-scatter + all-gather.  All three are bit-identical.
-        #
-        # With error feedback (or a server optimizer) the aggregate
-        # must come back in f32: casting the mean to an aggressive
-        # wire dtype here would re-quantize it with no residual to
-        # compensate (the broadcast's delta cache still applies).
-        agg_out_dtype = (
-            "float32"
-            if (error_feedback or server_opt is not None)
-            else None
-        )
-        # Compressed-domain round: parties code their update as a DELTA
-        # against the round's shared starting model (`current`, bit-
-        # identical on every controller) on a grid derived from the
-        # PREVIOUS round's observed aggregate delta — per-party deltas
-        # live at that scale, so the 8-bit step resolves the signal,
-        # not the ambient parameter range.  Every controller derives
-        # the identical grid from the identical shared buffers (that IS
-        # the negotiation; the fingerprint rides every quantized frame
-        # and the aggregators verify it).  The FIRST round has no
-        # observed delta yet and runs unquantized (bootstrap).
-        round_grid = None
-        round_ref = None
-        if wire_quant is not None:
-            from rayfed_tpu.fl import quantize as _qz
-            from rayfed_tpu.fl.compression import pack_tree
-
-            round_ref = _np.asarray(
-                pack_tree(current, _jnp.float32).buf
-            )
-            if quant_prev_delta is not None:
-                round_grid = _qz.make_round_grid(
-                    quant_prev_delta, wire_dtype=_qname, mode="delta",
-                    # The grid chunking must BE the fold/stripe
-                    # chunking: a ring round with an overridden
-                    # ring_chunk_elems quantizes on that same grid, or
-                    # ring_aggregate's chunk-match guard would abort
-                    # (and silently fall back) every quantized round.
-                    chunk_elems=(
-                        ring_chunk_elems
-                        if mode in ("ring", "hierarchy") else None
-                    ),
-                    # Per-party deltas overshoot the aggregate delta
-                    # (the mean averages them down) — give the grid
-                    # headroom; what still clips rides the EF residual.
-                    expand=_QUANT_DELTA_EXPAND,
-                )
-        # Packed server optimization (fl.server_opt): the round's
-        # shared starting buffer anchors the step (applied at the
-        # finalizing node for streaming/quorum/hierarchy, locally on
-        # every controller for ring/classic — deterministic f32 on
-        # byte-identical input either way) and the post-round state
-        # resync every controller runs from the broadcast pair.
-        step_fn = None
-        x_srv = None
-        if sopt is not None:
-            if round_ref is not None:
-                x_srv = round_ref
             else:
-                from rayfed_tpu.fl.compression import pack_tree as _pt2
-
-                x_srv = _np.asarray(_pt2(current, _jnp.float32).buf)
-            sopt.ensure(x_srv)
-            step_fn = sopt.step_fn(x_srv)
-        # Secure aggregation: this party's round masker (pairwise
-        # seeds toward every active peer at its own fold weight); the
-        # keystream expansion prefetches on a background thread so it
-        # overlaps training/the wire instead of the round's critical
-        # path.  The bootstrap round (no grid) runs unmasked.
-        round_masker = None
-        if secure_agg and round_grid is not None and me in trainers:
-            from rayfed_tpu.fl import secagg as _sa
-            from rayfed_tpu.fl.fedavg import quant_weights
-
-            _iw, _ = quant_weights(
-                None if weights is None
-                else [float(w) for w in weights],
-                len(active),
-            )
-            round_masker = _sa.RoundMasker(
-                sa_keys, me, [p for p in active if p != me],
-                session=sa_session, stream="fedavg", round_index=r,
-                weight=_iw[active.index(me)],
-            )
-            round_masker.prefetch(round_grid.total_elems)
-        if mode == "hierarchy":
-            from rayfed_tpu.fl.streaming import streaming_aggregate
-
-            if round_grid is None:
-                # Bootstrap round: no shared grid has been observed yet
-                # and hierarchy is compressed-domain only — run the
-                # flat streaming round (exactly the quantized loop's
-                # own bootstrap), hierarchical from the next round.
-                avg = streaming_aggregate(
-                    updates, weights, stream="fedavg",
-                    coordinator=coord, out_dtype=agg_out_dtype,
-                    timings=rec,
-                    server_step=step_fn,
+                outgoing = current
+            rec = None
+            if timings is not None or (
+                round_span is not None and not pipeline
+            ):
+                # Per-round breakdown (satellite of the overlap work): the
+                # synchronous path exposes local/push/agg walls with
+                # hidden_s pinned at 0 — comms fully serialize behind
+                # compute here, which is exactly what overlap=True removes.
+                rec = {
+                    "local_s": 0.0, "push_s": 0.0, "agg_s": 0.0,
+                    "hidden_s": 0.0,
+                }
+                t_r0 = _time.perf_counter()
+            updates = [trainers[p].train.remote(outgoing) for p in active]
+            if rec is not None and me in active:
+                my_ref = updates[active.index(me)].get_local_ref()
+                if my_ref is not None:
+                    my_ref.add_done_callback(
+                        lambda _ref, rec=rec, t0=t_r0: rec.__setitem__(
+                            "local_s", _time.perf_counter() - t0
+                        )
+                    )
+            if pipeline:
+                last = r == rounds - 1
+                current = aggregate(
+                    updates,
+                    weights,
+                    mode="coordinator",
+                    coordinator=coord,
+                    materialize=last,
                 )
-            else:
-                from rayfed_tpu.fl.hierarchy import (
-                    HIER_STATS,
-                    HierarchyRoundError,
-                    hierarchy_aggregate,
+                if last and compress_wire:
+                    current = decompress(current)
+                continue
+
+            # aggregate() owns the wire topology for both the mean and a
+            # custom reducer (coordinator-side reduce + broadcast at N>2) —
+            # one place decides who talks to whom.  The streaming path rides
+            # the same coordinator topology but folds contributions in as
+            # their chunks arrive; the ring path replaces the hub with a
+            # reduce-scatter + all-gather.  All three are bit-identical.
+            #
+            # With error feedback (or a server optimizer) the aggregate
+            # must come back in f32: casting the mean to an aggressive
+            # wire dtype here would re-quantize it with no residual to
+            # compensate (the broadcast's delta cache still applies).
+            agg_out_dtype = (
+                "float32"
+                if (error_feedback or server_opt is not None)
+                else None
+            )
+            # Compressed-domain round: parties code their update as a DELTA
+            # against the round's shared starting model (`current`, bit-
+            # identical on every controller) on a grid derived from the
+            # PREVIOUS round's observed aggregate delta — per-party deltas
+            # live at that scale, so the 8-bit step resolves the signal,
+            # not the ambient parameter range.  Every controller derives
+            # the identical grid from the identical shared buffers (that IS
+            # the negotiation; the fingerprint rides every quantized frame
+            # and the aggregators verify it).  The FIRST round has no
+            # observed delta yet and runs unquantized (bootstrap).
+            round_grid = None
+            round_ref = None
+            if wire_quant is not None:
+                from rayfed_tpu.fl import quantize as _qz
+                from rayfed_tpu.fl.compression import pack_tree
+
+                # d2h of the whole model as float32.
+                with _telemetry.span("fl.quant.ref") as sp:
+                    round_ref = _np.asarray(
+                        pack_tree(current, _jnp.float32).buf
+                    )
+                    if sp is not None:
+                        sp.nbytes = round_ref.nbytes
+                if quant_prev_delta is not None:
+                    round_grid = _qz.make_round_grid(
+                        quant_prev_delta, wire_dtype=_qname, mode="delta",
+                        # The grid chunking must BE the fold/stripe
+                        # chunking: a ring round with an overridden
+                        # ring_chunk_elems quantizes on that same grid, or
+                        # ring_aggregate's chunk-match guard would abort
+                        # (and silently fall back) every quantized round.
+                        chunk_elems=(
+                            ring_chunk_elems
+                            if mode in ("ring", "hierarchy") else None
+                        ),
+                        # Per-party deltas overshoot the aggregate delta
+                        # (the mean averages them down) — give the grid
+                        # headroom; what still clips rides the EF residual.
+                        expand=_QUANT_DELTA_EXPAND,
+                    )
+            # Packed server optimization (fl.server_opt): the round's
+            # shared starting buffer anchors the step (applied at the
+            # finalizing node for streaming/quorum/hierarchy, locally on
+            # every controller for ring/classic — deterministic f32 on
+            # byte-identical input either way) and the post-round state
+            # resync every controller runs from the broadcast pair.
+            step_fn = None
+            x_srv = None
+            if sopt is not None:
+                if round_ref is not None:
+                    x_srv = round_ref
+                else:
+                    from rayfed_tpu.fl.compression import pack_tree as _pt2
+
+                    x_srv = _np.asarray(_pt2(current, _jnp.float32).buf)
+                sopt.ensure(x_srv)
+                step_fn = sopt.step_fn(x_srv)
+            # Secure aggregation: this party's round masker (pairwise
+            # seeds toward every active peer at its own fold weight); the
+            # keystream expansion prefetches on a background thread so it
+            # overlaps training/the wire instead of the round's critical
+            # path.  The bootstrap round (no grid) runs unmasked.
+            round_masker = None
+            if secure_agg and round_grid is not None and me in trainers:
+                from rayfed_tpu.fl import secagg as _sa
+                from rayfed_tpu.fl.fedavg import quant_weights
+
+                _iw, _ = quant_weights(
+                    None if weights is None
+                    else [float(w) for w in weights],
+                    len(active),
+                )
+                round_masker = _sa.RoundMasker(
+                    sa_keys, me, [p for p in active if p != me],
+                    session=sa_session, stream="fedavg", round_index=r,
+                    weight=_iw[active.index(me)],
+                )
+                round_masker.prefetch(round_grid.total_elems)
+            if mode == "hierarchy":
+                from rayfed_tpu.fl.streaming import streaming_aggregate
+
+                if round_grid is None:
+                    # Bootstrap round: no shared grid has been observed yet
+                    # and hierarchy is compressed-domain only — run the
+                    # flat streaming round (exactly the quantized loop's
+                    # own bootstrap), hierarchical from the next round.
+                    avg = streaming_aggregate(
+                        updates, weights, stream="fedavg",
+                        coordinator=coord, out_dtype=agg_out_dtype,
+                        timings=rec,
+                        server_step=step_fn,
+                    )
+                else:
+                    from rayfed_tpu.fl.hierarchy import (
+                        HIER_STATS,
+                        HierarchyRoundError,
+                        hierarchy_aggregate,
+                    )
+
+                    try:
+                        avg = hierarchy_aggregate(
+                            updates, weights,
+                            region_size=int(region_size),
+                            region_branch=region_branch,
+                            region_quorum=region_quorum,
+                            region_deadline_s=region_deadline_s,
+                            stream="fedavg",
+                            server_step=step_fn,
+                            quant=round_grid, quant_ref=round_ref,
+                            quant_scope="fedavg",
+                            # Quantize the broadcast down the tree too —
+                            # the downlink is the other half of the
+                            # round's bytes (shared quantize_downlink
+                            # producer).
+                            quant_downlink=True,
+                            round_tag=r, timings=rec,
+                        )
+                    except HierarchyRoundError as e:
+                        # The abort reached every controller (tree-shaped
+                        # poison cascade + commit/release), so all of them
+                        # take this branch in lockstep: re-aggregate the
+                        # SAME round's updates over the flat streaming
+                        # path — owners still hold them, and the shared
+                        # RoundCodec re-quantizes with the SAME residual.
+                        logger.warning(
+                            "hierarchy round %d aborted (%s); falling back "
+                            "to flat streaming aggregation at %r", r, e,
+                            coord,
+                        )
+                        HIER_STATS["fallback_rounds"] += 1
+                        avg = streaming_aggregate(
+                            updates, weights, stream="fedavg",
+                            coordinator=coord, timings=rec,
+                            quant=round_grid, quant_ref=round_ref,
+                            quant_scope="fedavg",
+                            # The SAME step from the SAME state: the abort
+                            # happened before any resync, so the flat
+                            # re-run's step is bit-identical to the one the
+                            # hierarchy root would have applied.
+                            server_step=step_fn,
+                        )
+            elif mode == "ring":
+                from rayfed_tpu.fl.ring import (
+                    RING_STATS,
+                    RingRoundError,
+                    ring_aggregate,
                 )
 
                 try:
-                    avg = hierarchy_aggregate(
-                        updates, weights,
-                        region_size=int(region_size),
-                        region_branch=region_branch,
-                        region_quorum=region_quorum,
-                        region_deadline_s=region_deadline_s,
-                        stream="fedavg",
-                        server_step=step_fn,
+                    avg = ring_aggregate(
+                        updates, weights, stream="fedavg",
+                        out_dtype=agg_out_dtype,
+                        chunk_elems=ring_chunk_elems, timings=rec,
                         quant=round_grid, quant_ref=round_ref,
                         quant_scope="fedavg",
-                        # Quantize the broadcast down the tree too —
-                        # the downlink is the other half of the
-                        # round's bytes (shared quantize_downlink
-                        # producer).
-                        quant_downlink=True,
-                        round_tag=r, timings=rec,
                     )
-                except HierarchyRoundError as e:
-                    # The abort reached every controller (tree-shaped
-                    # poison cascade + commit/release), so all of them
-                    # take this branch in lockstep: re-aggregate the
-                    # SAME round's updates over the flat streaming
-                    # path — owners still hold them, and the shared
-                    # RoundCodec re-quantizes with the SAME residual.
+                    if step_fn is not None:
+                        # The ring has no downlink — every controller holds
+                        # the byte-identical assembled aggregate, so each
+                        # applies the same deterministic f32 step locally
+                        # and all byte-agree on the post-step model.
+                        avg = step_fn(avg)
+                except RingRoundError as e:
+                    # The abort reached every controller (poison cascade +
+                    # commit ring), so all of them take this branch in
+                    # lockstep: re-aggregate the SAME round's updates over
+                    # the coordinator topology — owners still hold them, so
+                    # no training work is lost.
+                    from rayfed_tpu.fl.streaming import streaming_aggregate
+
                     logger.warning(
-                        "hierarchy round %d aborted (%s); falling back "
-                        "to flat streaming aggregation at %r", r, e,
-                        coord,
+                        "ring round %d aborted (%s); falling back to "
+                        "coordinator aggregation at %r", r, e, coord,
                     )
-                    HIER_STATS["fallback_rounds"] += 1
+                    RING_STATS["fallback_rounds"] += 1
                     avg = streaming_aggregate(
                         updates, weights, stream="fedavg",
-                        coordinator=coord, timings=rec,
+                        coordinator=coord, out_dtype=agg_out_dtype,
+                        timings=rec,
+                        # Same grid, same (uncommitted) residual: the
+                        # fallback re-quantizes the identical codes the
+                        # ring round would have folded.  Downlink stays
+                        # plain — this is the recovery path, keep it
+                        # simple.  The server step re-runs from the same
+                        # (never-resynced) state at the coordinator.
                         quant=round_grid, quant_ref=round_ref,
                         quant_scope="fedavg",
-                        # The SAME step from the SAME state: the abort
-                        # happened before any resync, so the flat
-                        # re-run's step is bit-identical to the one the
-                        # hierarchy root would have applied.
                         server_step=step_fn,
                     )
-        elif mode == "ring":
-            from rayfed_tpu.fl.ring import (
-                RING_STATS,
-                RingRoundError,
-                ring_aggregate,
-            )
-
-            try:
-                avg = ring_aggregate(
-                    updates, weights, stream="fedavg",
-                    out_dtype=agg_out_dtype,
-                    chunk_elems=ring_chunk_elems, timings=rec,
-                    quant=round_grid, quant_ref=round_ref,
-                    quant_scope="fedavg",
-                )
-                if step_fn is not None:
-                    # The ring has no downlink — every controller holds
-                    # the byte-identical assembled aggregate, so each
-                    # applies the same deterministic f32 step locally
-                    # and all byte-agree on the post-step model.
-                    avg = step_fn(avg)
-            except RingRoundError as e:
-                # The abort reached every controller (poison cascade +
-                # commit ring), so all of them take this branch in
-                # lockstep: re-aggregate the SAME round's updates over
-                # the coordinator topology — owners still hold them, so
-                # no training work is lost.
+            elif streaming_agg:
                 from rayfed_tpu.fl.streaming import streaming_aggregate
 
-                logger.warning(
-                    "ring round %d aborted (%s); falling back to "
-                    "coordinator aggregation at %r", r, e, coord,
-                )
-                RING_STATS["fallback_rounds"] += 1
                 avg = streaming_aggregate(
                     updates, weights, stream="fedavg",
-                    coordinator=coord, out_dtype=agg_out_dtype,
+                    coordinator=coord,
+                    out_dtype=agg_out_dtype,
                     timings=rec,
-                    # Same grid, same (uncommitted) residual: the
-                    # fallback re-quantizes the identical codes the
-                    # ring round would have folded.  Downlink stays
-                    # plain — this is the recovery path, keep it
-                    # simple.  The server step re-runs from the same
-                    # (never-resynced) state at the coordinator.
                     quant=round_grid, quant_ref=round_ref,
                     quant_scope="fedavg",
+                    # Quantize the result broadcast too: the downlink is
+                    # the other half of the round's bytes.  Under a server
+                    # step the coordinator steps FIRST, so the downlink
+                    # recode's fresh grid is ranged by the post-step delta.
+                    quant_downlink=round_grid is not None,
+                    secagg=round_masker,
                     server_step=step_fn,
                 )
-        elif streaming_agg:
-            from rayfed_tpu.fl.streaming import streaming_aggregate
-
-            avg = streaming_aggregate(
-                updates, weights, stream="fedavg",
-                coordinator=coord,
-                out_dtype=agg_out_dtype,
-                timings=rec,
-                quant=round_grid, quant_ref=round_ref,
-                quant_scope="fedavg",
-                # Quantize the result broadcast too: the downlink is
-                # the other half of the round's bytes.  Under a server
-                # step the coordinator steps FIRST, so the downlink
-                # recode's fresh grid is ranged by the post-step delta.
-                quant_downlink=round_grid is not None,
-                secagg=round_masker,
-                server_step=step_fn,
-            )
-        else:
-            t_a0 = _time.perf_counter() if rec is not None else 0.0
-            avg = aggregate(
-                updates, weights, reducer=aggregator, coordinator=coord
-            )
-            if step_fn is not None:
-                # Every controller holds the byte-identical broadcast
-                # mean; the deterministic f32 step keeps them agreeing.
-                avg = step_fn(avg)
-            if rec is not None:
-                rec["agg_s"] = _time.perf_counter() - t_a0
-        if sopt is not None:
-            # Every controller advances its state replica from the
-            # round's byte-agreed broadcast pair (the broadcast IS the
-            # post-step model) — all replicas stay byte-identical with
-            # zero extra wire bytes (fl.server_opt).
-            sopt.resync(x_srv, _np.asarray(avg.buf))
-        if wire_quant is not None:
-            # What the grid must cover next round: how far the global
-            # model just moved, per block.  Derived from broadcast
-            # values only, so it is bit-identical on every controller
-            # (under server_opt: the POST-step delta — the grid ranges
-            # over the model movement the step actually realized).
-            quant_prev_delta = (
-                _np.asarray(avg.buf).astype(_np.float32) - round_ref
-            )
-        if compress_wire:
-            avg = decompress(avg)
-        if legacy_opt is not None:
-            current, state = legacy_opt.apply(current, avg, state)
-        else:
-            current = avg
-        if on_round is not None:
-            on_round(r, current)
-        if checkpoint_every and (r + 1) % checkpoint_every == 0:
-            snap = {"params": current}
-            if state is not None:
-                snap["server_state"] = state
+            else:
+                t_a0 = _time.perf_counter() if rec is not None else 0.0
+                avg = aggregate(
+                    updates, weights, reducer=aggregator, coordinator=coord
+                )
+                if step_fn is not None:
+                    # Every controller holds the byte-identical broadcast
+                    # mean; the deterministic f32 step keeps them agreeing.
+                    avg = step_fn(avg)
+                if rec is not None:
+                    rec["agg_s"] = _time.perf_counter() - t_a0
             if sopt is not None:
-                snap["server_state"] = sopt.state
-            checkpointer.save(
-                r + 1, snap, metadata={"server_opt": sopt_descr}
-            )
-        if rec is not None:
-            # The aggregation call blocks on this party's own training
-            # output before any byte can move, so its measured walls
-            # include the local wait — subtract it to report the comms-
-            # only window (what overlap=True would hide).
-            rec["push_s"] = max(0.0, rec["push_s"] - rec["local_s"])
-            rec["agg_s"] = max(0.0, rec["agg_s"] - rec["local_s"])
-            # Correlation stamp: the SAME keys the transport rides on
-            # every frame (wire.ROUND_TAG_KEY / EPOCH_TAG_KEY), so a
-            # timings row joins the wire's view of its round on one
-            # timeline.  Classic fedavg has no roster epoch — None.
-            rec["round"] = r
-            rec["epoch"] = None
-            rec["coordinator"] = coord
-            if timings is not None:
-                timings.append(rec)
-            if trace_rounds:
-                _telemetry.emit(
-                    "driver.round", round=r, party=me, peer=coord,
-                    t_start=t_r0_wall,
-                    dur_s=_time.perf_counter() - t_r0,
-                    detail={
+                # Every controller advances its state replica from the
+                # round's byte-agreed broadcast pair (the broadcast IS the
+                # post-step model) — all replicas stay byte-identical with
+                # zero extra wire bytes (fl.server_opt).
+                sopt.resync(x_srv, _np.asarray(avg.buf))
+            if wire_quant is not None:
+                # What the grid must cover next round: how far the global
+                # model just moved, per block.  Derived from broadcast
+                # values only, so it is bit-identical on every controller
+                # (under server_opt: the POST-step delta — the grid ranges
+                # over the model movement the step actually realized).
+                # d2h of the aggregate + a numpy pass over the model.
+                with _telemetry.span("fl.quant.delta"):
+                    quant_prev_delta = (
+                        _np.asarray(avg.buf).astype(_np.float32)
+                        - round_ref
+                    )
+            if compress_wire:
+                avg = decompress(avg)
+            if legacy_opt is not None:
+                current, state = legacy_opt.apply(current, avg, state)
+            else:
+                current = avg
+            if on_round is not None:
+                on_round(r, current)
+            if checkpoint_every and (r + 1) % checkpoint_every == 0:
+                snap = {"params": current}
+                if state is not None:
+                    snap["server_state"] = state
+                if sopt is not None:
+                    snap["server_state"] = sopt.state
+                checkpointer.save(
+                    r + 1, snap, metadata={"server_opt": sopt_descr}
+                )
+            if rec is not None:
+                # The aggregation call blocks on this party's own training
+                # output before any byte can move, so its measured walls
+                # include the local wait — subtract it to report the comms-
+                # only window (what overlap=True would hide).
+                rec["push_s"] = max(0.0, rec["push_s"] - rec["local_s"])
+                rec["agg_s"] = max(0.0, rec["agg_s"] - rec["local_s"])
+                # Correlation stamp: the SAME keys the transport rides on
+                # every frame (wire.ROUND_TAG_KEY / EPOCH_TAG_KEY), so a
+                # timings row joins the wire's view of its round on one
+                # timeline.  Classic fedavg has no roster epoch — None.
+                rec["round"] = r
+                rec["epoch"] = None
+                rec["coordinator"] = coord
+                if timings is not None:
+                    timings.append(rec)
+                if round_span is not None:
+                    round_span.detail = {
                         k: (round(v, 6) if isinstance(v, float) else v)
                         for k, v in rec.items()
-                    },
+                    }
+                logger.debug(
+                    "round %d timings: local=%.3fs push=%.3fs agg=%.3fs "
+                    "hidden=%.3fs", r, rec["local_s"], rec["push_s"],
+                    rec["agg_s"], rec["hidden_s"],
                 )
-            logger.debug(
-                "round %d timings: local=%.3fs push=%.3fs agg=%.3fs "
-                "hidden=%.3fs", r, rec["local_s"], rec["push_s"],
-                rec["agg_s"], rec["hidden_s"],
-            )
 
     return current

@@ -22,21 +22,17 @@ is a real subsystem, in three layers:
 - **span traces** — the federated flight recorder
   (:mod:`rayfed_tpu.telemetry`): structured cross-party span/event
   records, merged timelines (Perfetto export), and critical-path round
-  reports (``tool/trace_report.py``).  :func:`trace_span` /
-  :func:`start_profile` / :func:`stop_profile` remain the thin
-  ``jax.profiler`` hooks for on-device (XLA) timelines — the flight
-  recorder covers the cross-party protocol layer those never see.
+  reports (``tool/trace_report.py``).  Timing a block
+  (``telemetry.span``) and taking a device profile
+  (``telemetry.start_profile`` / ``stop_profile``) live there too: one
+  recorder, one entry point each.
 """
 
 from __future__ import annotations
 
 import collections
-import contextlib
 import threading
-import time
 from typing import Any, Dict, Optional
-
-import jax
 
 from rayfed_tpu.runtime import get_runtime_or_none
 
@@ -222,30 +218,3 @@ def metrics_snapshot() -> Dict[str, Any]:
         },
     }
     return out
-
-
-@contextlib.contextmanager
-def trace_span(name: str, **kwargs):
-    """Annotate a block on the jax profiler timeline (no-op cost when no
-    trace is being captured)."""
-    with jax.profiler.TraceAnnotation(name, **kwargs):
-        yield
-
-
-def start_profile(log_dir: str) -> None:
-    """Begin a jax profiler capture (TensorBoard-viewable)."""
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_profile() -> None:
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def timed(out: Dict[str, float], key: str):
-    """Accumulate wall time of a block into ``out[key]``."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        out[key] = out.get(key, 0.0) + (time.perf_counter() - t0)

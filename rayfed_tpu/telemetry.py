@@ -36,6 +36,18 @@ Arming:
 - ``JobConfig.trace = True``, or
 - :func:`install` directly from tests/benches.
 
+Two ways to write a record, one ring: :func:`emit` (and
+:func:`phase_spanner`) for sites that learn a span's bounds after the
+fact and back-date it (``wire.*``, ``agg.*``), and :func:`span` for a
+block the calling thread runs itself.  A scoped span knows the span
+that caused it (``detail["parent"]``, from a thread-local stack),
+inherits ``party`` and ``round`` from it, and while armed is also a
+``jax.profiler.TraceAnnotation``: under :func:`start_profile` it lands
+on the host plane of the same ``.xplane.pb`` as the device operations.
+Back-dated records cannot be annotations; the ``trace.anchor`` pair
+:func:`start_profile` writes (one annotation, one record holding the
+same instant) maps their wall clock onto the trace's.
+
 Cross-party collection: :func:`rayfed_tpu.api.trace_collect` pulls each
 peer's ring window over the existing transport (an observer-consumed
 request frame + a nonce-keyed DATA reply — the BLOB_GET shape), aligns
@@ -50,7 +62,6 @@ merges everything into one timeline.  Renderers: :func:`to_trace_events`
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import threading
 import time
@@ -199,6 +210,9 @@ class FlightRecorder:
 # ---------------------------------------------------------------------------
 
 _ACTIVE: Optional[FlightRecorder] = None
+# ``jax.profiler.TraceAnnotation``, looked up when a recorder is armed:
+# disarmed, this module never imports jax.
+_ANNOTATION: Any = None
 
 
 def install(
@@ -207,7 +221,10 @@ def install(
 ) -> FlightRecorder:
     """Arm the flight recorder process-wide; returns it.  Re-installing
     replaces the ring (tests that want a fresh window)."""
-    global _ACTIVE
+    global _ACTIVE, _ANNOTATION
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
     _ACTIVE = FlightRecorder(party=party, capacity=capacity)
     return _ACTIVE
 
@@ -291,26 +308,155 @@ def phase_spanner(prefix: str, **static_kw: Any):
     return mark
 
 
-@contextlib.contextmanager
-def span(phase: str, **kw: Any):
-    """Time a block as one span.  Disarmed cost: one global read and a
-    generator frame — use only at non-hot sites (per round / per pull /
-    per checkpoint, not per frame)."""
+class _Disarmed:
+    """What :func:`span` hands out with no recorder armed: one shared
+    context manager that does nothing and yields ``None``."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
+
+_DISARMED = _Disarmed()
+
+# Per-thread stack of the open scoped spans (innermost last).
+_tls = threading.local()
+
+
+def _thread_party() -> Optional[str]:
+    """The party whose runtime the calling thread is bound to: a span
+    opened on a worker of an in-process party (one recorder, several
+    parties) names its own party, not the recorder's."""
+    from rayfed_tpu.runtime import get_runtime_or_none
+
+    runtime = get_runtime_or_none()
+    return None if runtime is None else runtime.party
+
+
+class Span:
+    """One open scoped span; :func:`span` makes it, ``with`` yields it.
+
+    The block may set ``nbytes`` and ``detail`` on it (what it only
+    knows once the work is done); ``parent`` is the phase of the
+    enclosing span of this thread, or ``None``."""
+
+    __slots__ = (
+        "phase", "party", "round", "epoch", "peer", "stream", "nbytes",
+        "detail", "parent", "_rec", "_t_wall", "_t0", "_annotation",
+    )
+
+    def __init__(self, rec, phase, party, round, epoch, peer, stream,
+                 nbytes, detail) -> None:
+        self._rec = rec
+        self.phase = phase
+        self.party, self.round, self.epoch = party, round, epoch
+        self.peer, self.stream = peer, stream
+        self.nbytes, self.detail = nbytes, detail
+        self.parent: Optional[str] = None
+
+    def __enter__(self) -> "Span":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if stack:
+            outer = stack[-1]
+            self.parent = outer.phase
+            if self.party is None:
+                self.party = outer.party
+            if self.round is None:
+                self.round = outer.round
+        if self.party is None:
+            self.party = _thread_party()
+        stack.append(self)
+        self._annotation = _ANNOTATION(self.phase)
+        self._annotation.__enter__()
+        self._t_wall = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dur_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        _tls.stack.pop()
+        detail = self.detail
+        if self.parent is not None:
+            detail = dict(detail or (), parent=self.parent)
+        self._rec.emit(
+            self.phase, t_start=self._t_wall, dur_s=dur_s,
+            round=self.round, epoch=self.epoch, peer=self.peer,
+            stream=self.stream, nbytes=self.nbytes,
+            outcome="ok" if exc_type is None else "error",
+            detail=detail, party=self.party,
+        )
+        return False
+
+
+def span(
+    phase: str,
+    *,
+    party: Optional[str] = None,
+    round: Optional[int] = None,
+    epoch: Optional[int] = None,
+    peer: Optional[str] = None,
+    stream: Optional[str] = None,
+    nbytes: int = 0,
+    detail: Optional[Dict[str, Any]] = None,
+):
+    """Time a block of the calling thread as one span — the one way to
+    time a block.
+
+    Disarmed: ONE global read; the ``with`` yields ``None`` (no clock
+    read, no dict, no annotation), so a site sets what it learns inside
+    the block behind ``if sp is not None``.  Armed: yields the open
+    :class:`Span`.  It records the enclosing span of this thread as
+    ``detail["parent"]`` (the span that caused it), inherits ``party``
+    and ``round`` from it when given none (a top-level span takes the
+    party the thread is bound to), and is entered as a
+    ``jax.profiler.TraceAnnotation`` too, so a device profile taken
+    through :func:`start_profile` shows it on its host plane.  An
+    exception leaving the block stamps ``outcome="error"``."""
     rec = _ACTIVE
     if rec is None:
-        yield
-        return
+        return _DISARMED
+    return Span(rec, phase, party, round, epoch, peer, stream, nbytes,
+                detail)
+
+
+# ---------------------------------------------------------------------------
+# Device profile: host spans and device operations in one file
+# ---------------------------------------------------------------------------
+
+ANCHOR_PHASE = "trace.anchor"
+
+
+def start_profile(log_dir: str) -> None:
+    """Begin a ``jax.profiler`` capture (an ``.xplane.pb`` under
+    ``log_dir``, TensorBoard- and Perfetto-viewable) — the single entry
+    point for a device profile.  Arm the recorder first: every scoped
+    span then appears on the capture's host plane beside the device
+    operations.  Right after the start this writes one
+    ``trace.anchor`` annotation and one ``trace.anchor`` record whose
+    ``t_start`` (also ``detail["time"]``) is the same instant on
+    ``time.time()``'s clock, so a back-dated record at wall time ``t``
+    sits at ``anchor annotation start + (t - anchor record t_start)``
+    on the trace's clock."""
+    import jax
+
+    jax.profiler.start_trace(log_dir)
     t_wall = time.time()
-    t0 = time.perf_counter()
-    try:
-        yield
-    except BaseException:
-        rec.emit(
-            phase, t_start=t_wall, dur_s=time.perf_counter() - t0,
-            outcome="error", **kw,
-        )
-        raise
-    rec.emit(phase, t_start=t_wall, dur_s=time.perf_counter() - t0, **kw)
+    with jax.profiler.TraceAnnotation(ANCHOR_PHASE):
+        pass
+    emit(ANCHOR_PHASE, t_start=t_wall, detail={"time": t_wall})
+
+
+def stop_profile() -> None:
+    import jax
+
+    jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

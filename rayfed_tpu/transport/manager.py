@@ -1139,6 +1139,9 @@ class TransportManager:
                     try:
                         f.result()
                         self._peers_acked.add(p)
+                        # Billed BEFORE the result ref resolves below:
+                        # CleanupManager.wait_sending() returns on that
+                        # ref, and by then the counters hold this send.
                         self.stats["send_bytes"] += nbytes
                         self.stats["send_seconds"] += dt
                         self.transfer_log.record(

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from rayfed_tpu.checkpoint import FedCheckpointer
-from rayfed_tpu.metrics import TransferLog, timed, trace_span
+from rayfed_tpu.metrics import TransferLog
 
 
 @pytest.mark.parametrize("use_orbax", [True, False])
@@ -84,14 +84,6 @@ def test_transfer_log_throughput():
     for i in range(10):
         log.record("send", "bob", str(i), "x", 1, 0.1)
     assert len(log.records()) == 4
-
-
-def test_trace_span_and_timed():
-    out = {}
-    with timed(out, "block"):
-        with trace_span("test-span"):
-            jnp.ones((4,)).block_until_ready()
-    assert out["block"] > 0
 
 
 def test_stats_through_fed_api():

@@ -529,3 +529,323 @@ def test_malformed_trace_request_gets_fast_error_reply(
         mgrs["alice"].collect_trace("bob", timeout_s=30)
     # Fast-fail: one round trip, nowhere near the 30s park.
     assert time.perf_counter() - t0 < 10.0
+
+
+# ---------------------------------------------------------------------------
+# The round engine's own spans (driver, codec, task), the kernels' names
+# and the device-trace clock: two in-process parties, real loopback TCP
+# ---------------------------------------------------------------------------
+
+PARTIES = ("alice", "bob")
+UINT8 = dict(compress_wire=True, packed_wire=True, wire_quant="uint8",
+             streaming_agg=True)
+BF16_STREAM = dict(compress_wire=True, packed_wire=True, streaming_agg=True)
+PIPELINED = dict(compress_wire=True, packed_wire=True)
+
+
+def _run_rounds(round_kw, rounds=3, in_train=None):
+    """``rounds`` FedAvg rounds of a toy model between two in-process
+    parties; ``in_train(owner, round)`` runs at every entry into
+    ``train``.  Returns ``{party: (final flat f32, get_stats())}``,
+    read after ``wait_sending()`` and a barrier over the parties."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import rayfed_tpu as fed
+    from rayfed_tpu import inprocess
+    from rayfed_tpu.fl import compression
+    from rayfed_tpu.fl.trainer import run_fedavg_rounds
+    from rayfed_tpu.metrics import get_stats
+    from rayfed_tpu.runtime import get_runtime
+
+    all_done = threading.Barrier(len(PARTIES))
+
+    def party_main(party):
+        @fed.remote
+        class Trainer:
+            def __init__(self, owner):
+                self._owner, self._round = owner, 0
+
+            def train(self, bundle):
+                if in_train is not None:
+                    in_train(self._owner, self._round)
+                self._round += 1
+                step = 0.01 if self._owner == "alice" else 0.03
+                tree = compression.decompress(bundle, jnp.float32)
+                tree = jax.tree_util.tree_map(lambda x: x + step, tree)
+                return compression.compress(tree, packed=True)
+
+        trainers = {p: Trainer.party(p).remote(p) for p in PARTIES}
+        params = {"w": jnp.linspace(-1.0, 1.0, 3000), "b": jnp.zeros((7,))}
+        final = run_fedavg_rounds(trainers, params, rounds, **round_kw)
+        flat = np.concatenate([
+            np.asarray(x, np.float32).ravel()
+            for x in jax.tree_util.tree_leaves(final)
+        ])
+        get_runtime().cleanup_manager.wait_sending()
+        all_done.wait(timeout=60)
+        return flat, get_stats()
+
+    return inprocess.run_parties(
+        party_main, inprocess.loopback_cluster(PARTIES), timeout=120,
+        logging_level="warning",
+    )
+
+
+def _by_phase(records):
+    out = {}
+    for r in records:
+        out.setdefault(r.phase, []).append(r)
+    return out
+
+
+def test_uint8_round_emits_every_seam_span():
+    rec = telemetry.install(capacity=1 << 16)
+    _run_rounds(UINT8)
+    spans = _by_phase(rec.records())
+    assert {
+        "task.wait", "task.run", "driver.round", "fl.pack", "fl.unpack",
+        "fl.quant.ref", "fl.quant.grid", "fl.quant.encode",
+        "fl.quant.decode", "fl.quant.recode", "fl.quant.delta",
+    } <= set(spans), sorted(spans)
+    # Every new span names the party that did the work.
+    for phase in ("task.wait", "task.run", "driver.round", "fl.pack",
+                  "fl.quant.encode", "fl.quant.delta"):
+        assert {r.party for r in spans[phase]} == set(PARTIES), phase
+    # Children carry the span that caused them and inherit its round.
+    for phase in ("fl.quant.ref", "fl.quant.delta", "fl.quant.recode"):
+        for r in spans[phase]:
+            assert r.detail["parent"] == "driver.round", (phase, r)
+            assert r.round in (0, 1, 2), (phase, r)
+    assert all(r.nbytes == 3007 * 4 for r in spans["fl.quant.ref"])
+    # The downlink recode is the parent of its grid, encode and decode;
+    # the round comes down two levels.
+    down = [r for r in spans["fl.quant.grid"] if r.detail["side"] == "down"]
+    assert down and all(
+        r.detail["parent"] == "fl.quant.recode" and r.round in (1, 2)
+        for r in down
+    )
+    assert any(r.detail["side"] == "up" for r in spans["fl.quant.grid"])
+    under_recode = {
+        r.phase for rs in spans.values() for r in rs
+        if (r.detail or {}).get("parent") == "fl.quant.recode"
+    }
+    assert under_recode == {
+        "fl.quant.grid", "fl.quant.encode", "fl.quant.decode"
+    }
+    assert all(r.nbytes == 3007 for r in spans["fl.quant.encode"])
+    # Pack and unpack are told apart by parent: the engine's or the
+    # trainer's own.
+    for phase in ("fl.pack", "fl.unpack"):
+        parents = {r.detail["parent"] for r in spans[phase]}
+        assert parents == {"driver.round", "task.run"}, (phase, parents)
+    # task.*: what the trainer waited for and its own time, by name.
+    trains = [r for r in spans["task.wait"]
+              if r.detail["name"].endswith("train")]
+    assert len(trains) == 3 * len(PARTIES)
+    assert all(r.detail["queue_ms"] >= 0.0 for r in trains)
+    assert any(r.detail["name"] == "Trainer.train" for r in spans["task.run"])
+    # agg.fold keeps its duration and says what one fold kernel moves.
+    for r in spans["agg.fold"]:
+        assert r.detail["chunk_elems"] > 0 and "drain_ms" in r.detail
+        assert r.detail["acc"] in ("int32", "float32")
+    # The f32 fold of the bootstrap round is jitted: its drain is timed.
+    assert any(
+        r.detail["fold"] == "jit" and r.detail["drain_ms"] >= 0.0
+        for r in spans["agg.fold"]
+    )
+
+
+def test_recorder_armed_mid_call_is_seen_from_the_next_round():
+    """The benchmark (and an operator) arms the recorder in the middle
+    of ``run_fedavg_rounds``: the armed state is read once a round."""
+    import threading
+
+    lock = threading.Lock()
+
+    def arm_in_round_one(owner, round_):
+        with lock:
+            if round_ == 1 and telemetry.installed() is None:
+                telemetry.install(capacity=1 << 16)
+
+    _run_rounds(BF16_STREAM, rounds=4, in_train=arm_in_round_one)
+    rounds_seen = {
+        (r.party, r.round) for r in telemetry.installed().records()
+        if r.phase == "driver.round"
+    }
+    assert {(p, r) for p in PARTIES for r in (2, 3)} <= rounds_seen
+    assert not any(r == 0 for _, r in rounds_seen)
+
+
+def test_pipelined_path_emits_dispatch_and_stays_lazy():
+    import numpy as np
+
+    def slow_first_round(owner, round_):
+        if round_ == 0:
+            time.sleep(0.3)
+
+    plain = _run_rounds(PIPELINED, rounds=4, in_train=slow_first_round)
+    rec = telemetry.install(capacity=1 << 16)
+    traced = _run_rounds(PIPELINED, rounds=4, in_train=slow_first_round)
+    for p in PARTIES:  # tracing changes no result
+        assert np.array_equal(plain[p][0], traced[p][0])
+    spans = _by_phase(rec.records())
+    assert "driver.round" not in spans  # no round boundary on this path
+    for p in PARTIES:
+        dispatch = {r.round: r for r in spans["driver.dispatch"]
+                    if r.party == p}
+        assert sorted(dispatch) == [0, 1, 2, 3]
+        first_train_end = min(
+            r.t_start + r.dur_s for r in spans["task.run"]
+            if r.party == p and r.detail["name"].endswith("train")
+        )
+        # Rounds 0-2 were enqueued while the first train still ran:
+        # nothing was materialized for a span's sake.  Only the last
+        # round waits for its aggregate, as before.
+        for r in (0, 1, 2):
+            assert (dispatch[r].t_start + dispatch[r].dur_s
+                    < first_train_end), (p, r)
+        assert dispatch[3].t_start + dispatch[3].dur_s > first_train_end
+    # On this path the trainer's wait for the aggregate is its task.wait.
+    waits = [r.dur_s for r in spans["task.wait"]
+             if r.detail["name"].endswith("train")]
+    assert max(waits) > 0.0
+
+
+def test_disarmed_round_records_nothing_and_builds_no_annotation(monkeypatch):
+    import jax.profiler
+
+    built = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        built.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    _run_rounds(UINT8)
+    assert built == [] and telemetry.installed() is None
+    # The same patch does see an armed span's annotation.
+    rec = telemetry.install()
+    with telemetry.span("fl.pack"):
+        pass
+    assert built == ["fl.pack"] and len(rec.records()) == 1
+
+
+def test_scoped_spans_share_the_device_trace_clock(tmp_path):
+    """One profile, host spans and device operations together: a scoped
+    span's annotation sits where its record says, measured from the
+    ``trace.anchor`` pair."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    rec = telemetry.install(capacity=1 << 16)
+    telemetry.start_profile(str(tmp_path))
+    try:
+        _run_rounds(UINT8, rounds=2)
+    finally:
+        telemetry.stop_profile()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    starts = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("trace.anchor", "fl.quant.ref", "task.run"):
+                    starts.setdefault(e.name, []).append(e.start_ns)
+    records = _by_phase(rec.records())
+    (anchor_ns,), (anchor,) = starts["trace.anchor"], records["trace.anchor"]
+    assert anchor.detail["time"] == anchor.t_start
+    for phase in ("fl.quant.ref", "task.run"):
+        on_trace = sorted((ns - anchor_ns) / 1e9 for ns in starts[phase])
+        on_wall = sorted(r.t_start - anchor.t_start for r in records[phase])
+        assert len(on_trace) == len(on_wall) > 0
+        for a, b in zip(on_trace, on_wall):
+            assert abs(a - b) < 2e-3, (phase, a, b)
+
+
+def _kernel_cases():
+    import jax.numpy as jnp
+
+    from rayfed_tpu.fl import fedavg, quantize, streaming
+
+    f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    grid = (jnp.ones(1, f32), jnp.zeros(1, f32))  # scales, zps
+    return {
+        "fed_fold_i32": (
+            fedavg.quantized_accum_kernel(8, "uint8"),
+            (jnp.zeros(8, i32), jnp.ones(8, u8), i32(0), i32(1)),
+        ),
+        "fed_fold_f32": (
+            streaming._accum_kernel(8, "float32", "bfloat16"),
+            (jnp.zeros(8, f32), jnp.ones(8, jnp.bfloat16), i32(0), f32(1)),
+        ),
+        "fed_quant_encode": (
+            quantize._quantize_kernel(8, 8, "uint8", True),
+            (jnp.ones(8, f32), jnp.zeros(8, f32), *grid, jnp.zeros(8, f32)),
+        ),
+        "fed_quant_decode": (
+            quantize._dequantize_kernel(8, 8, "uint8", "float32", True),
+            (jnp.ones(8, u8), jnp.zeros(8, f32), *grid),
+        ),
+        "fed_finalize_f32": (
+            fedavg._stripe_finalize_jit(8, "float32"),
+            (jnp.zeros(8, f32), f32(2)),
+        ),
+        "fed_finalize_i32": (
+            fedavg._quant_finalize_jit(8, 8, "float32", True),
+            (jnp.zeros(8, i32), jnp.zeros(8, f32), *grid, f32(2)),
+        ),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "fed_fold_i32", "fed_fold_f32", "fed_quant_encode", "fed_quant_decode",
+    "fed_finalize_f32", "fed_finalize_i32",
+])
+def test_kernels_are_named_in_the_trace(name):
+    """A device trace names a program after its jitted function: the
+    fold, codec and finalize kernels each say what ran."""
+    kernel, args = _kernel_cases()[name]
+    assert kernel.__name__ == name
+    assert f"module @jit_{name} " in kernel.lower(*args).as_text()
+
+
+# ---------------------------------------------------------------------------
+# wait_sending(): every tracked send is billed when it returns
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("round_kw", [UINT8, PIPELINED],
+                         ids=["streaming-uint8", "pipelined-bf16"])
+def test_send_bytes_are_billed_when_wait_sending_returns(round_kw):
+    out = _run_rounds(round_kw)
+    sent = sum(stats["send_bytes"] for _, stats in out.values())
+    received = sum(stats["receive_bytes"] for _, stats in out.values())
+    assert sent == received > 0
+
+
+def test_wait_sending_drains_a_ref_pushed_behind_its_sentinel():
+    import threading
+
+    from rayfed_tpu.cleanup import CleanupManager
+    from rayfed_tpu.executor import LocalRef
+
+    cm = CleanupManager()
+    first, late = LocalRef(), LocalRef()
+    cm.push_to_sending(first)
+    waiter = threading.Thread(target=cm.wait_sending, daemon=True)
+    waiter.start()
+    time.sleep(0.1)  # the sentinel is queued behind ``first``
+    cm.push_to_sending(late)  # lands behind the sentinel
+    first.set_result(True)
+    waiter.join(timeout=0.5)
+    assert waiter.is_alive()  # still waiting: ``late`` is tracked too
+    late.set_result(True)
+    waiter.join(timeout=10)
+    assert not waiter.is_alive() and not cm.check_thread_alive
