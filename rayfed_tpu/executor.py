@@ -70,7 +70,13 @@ class LocalRef:
         self._future.set_exception(exc)
 
     def add_done_callback(self, fn: Callable[["LocalRef"], None]) -> None:
-        self._future.add_done_callback(lambda _f: fn(self))
+        # The callback gets a fresh ref around the same future, not
+        # ``self``: a future keeps its callbacks after it is done, and a
+        # callback that closed over ``self`` would make ref -> future ->
+        # callback -> ref a cycle.  Whatever the callback holds (a round's
+        # aggregator and codec, with their device buffers) would then wait
+        # for the cycle collector instead of going with the last ref.
+        self._future.add_done_callback(lambda f: fn(LocalRef(f)))
 
     def then(
         self,

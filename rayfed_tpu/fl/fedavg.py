@@ -336,7 +336,8 @@ def finalize_packed_quantized(
 
     ``ref`` (delta-coded rounds): the shared reference buffer the codes
     were taken against — a flat f32 array of ``total_elems`` elements
-    (a stripe owner passes its stripe-compacted slice).
+    (a stripe owner passes its stripe-compacted slice), read where it
+    lives: a device array is not fetched, a host array is uploaded.
 
     The quantized sibling of :func:`finalize_packed_stripe`, and like
     it the SINGLE producer of the output bytes for every topology: the
@@ -349,9 +350,11 @@ def finalize_packed_quantized(
     """
     import jax.numpy as jnp
 
+    from rayfed_tpu.fl.quantize import _flat_f32
+
     with_ref = ref is not None
     if with_ref:
-        ref = jnp.asarray(np.asarray(ref).reshape(-1), jnp.float32)
+        ref = _flat_f32(ref)
         if int(ref.size) != int(total_elems):
             raise ValueError(
                 f"reference has {ref.size} elements, finalize covers "

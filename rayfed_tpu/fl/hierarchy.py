@@ -1267,9 +1267,12 @@ class HierarchyRound:
                 meta_check=lambda v: check_region_meta(v, want),
                 quant=self._grid,
                 quant_blocks=stripes[m],
+                # A stripe is a host buffer: its reference slice is cut
+                # from host bytes, fetched here.
                 quant_ref=(
                     None if self._qref is None else _stripe_slice(
-                        self._qref, stripes[m], ce, total_elems
+                        np.asarray(self._qref), stripes[m], ce,
+                        total_elems,
                     )
                 ),
             )

@@ -24,10 +24,22 @@ This module is the **codec half** of the compressed-domain split:
   canonical chunking every fold schedule already uses).  Derived
   deterministically from a reference buffer every controller holds
   identically, so "negotiation" is a pure function: the coordinator's
-  grid and every party's grid are bit-identical by construction, the
+  grid and every party's grid are bit-identical, the
   compact descriptor rides every quantized frame's metadata
   (``wire.QUANT_GRID_KEY``), and the aggregator REJECTS any
-  contribution whose grid fingerprint differs from its own.  The round
+  contribution whose grid fingerprint differs from its own.  The
+  derivation reads the buffer where it lives: ONE device reduction
+  (``jit_fed_quant_stats``, :func:`block_stats`) gives every block's
+  min, max and float32 sum of squares, those ``nblocks x 3`` numbers
+  are all that crosses to the host, and :func:`make_round_grid`
+  finishes in numpy over them.  What the bit-identity rests on: min and
+  max are exact on any backend; the global RMS (the range floor) is the
+  exactly rounded float64 sum (``math.fsum``) of the per-block sums, so
+  it depends on each block's float32 sum alone — and that sum's order
+  is the compiler's, so controllers agree to the bit when they compile
+  the same program for the same kind of device (one process's parties,
+  a federation of like accelerators, CPUs on the same jaxlib).  The
+  fingerprint check is the loud guard for anything else.  The round
   loop uses ``mode="delta"``: parties code ``update − shared model``
   on a grid ranged by the PREVIOUS round's observed aggregate delta —
   per-round updates are orders of magnitude smaller than the params,
@@ -75,6 +87,7 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import zlib
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
@@ -287,6 +300,112 @@ def check_descriptor(descriptor: Any, grid: QuantGrid) -> None:
             )
 
 
+class BlockStats(NamedTuple):
+    """Per-block range statistics of one flat buffer, held where the
+    buffer lives: all :func:`make_round_grid` needs of it.
+
+    ``stats`` is an ``(nblocks, 3)`` float32 array of ``[min, max, sum
+    of squares]`` per canonical block; it stays a device array until a
+    grid is derived from it, and those ``nblocks x 12`` bytes are then
+    all that crosses to the host.
+    """
+
+    stats: Any
+    chunk_elems: int
+    total_elems: int
+
+
+@functools.lru_cache(maxsize=None)
+def _stats_kernel(chunk_elems: int, total_elems: int, with_ref: bool):
+    """ONE reduction over the whole packed buffer: per canonical block
+    the min, the max and the sum of squares of ``buf [- ref]`` in
+    float32.  The short tail block is reduced at its own length, which
+    is what padding it by its last value gave (a zero pad would drag
+    the range toward 0 for tail blocks that never contain 0).  A loop
+    over the blocks, each one fused slice-subtract-reduce: neither the
+    difference nor a padded copy is materialized (as one reshaped
+    reduction the TPU compiler keeps both, 260 MB at 31 M elements)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    whole, tail = divmod(total_elems, chunk_elems)
+
+    @jax.jit
+    def fed_quant_stats(buf, ref):
+        buf = buf.reshape(-1)
+
+        def block(start, size):
+            value = lax.dynamic_slice(buf, (start,), (size,))
+            value = value.astype(jnp.float32)
+            if with_ref:
+                value = value - lax.dynamic_slice(ref, (start,), (size,))
+            return jnp.stack(
+                [value.min(), value.max(), (value * value).sum()]
+            )
+
+        rows = []
+        if whole:
+            rows.append(lax.map(
+                lambda i: block(i * chunk_elems, chunk_elems),
+                jnp.arange(whole),
+            ))
+        if tail:
+            rows.append(block(whole * chunk_elems, tail)[None])
+        return jnp.concatenate(rows)
+
+    return fed_quant_stats
+
+
+def _flat_f32(ref: Any) -> Any:
+    """A reference buffer as a flat float32 device array: a device
+    array stays where it is, a host array is uploaded (once, here)."""
+    import jax.numpy as jnp
+
+    if isinstance(ref, PackedTree):
+        ref = ref.buf
+    return jnp.asarray(ref, jnp.float32).reshape(-1)
+
+
+def block_stats(
+    buf: Any, ref: Optional[Any] = None, chunk_elems: Optional[int] = None,
+) -> BlockStats:
+    """Dispatch the grid-statistics kernel over ``buf [- ref]`` where
+    the buffer lives (a numpy buffer is put on the default device) and
+    return its result WITHOUT fetching it: a round loop takes the
+    statistics of the broadcast it just decoded while the chip is idle,
+    and :func:`make_round_grid` reads them a round later.  The
+    difference itself is never materialized.
+    """
+    import jax.numpy as jnp
+
+    if isinstance(buf, PackedTree):
+        buf = buf.buf
+    total = int(getattr(buf, "size", 0))
+    if total == 0:
+        raise ValueError(
+            "cannot derive a quantization grid from an empty buffer"
+        )
+    if chunk_elems is None:
+        from rayfed_tpu.fl.streaming import DEFAULT_CHUNK_ELEMS
+
+        chunk_elems = DEFAULT_CHUNK_ELEMS
+    ce = int(chunk_elems)
+    with_ref = ref is not None
+    if with_ref:
+        ref = _flat_f32(ref)
+        if int(ref.size) != total:
+            raise ValueError(
+                f"reference buffer has {int(ref.size)} elements, the "
+                f"buffer has {total}"
+            )
+    else:
+        ref = jnp.zeros(0, jnp.float32)  # unused placeholder arg
+    return BlockStats(
+        _stats_kernel(ce, total, with_ref)(buf, ref), ce, total
+    )
+
+
 def make_round_grid(
     reference: Any,
     chunk_elems: Optional[int] = None,
@@ -299,7 +418,9 @@ def make_round_grid(
     """Derive a shared grid from a reference range buffer.
 
     ``reference``: a buffer every controller holds **bit-identically**
-    whose per-block value range predicts the values to be coded.  For
+    whose per-block value range predicts the values to be coded (a
+    device array, a numpy array, a :class:`PackedTree`, or the
+    :class:`BlockStats` :func:`block_stats` already took of one).  For
     the round loop's ``mode="delta"`` uplink that is the PREVIOUS
     round's aggregate delta (``agg_r − agg_{r-1}``): per-party deltas
     live at the same scale, so the grid step lands orders of magnitude
@@ -307,10 +428,12 @@ def make_round_grid(
     ambient parameter range (the first round, with no observed delta
     yet, runs unquantized — the driver's bootstrap).  For ``mode=
     "abs"`` it is the values themselves (e.g. the aggregate the
-    coordinator is about to broadcast).  The derivation is pure numpy
-    over the shared buffer, so every controller computes the identical
-    grid with no extra wire hop — that IS the negotiation, pinned by
-    the fingerprint check on every quantized frame.
+    coordinator is about to broadcast).  The derivation is one device
+    reduction over the shared buffer (``jit_fed_quant_stats``) and a
+    numpy finish over its ``nblocks x 3`` results, so every controller
+    computes the identical grid with no extra wire hop — that IS the
+    negotiation, pinned by the fingerprint check on every quantized
+    frame (see the module docstring for what the identity rests on).
 
     Per block: the value range is the block's [min, max] expanded by
     ``expand`` around its midpoint (values drift past the reference
@@ -322,42 +445,30 @@ def make_round_grid(
     clip-everything trap), then mapped affinely onto the integer
     range.  ``min_scale`` floors the fully-degenerate all-zero case.
 
-    Flight recorder: ``fl.quant.grid`` (numpy min/max over every
-    block; ``detail.side`` is ``up``, or ``down`` under a recode).
+    Flight recorder: ``fl.quant.grid`` (the kernel where the reference
+    is a buffer, the fetch of the statistics, the numpy finish;
+    ``nbytes`` is what crossed to the host, ``nblocks x 12``;
+    ``detail.side`` is ``up``, or ``down`` under a recode;
+    ``detail.fp`` is the grid's fingerprint, the one receivers check).
     """
     with telemetry.span("fl.quant.grid") as sp:
-        if sp is not None:
-            # The coordinator's downlink recode derives its own grid.
-            sp.detail = {
-                "side": "down" if sp.parent == "fl.quant.recode" else "up"
-            }
-        if isinstance(reference, PackedTree):
-            reference = reference.buf
-        arr = np.asarray(reference).reshape(-1).astype(np.float32)
-        if arr.size == 0:
+        if not isinstance(reference, BlockStats):
+            reference = block_stats(reference, chunk_elems=chunk_elems)
+        ce, total = reference.chunk_elems, reference.total_elems
+        if chunk_elems is not None and int(chunk_elems) != ce:
             raise ValueError(
-                "cannot derive a quantization grid from an empty buffer"
+                f"block statistics were taken at {ce} elems/block, the "
+                f"grid is asked for at {int(chunk_elems)}"
             )
-        if chunk_elems is None:
-            from rayfed_tpu.fl.streaming import DEFAULT_CHUNK_ELEMS
-
-            chunk_elems = DEFAULT_CHUNK_ELEMS
-        ce = int(chunk_elems)
         qmin, qmax = _qrange(wire_dtype)
-        from rayfed_tpu.fl.fedavg import packed_block_grid
-
-        nb = packed_block_grid(arr.size, ce)
-        total = arr.size
-        rms = float(np.sqrt(np.mean(np.square(arr, dtype=np.float64))))
-        # Pad the tail block with its last value: min/max of the padded row
-        # equal the true block min/max (a zero pad would drag the range
-        # toward 0 for tail blocks that never contain 0).
-        pad = nb * ce - total
-        if pad:
-            arr = np.concatenate([arr, np.full(pad, arr[-1], np.float32)])
-        a2 = arr.reshape(nb, ce)
-        lo = a2.min(axis=1)
-        hi = a2.max(axis=1)
+        stats = np.asarray(reference.stats)
+        if sp is not None:
+            sp.nbytes = stats.nbytes
+        lo, hi = stats[:, 0], stats[:, 1]
+        # Global RMS from the per-block float32 sums, combined here in
+        # float64: fsum is exactly rounded, so the result depends on
+        # the blocks' sums alone, not on how they are grouped.
+        rms = math.sqrt(math.fsum(stats[:, 2].tolist()) / total)
         mid = 0.5 * (hi + lo)
         half = np.maximum(
             0.5 * (hi - lo) * np.float32(expand),
@@ -369,7 +480,14 @@ def make_round_grid(
             (hi - lo) / np.float32(qmax - qmin), np.float32(min_scale)
         ).astype(np.float32)
         zps = (qmin - lo / scales).astype(np.float32)
-        return QuantGrid(scales, zps, ce, total, wire_dtype, mode)
+        grid = QuantGrid(scales, zps, ce, total, wire_dtype, mode)
+        if sp is not None:
+            # The coordinator's downlink recode derives its own grid.
+            sp.detail = {
+                "side": "down" if sp.parent == "fl.quant.recode" else "up",
+                "fp": grid.fingerprint(),
+            }
+        return grid
 
 
 class QuantizedPackedTree(PackedTree):
@@ -718,14 +836,12 @@ class RoundCodec:
             compressor(scope)
             if grid is not None and scope is not None else None
         )
-        self.ref: Optional[np.ndarray] = None
+        self.ref: Optional[Any] = None
         self.descriptor: Optional[Dict[str, Any]] = None
         if grid is not None:
             self.descriptor = grid_descriptor(grid)
             if ref is not None:
-                if isinstance(ref, PackedTree):
-                    ref = ref.buf
-                self.ref = np.asarray(ref).reshape(-1).astype(np.float32)
+                self.ref = _flat_f32(ref)
 
     def to_wire(self, value: Any) -> Any:
         """This party's contribution in wire form: quantized onto the
@@ -763,7 +879,7 @@ class RoundCodec:
 def quantize_downlink(
     result: Any,
     grid: QuantGrid,
-    ref: Optional[np.ndarray],
+    ref: Optional[Any],
     scope: Optional[str],
     out_dtype: Any = np.float32,
 ) -> Tuple[QuantizedPackedTree, Any, Dict[str, Any]]:
@@ -785,27 +901,29 @@ def quantize_downlink(
     automatically ranged by the post-step delta — no new metadata key,
     no schema change.  ``scope`` keys the downlink's own
     error-feedback residual (``{scope}/down``); None quantizes
-    statelessly.  Flight recorder: ``fl.quant.recode``, the parent of
+    statelessly.  The aggregate and ``ref`` stay where they live (a
+    host ``ref`` is uploaded once): the statistics, encode and decode
+    kernels all read them there, and what crosses to the host is the
+    grid's statistics and the codes the wire ships.  Flight recorder:
+    ``fl.quant.recode`` (``nbytes``: the codes' bytes), the parent of
     its grid, encode and decode spans.
     """
-    with telemetry.span("fl.quant.recode"):
+    with telemetry.span("fl.quant.recode") as sp:
         if ref is not None:
-            down_src = np.asarray(result.buf).astype(np.float32) - ref
-            down_grid = make_round_grid(
-                down_src, chunk_elems=grid.chunk_elems,
-                wire_dtype=grid.wire_dtype, mode="delta",
-            )
-        else:
-            down_grid = make_round_grid(
-                result.buf, chunk_elems=grid.chunk_elems,
-                wire_dtype=grid.wire_dtype, mode="abs",
-            )
+            ref = _flat_f32(ref)
+        down_grid = make_round_grid(
+            block_stats(result, ref, chunk_elems=grid.chunk_elems),
+            wire_dtype=grid.wire_dtype,
+            mode="abs" if ref is None else "delta",
+        )
         dcomp = compressor(f"{scope}/down") if scope is not None else None
         wire_result = (
             dcomp.quantize(result, down_grid, ref=ref)
             if dcomp is not None
             else quantize_packed(result, down_grid, ref=ref)
         )
+        if sp is not None:
+            sp.nbytes = wire_result.buf.nbytes
         decoded = wire_result.dequantize(np.dtype(out_dtype), ref=ref)
         if dcomp is not None:
             dcomp.commit()

@@ -495,7 +495,10 @@ def ring_aggregate(
     from rayfed_tpu.fl.quantize import RoundCodec
 
     codec = RoundCodec(quant, quant_ref, quant_scope)
-    qref = codec.ref
+    # The codec codes against the reference where it lives; the stripes
+    # are host buffers, so their reference slices are cut from host
+    # bytes, fetched here.
+    qref = None if codec.ref is None else np.asarray(codec.ref)
     q_descriptor = codec.descriptor
     _to_wire = codec.to_wire
     _quant_commit = codec.commit

@@ -232,7 +232,8 @@ class StreamingAggregator:
         self._int_weights: Optional[List[int]] = None
         # Delta-coded rounds: the shared reference buffer (flat f32;
         # every controller holds it bit-identically) the finalize adds
-        # back after the single fused rescale.  A StripeAggregator gets
+        # back after the single fused rescale, kept on the device (a
+        # host buffer is uploaded here, once).  A StripeAggregator gets
         # its stripe-compacted slice.
         self._quant_ref = None
         # Subclasses (StripeAggregator) fold a block SUBSET of the grid;
@@ -246,7 +247,9 @@ class StreamingAggregator:
                         "a mode='delta' grid needs quant_ref= (the "
                         "round's shared reference buffer)"
                     )
-                self._quant_ref = np.asarray(quant_ref).reshape(-1)
+                from rayfed_tpu.fl.quantize import _flat_f32
+
+                self._quant_ref = _flat_f32(quant_ref)
             elif quant_ref is not None:
                 raise ValueError(
                     "quant_ref only applies to mode='delta' grids"

@@ -959,8 +959,17 @@ def run_fedavg_rounds(
     # Compressed-domain state: the previous round's observed aggregate
     # delta (shared — derived from broadcast values only), the range
     # reference for the next round's grid.  None until one round has
-    # been observed, so the first round always runs unquantized.
+    # been observed, so the first round always runs unquantized.  Held
+    # as its per-block statistics (fl.quantize.BlockStats), all the grid
+    # needs of it.
     quant_prev_delta = None
+    # The grid chunking must BE the fold/stripe chunking: a ring round
+    # with an overridden ring_chunk_elems quantizes on that same grid, or
+    # ring_aggregate's chunk-match guard would abort (and silently fall
+    # back) every quantized round.
+    quant_chunk_elems = (
+        ring_chunk_elems if mode in ("ring", "hierarchy") else None
+    )
 
     sa_keys = None
     sa_session = None
@@ -1087,25 +1096,20 @@ def run_fedavg_rounds(
                 from rayfed_tpu.fl import quantize as _qz
                 from rayfed_tpu.fl.compression import pack_tree
 
-                # d2h of the whole model as float32.
-                with _telemetry.span("fl.quant.ref") as sp:
-                    round_ref = _np.asarray(
-                        pack_tree(current, _jnp.float32).buf
-                    )
-                    if sp is not None:
-                        sp.nbytes = round_ref.nbytes
+                # The model as one float32 buffer, where it lives: the
+                # codec, the aggregator and the delta read it there.
+                with _telemetry.span("fl.quant.ref"):
+                    round_ref = pack_tree(current, _jnp.float32).buf
+                # That buffer is the model until the round's aggregate
+                # replaces it: the trainers hold `outgoing`, nothing
+                # below reads the tree.  Letting it go keeps ONE float32
+                # model on the device, as when the reference was a host
+                # array (two parties' steps share the chip's memory).
+                current = None
                 if quant_prev_delta is not None:
                     round_grid = _qz.make_round_grid(
                         quant_prev_delta, wire_dtype=_qname, mode="delta",
-                        # The grid chunking must BE the fold/stripe
-                        # chunking: a ring round with an overridden
-                        # ring_chunk_elems quantizes on that same grid, or
-                        # ring_aggregate's chunk-match guard would abort
-                        # (and silently fall back) every quantized round.
-                        chunk_elems=(
-                            ring_chunk_elems
-                            if mode in ("ring", "hierarchy") else None
-                        ),
+                        chunk_elems=quant_chunk_elems,
                         # Per-party deltas overshoot the aggregate delta
                         # (the mean averages them down) — give the grid
                         # headroom; what still clips rides the EF residual.
@@ -1301,12 +1305,16 @@ def run_fedavg_rounds(
                 # values only, so it is bit-identical on every controller
                 # (under server_opt: the POST-step delta — the grid ranges
                 # over the model movement the step actually realized).
-                # d2h of the aggregate + a numpy pass over the model.
+                # Its block statistics are taken now, on the device and
+                # while the chip is idle; make_round_grid fetches them
+                # next round.  Nothing crosses to the host here.
                 with _telemetry.span("fl.quant.delta"):
-                    quant_prev_delta = (
-                        _np.asarray(avg.buf).astype(_np.float32)
-                        - round_ref
+                    quant_prev_delta = _qz.block_stats(
+                        avg.buf, round_ref, chunk_elems=quant_chunk_elems
                     )
+                # Let go before the unpack below makes the next model's
+                # leaves: two model-sized buffers at a time, not three.
+                round_ref = None
             if compress_wire:
                 avg = decompress(avg)
             if legacy_opt is not None:

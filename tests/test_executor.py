@@ -64,6 +64,40 @@ def test_is_local_refs():
     assert not is_local_refs([])
 
 
+@pytest.mark.parametrize("chained", [False, True])
+def test_done_callback_goes_with_the_last_ref_not_the_collector(chained):
+    """A callback registered BEFORE its ref resolves stays on the future;
+    it must not close a ref -> future -> callback -> ref cycle, or what
+    it holds (a round's aggregator and codec: device buffers) waits for
+    the cycle collector."""
+    import gc
+    import weakref
+
+    class Held:
+        pass
+
+    held = Held()
+    alive = weakref.ref(held)
+    seen = []
+    gc.disable()
+    try:
+        ref = LocalRef()
+        if chained:
+            out = ref.then(lambda v, held=held: seen.append(v))
+        else:
+            ref.add_done_callback(
+                lambda r, held=held: seen.append(r.resolve())
+            )
+        ref.set_result(7)
+        assert seen == [7]
+        del held, ref
+        if chained:
+            del out
+        assert alive() is None
+    finally:
+        gc.enable()
+
+
 class Counter:
     def __init__(self, start):
         self.value = start

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 import rayfed_tpu as fed
-from rayfed_tpu import inprocess
+from rayfed_tpu import inprocess, telemetry
 from rayfed_tpu.fl import compression as C
 from rayfed_tpu.fl import quantize as qz
 from rayfed_tpu.fl.streaming import StreamingAggregator
@@ -44,8 +44,8 @@ def _flat(tree) -> np.ndarray:
     )
 
 
-def _fedavg_in_process(parties, wire_kw):
-    """Run ROUNDS of FedAvg with ``parties`` as threads; returns
+def _fedavg_in_process(parties, wire_kw, rounds=ROUNDS):
+    """Run ``rounds`` of FedAvg with ``parties`` as threads; returns
     ``({party: final flat f32}, {party: wire bytes sent},
     {party: uplink error-feedback residual},
     {(party, round): update flat f32})``."""
@@ -81,7 +81,7 @@ def _fedavg_in_process(parties, wire_kw):
             for i, p in enumerate(parties)
         }
         params = logistic.init_logistic(jax.random.PRNGKey(0), D, CLASSES)
-        final = run_fedavg_rounds(trainers, params, ROUNDS, **wire_kw)
+        final = run_fedavg_rounds(trainers, params, rounds, **wire_kw)
         from rayfed_tpu.metrics import get_stats
 
         resid = qz.compressor("fedavg").residual
@@ -139,6 +139,50 @@ def test_inprocess_fedavg_matches_numpy_reference(n_parties, wire_form):
 
     # The bytes really crossed the wire between distinct parties.
     assert all(sent[p] > 0 for p in parties), sent
+
+
+def test_quantized_round_keeps_reference_and_delta_on_the_device():
+    """The engagement counter of the device-resident quantized round:
+    ``nbytes`` of the four ``fl.quant.*`` spans is what crossed to the
+    host inside them.  One bootstrap round, then three quantized ones."""
+    parties = ["alice", "bob"]
+    rounds = 4
+    rec = telemetry.install(capacity=1 << 16)
+    try:
+        finals, _, resids, _ = _fedavg_in_process(
+            parties, WIRE_FORMS["uint8"], rounds=rounds
+        )
+    finally:
+        telemetry.uninstall()
+    total = D * CLASSES + CLASSES
+    assert resids["alice"].size == total
+    nb = 1  # one canonical block covers the toy model
+    spans = {}
+    for r in rec.records():
+        if r.phase.startswith("fl.quant."):
+            spans.setdefault(r.phase, []).append(r)
+
+    quantized = range(1, rounds)
+    for phase in ("fl.quant.ref", "fl.quant.delta"):
+        seen = {(r.party, r.round) for r in spans[phase]}
+        assert seen == {(p, q) for p in parties for q in range(rounds)}
+        assert all(r.nbytes == 0 for r in spans[phase]), phase
+    grids = spans["fl.quant.grid"]
+    assert grids and all(r.nbytes == nb * 12 for r in grids)
+    recodes = spans["fl.quant.recode"]
+    assert [r.round for r in recodes] == list(quantized)
+    assert all(r.nbytes == total for r in recodes)  # uint8 codes
+
+    # Every controller derived the round's uplink grid itself, from the
+    # statistics it took of the decoded broadcast: same fingerprint.
+    for q in quantized:
+        fps = {
+            r.party: r.detail["fp"] for r in grids
+            if r.round == q and r.detail["side"] == "up"
+        }
+        assert set(fps) == set(parties), (q, fps)
+        assert len(set(fps.values())) == 1, (q, fps)
+    assert finals["alice"].tobytes() == finals["bob"].tobytes()
 
 
 def test_shutdown_of_one_party_leaves_the_others_working():

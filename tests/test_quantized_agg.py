@@ -80,6 +80,112 @@ def test_grid_derivation_deterministic_and_fingerprinted():
         qz.check_descriptor(dict(gd, fp=gd["fp"] ^ 1), g1)
 
 
+def _numpy_round_grid(reference, chunk_elems, wire_dtype="uint8",
+                      expand=1.25, min_scale=1e-12, floor_frac=0.05):
+    """The derivation as it was before the statistics moved to the
+    device (PR 27): pure numpy over the whole buffer, a float64 mean for
+    the RMS.  Kept as the plain reference of ``make_round_grid``."""
+    qmin, qmax = {"uint8": (0, 255), "int8": (-128, 127)}[wire_dtype]
+    arr = np.asarray(reference).reshape(-1).astype(np.float32)
+    total = arr.size
+    nb = -(-total // chunk_elems)
+    rms = float(np.sqrt(np.mean(np.square(arr, dtype=np.float64))))
+    pad = nb * chunk_elems - total
+    if pad:
+        arr = np.concatenate([arr, np.full(pad, arr[-1], np.float32)])
+    a2 = arr.reshape(nb, chunk_elems)
+    lo, hi = a2.min(axis=1), a2.max(axis=1)
+    mid = 0.5 * (hi + lo)
+    half = np.maximum(
+        0.5 * (hi - lo) * np.float32(expand),
+        np.float32(float(floor_frac) * rms),
+    )
+    lo, hi = mid - half, mid + half
+    scales = np.maximum(
+        (hi - lo) / np.float32(qmax - qmin), np.float32(min_scale)
+    ).astype(np.float32)
+    return scales, (qmin - lo / scales).astype(np.float32)
+
+
+def _grid_case(name):
+    rng = np.random.default_rng(7)
+    if name == "tail_padded":  # tail block far from 0: a zero pad would show
+        return (5.0 + 0.01 * rng.normal(size=2 * CE + 777)).astype(np.float32)
+    if name == "exact_multiple":
+        return (0.01 * rng.normal(size=3 * CE)).astype(np.float32)
+    if name == "constant_block":  # block 1 has no range: the RMS floor engages
+        buf = (0.01 * rng.normal(size=3 * CE + 5)).astype(np.float32)
+        buf[CE:2 * CE] = 0.25
+        return buf
+    if name == "all_zeros":  # no range, no RMS: min_scale
+        return np.zeros(2 * CE + 3, np.float32)
+    if name == "bf16":
+        return jnp.asarray(
+            0.01 * rng.normal(size=2 * CE + 100), jnp.bfloat16
+        )
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("mode", ["abs", "delta"])
+@pytest.mark.parametrize(
+    "case",
+    ["tail_padded", "exact_multiple", "constant_block", "all_zeros", "bf16"],
+)
+def test_grid_from_device_statistics_matches_numpy_derivation(case, mode):
+    buf = _grid_case(case)
+    expand = 4.0 if mode == "delta" else 1.25
+    host = qz.make_round_grid(
+        np.asarray(buf), chunk_elems=CE, mode=mode, expand=expand
+    )
+    dev = qz.make_round_grid(
+        jnp.asarray(buf), chunk_elems=CE, mode=mode, expand=expand
+    )
+    # One path: where the buffer lives does not reach the grid's bits.
+    assert host.fingerprint() == dev.fingerprint()
+    assert host.mode == mode and host.total_elems == buf.size
+    # Statistics taken ahead of time (the round loop's way) give that
+    # grid too, and a chunking they were not taken at is refused.
+    st = qz.block_stats(jnp.asarray(buf), chunk_elems=CE)
+    assert st.stats.shape == (host.nblocks, 3)
+    assert qz.make_round_grid(
+        st, mode=mode, expand=expand
+    ).fingerprint() == host.fingerprint()
+    with pytest.raises(ValueError, match="elems/block"):
+        qz.make_round_grid(st, chunk_elems=2 * CE, mode=mode)
+
+    scales, zps = _numpy_round_grid(buf, CE, expand=expand)
+    # min and max are exact; the RMS comes from float32 block sums, so
+    # a floored scale may differ in float32's last digits, no more.
+    np.testing.assert_allclose(host.scales, scales, rtol=2e-6, atol=0)
+    np.testing.assert_allclose(host.zps, zps, rtol=2e-6, atol=1e-3)
+    if case == "all_zeros":
+        assert np.all(host.scales == np.float32(1e-12))
+    if case == "constant_block":
+        assert host.scales[1] > 0 and host.scales[1] == pytest.approx(
+            2 * 0.05 * float(np.sqrt(np.mean(
+                np.square(np.asarray(buf, np.float64))
+            ))) / 255, rel=1e-5,
+        )
+
+
+def test_block_stats_of_a_difference_never_materialize_it():
+    """``block_stats(buf, ref)`` ranges ``buf - ref`` (the downlink
+    recode, the round loop's delta) from device or host buffers alike."""
+    rng = np.random.default_rng(3)
+    ref = rng.normal(size=2 * CE + 9).astype(np.float32)
+    buf = ref + (0.01 * rng.normal(size=ref.size)).astype(np.float32)
+    want = qz.make_round_grid(buf - ref, chunk_elems=CE)
+    for b, r in ((buf, ref), (jnp.asarray(buf), jnp.asarray(ref)),
+                 (jnp.asarray(buf), ref)):
+        st = qz.block_stats(b, r, chunk_elems=CE)
+        assert isinstance(st.stats, jax.Array)
+        assert qz.make_round_grid(st).fingerprint() == want.fingerprint()
+    with pytest.raises(ValueError, match="reference buffer has"):
+        qz.block_stats(buf, ref[:-1], chunk_elems=CE)
+    with pytest.raises(ValueError, match="empty buffer"):
+        qz.block_stats(np.zeros(0, np.float32))
+
+
 def test_grid_floor_keeps_degenerate_blocks_usable():
     # A constant block's [min, max] range is empty; the dispersion
     # floor must keep its scale proportional to the buffer's RMS

@@ -620,7 +620,12 @@ def test_uint8_round_emits_every_seam_span():
         for r in spans[phase]:
             assert r.detail["parent"] == "driver.round", (phase, r)
             assert r.round in (0, 1, 2), (phase, r)
-    assert all(r.nbytes == 3007 * 4 for r in spans["fl.quant.ref"])
+    # nbytes is what crossed to the host inside the span (re-pinned in
+    # PR 27: the reference and the delta stay on the device, a grid
+    # fetches 12 bytes a block, a recode the codes).
+    for phase, crossed in (("fl.quant.ref", 0), ("fl.quant.delta", 0),
+                           ("fl.quant.grid", 12), ("fl.quant.recode", 3007)):
+        assert all(r.nbytes == crossed for r in spans[phase]), phase
     # The downlink recode is the parent of its grid, encode and decode;
     # the round comes down two levels.
     down = [r for r in spans["fl.quant.grid"] if r.detail["side"] == "down"]

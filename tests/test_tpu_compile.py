@@ -133,6 +133,20 @@ def _quantized_finalize():
     )
 
 
+def _quant_stats(total=None):
+    from rayfed_tpu.fl.quantize import _stats_kernel
+
+    # The delta's statistics, as the round loop and the downlink recode
+    # take them: at ResNet-18's size (a padded tail block), or at the
+    # benchmark's r=64 adapters (15 whole blocks, 126 MB as float32).
+    chunk = _resnet18_grid()[1]
+    total = total or _resnet18_grid()[0]
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    return _stats_kernel(chunk, total, True).lower(
+        *_on_chip((f32((total,)), f32((total,))))
+    )
+
+
 def _mesh_fedavg_step():
     from examples import mesh_fedavg
 
@@ -156,6 +170,8 @@ CASES = {
     "quantized_accum_kernel_resnet18": (_quantized_accum, False),
     "finalize_packed_quantized_resnet18": (_quantized_finalize, False),
     "mesh_fedavg_step_4chip_mesh": (_mesh_fedavg_step, False),
+    "quant_stats_resnet18": (_quant_stats, False),
+    "quant_stats_qlora_r64": (lambda: _quant_stats(15 << 21), False),
 }
 
 
@@ -166,6 +182,13 @@ def test_compiles_for_v5e(case):
     lowered = lower()
     compiled = lowered.compile()  # raises what the chip's compiler raises
     assert ("tpu_custom_call" in compiled.as_text()) == wants_kernel
+    if case.startswith("quant_stats"):
+        # Only the statistics leave the kernel (three floats a block, in
+        # one tile), and it keeps no model-sized temporary: the
+        # difference is never materialized.
+        memory = compiled.memory_analysis()
+        assert memory.output_size_in_bytes <= 4096
+        assert memory.temp_size_in_bytes < 1 << 20
     if case.startswith("mesh_fedavg"):
         # Each of the four chips holds its quarter of the 8 MiB leaf.
         out_bytes = compiled.memory_analysis().output_size_in_bytes
