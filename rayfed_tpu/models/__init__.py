@@ -14,15 +14,21 @@ Families cover the BASELINE.md configs:
 - :mod:`resnet`    — ResNet-18 for CIFAR-10 (config #3)
 - :mod:`bert`      — BERT-style encoder, split-FL friendly (config #5)
 - :mod:`llama`     — Llama-3-style decoder (RoPE/GQA/SwiGLU) (config #4)
+- :mod:`decoder`   — decoder described layer by layer (window / full
+  attention, dense / routed + shared experts, q/k norm, output gate)
 - :mod:`lora`      — LoRA adapters over any linear param (config #4)
-- :mod:`moe`       — mixture-of-experts layer, expert-parallel over ep
+- :mod:`moe`       — mixture-of-experts layer, expert-parallel over ep,
+  and one chip's share of a published sparse-expert layer
 - :mod:`quant`     — int8 weight-only quantization (frozen bases, KV)
 - :mod:`hf`        — Hugging Face Llama checkpoint conversion
   (logit-parity verified against ``transformers``)
 """
 
-from rayfed_tpu.models import bert, hf, llama, logistic, lora, moe, quant, resnet
+from rayfed_tpu.models import (
+    bert, decoder, hf, llama, logistic, lora, moe, quant, resnet,
+)
 
 __all__ = [
-    "logistic", "resnet", "bert", "llama", "lora", "moe", "quant", "hf",
+    "logistic", "resnet", "bert", "llama", "decoder", "lora", "moe", "quant",
+    "hf",
 ]

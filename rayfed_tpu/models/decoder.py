@@ -1,0 +1,425 @@
+"""A decoder whose block is described layer by layer.
+
+:mod:`rayfed_tpu.models.llama` is one stacked scan of identical layers
+(one attention kind, one FFN, model-wide).  Here every layer has a
+:class:`LayerSpec`: its attention kind (a sliding window with rotary
+positions, or full causal attention with no position embedding) and its
+FFN kind (dense SwiGLU, or routed + shared experts through
+:func:`rayfed_tpu.models.moe.apply_expert_share`).  The block also has
+what the sparse-expert decoders published since 2025 share: an RMS norm
+of q and k over the head width, a sigmoid gate on the attention output,
+norms on both sides of each sub-block, a scaled embedding.
+
+Consecutive layers with one FFN kind are a GROUP: their parameters are
+stacked on a leading dim and the forward pass is one ``lax.scan`` a
+group (one compiled body however many layers; ``remat`` is
+``jax.checkpoint`` of that body, as in ``llama.py``, which saves the
+experts selected beside the layer's input: a selection made again from
+recomputed scores need not be the one the forward pass used).  Where a group
+mixes attention kinds the body picks the layer's kernel with a ``cond``
+on a scanned flag (the flash kernel's ``window`` is static).
+``params["layers"]`` is the list of groups; an adapter tree from
+:func:`rayfed_tpu.models.lora.init_lora` mirrors it with the group's
+index as a string.  :func:`unstack` gives either tree layer by layer.
+
+The first configuration that runs through it is the AFMoE family's
+(``benchmark/families/afmoe_lm.py``, reference in
+``benchmark/reference/afmoe.py``).  Helpers are shared with
+``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
+``_linear``, ``lm_loss``, ``_adam_update``); ``llama.py``'s own programs
+do not pass through this module.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+import functools
+import threading
+import time
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from rayfed_tpu import telemetry
+from rayfed_tpu.models import moe
+from rayfed_tpu.models.llama import (
+    _adam_update,
+    _linear,
+    _rms_norm,
+    apply_rope,
+    lm_loss,
+    rope_tables,
+)
+from rayfed_tpu.ops.attention import dot_product_attention
+
+Params = Dict[str, Any]
+
+# Every linear matrix of the block but the router (LoraConfig.targets).
+ALL_LINEAR = (r"/w[qkvoz]$", r"/w_(gate|up|down)$")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    attention: str = "window"  # "window" (with RoPE) | "full" (no positions)
+    ffn: str = "dense"  # "dense" | "moe"
+
+    def __post_init__(self):
+        if self.attention not in ("window", "full"):
+            raise ValueError(f"unknown attention kind {self.attention!r}")
+        if self.ffn not in ("dense", "moe"):
+            raise ValueError(f"unknown ffn kind {self.ffn!r}")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    layers: Tuple[LayerSpec, ...]
+    vocab_size: int = 25024
+    hidden_size: int = 2048
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 6144  # the dense FFN's width
+    sliding_window: int = 2048
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    embed_scale: float = 1.0  # the residual stream starts at embed * this
+    experts: Optional[moe.ExpertShareConfig] = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    remat: bool = False
+
+    def __post_init__(self):
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if any(s.ffn == "moe" for s in self.layers) and self.experts is None:
+            raise ValueError("a layer with ffn='moe' needs config.experts")
+
+    def groups(self) -> Tuple[Tuple[int, int], ...]:
+        """``(first layer, one past the last)`` of every run of
+        consecutive layers with one FFN kind."""
+        out, start = [], 0
+        for i in range(1, len(self.layers) + 1):
+            if i == len(self.layers) or self.layers[i].ffn != self.layers[start].ffn:
+                out.append((start, i))
+                start = i
+        return tuple(out)
+
+
+def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
+    """Random weights: normal ``fan_in ** -0.5`` (the embedding 0.02, the
+    published initializer range, so that the scaled embedding is of the
+    size the sub-blocks' normed outputs add to), norms at one.  Every
+    layer draws from a key of its own; a group's layers are stacked."""
+    c = config
+    d, dh, h, kv = c.hidden_size, c.head_dim, c.num_heads, c.num_kv_heads
+    pdt = c.param_dtype
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+
+    def dense(key, *shape, fan_in):
+        return (jax.random.normal(key, shape) * fan_in**-0.5).astype(pdt)
+
+    def layer(key, spec: LayerSpec) -> Params:
+        ks = jax.random.split(key, 9)
+        ones = lambda n: jnp.ones((n,), pdt)
+        lp = {
+            "attn_norm": ones(d),
+            "wq": dense(ks[0], d, h * dh, fan_in=d),
+            "wk": dense(ks[1], d, kv * dh, fan_in=d),
+            "wv": dense(ks[2], d, kv * dh, fan_in=d),
+            "wz": dense(ks[3], d, h * dh, fan_in=d),
+            "q_norm": ones(dh),
+            "k_norm": ones(dh),
+            "wo": dense(ks[4], h * dh, d, fan_in=h * dh),
+            "post_attn_norm": ones(d),
+            "mlp_norm": ones(d),
+            "post_mlp_norm": ones(d),
+        }
+        if spec.ffn == "dense":
+            f = c.intermediate_size
+            lp["w_gate"] = dense(ks[5], d, f, fan_in=d)
+            lp["w_up"] = dense(ks[6], d, f, fan_in=d)
+            lp["w_down"] = dense(ks[7], f, d, fan_in=f)
+        else:
+            lp["moe"] = moe.init_expert_share(ks[8], c.experts, pdt)
+        return lp
+
+    keys = jax.random.split(k_layers, len(c.layers))
+    stack = lambda *leaves: jnp.stack(leaves)
+    return {
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, d)) * 0.02
+                  ).astype(pdt),
+        "layers": [
+            jax.tree_util.tree_map(stack, *(
+                layer(keys[i], c.layers[i]) for i in range(start, stop)
+            ))
+            for start, stop in c.groups()
+        ],
+        "final_norm": jnp.ones((d,), pdt),
+        "lm_head": dense(k_head, d, c.vocab_size, fan_in=d),
+    }
+
+
+def unstack(tree: Params, config: DecoderConfig) -> Params:
+    """``tree`` (parameters, or adapters mirroring them) with ``layers``
+    layer by layer: a list of per-layer dicts for parameters, a dict
+    keyed by the layer's index as a string for adapters.  What a reader
+    that knows nothing of groups takes (the plain reference)."""
+    groups = tree["layers"]
+    of_group = (lambda g: groups[g]) if isinstance(groups, list) else (
+        lambda g: groups.get(str(g), {})
+    )
+    layers = {}
+    for g, (start, stop) in enumerate(config.groups()):
+        for i in range(start, stop):
+            layers[i] = jax.tree_util.tree_map(
+                # an adapter's `scale` is one number for the whole group
+                lambda leaf: leaf[i - start] if jnp.ndim(leaf) else leaf,
+                of_group(g),
+            )
+    if isinstance(groups, list):
+        return dict(tree, layers=[layers[i] for i in sorted(layers)])
+    return dict(tree, layers={str(i): v for i, v in layers.items() if v})
+
+
+def embed(params: Params, input_ids: jax.Array, config: DecoderConfig):
+    """``[B, T]`` ids -> the residual stream before layer 0."""
+    x = params["embed"].astype(config.dtype)[input_ids]
+    return (x * jnp.asarray(config.embed_scale, config.dtype)).astype(
+        config.dtype
+    )
+
+
+def _attend(q, k, v, rope, *, kind, config, attn_fn):
+    with jax.named_scope(f"attn.{kind}"):
+        if kind == "full":
+            return attn_fn(q, k, v, causal=True)
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        return attn_fn(q, k, v, causal=True, window=config.sliding_window)
+
+
+def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
+                attn_fn: Callable = dot_product_attention,
+                lora: Optional[Params] = None):
+    """One layer on the stream ``x`` [B, T, D] -> (``x``, ``aux``).
+    ``lp`` (and ``lora``) are ONE layer's entries; ``attention`` is the
+    layer's kind, or a traced boolean "is windowed" (both kernels are
+    then in the program, under a ``cond``); ``aux`` is what
+    :func:`moe.apply_expert_share` reports, None for a dense FFN."""
+    c = config
+    b, t, _ = x.shape
+    h, kv, dh, dtype = c.num_heads, c.num_kv_heads, c.head_dim, c.dtype
+    lget = (lora or {}).get
+    rope = rope_tables(jnp.arange(t), dh, c.rope_theta)
+    with jax.named_scope("attn.proj"):
+        y = _rms_norm(x, lp["attn_norm"], c.rms_eps)
+        q = _linear(y, lp["wq"], lget("wq"), dtype).reshape(b, t, h, dh)
+        k = _linear(y, lp["wk"], lget("wk"), dtype).reshape(b, t, kv, dh)
+        v = _linear(y, lp["wv"], lget("wv"), dtype).reshape(b, t, kv, dh)
+        q = _rms_norm(q, lp["q_norm"], c.rms_eps)
+        k = _rms_norm(k, lp["k_norm"], c.rms_eps)
+        if kv != h:
+            k = jnp.repeat(k, h // kv, axis=2)
+            v = jnp.repeat(v, h // kv, axis=2)
+    attend = functools.partial(_attend, config=c, attn_fn=attn_fn)
+    if isinstance(attention, str):
+        o = attend(q, k, v, rope, kind=attention)
+    else:
+        o = jax.lax.cond(
+            attention, functools.partial(attend, kind="window"),
+            functools.partial(attend, kind="full"), q, k, v, rope,
+        )
+    with jax.named_scope("attn.proj"):
+        o = o.reshape(b, t, h * dh)
+        z = _linear(y, lp["wz"], lget("wz"), dtype)
+        o = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(dtype)
+        o = _linear(o, lp["wo"], lget("wo"), dtype)
+        x = x + _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
+
+    y = _rms_norm(x, lp["mlp_norm"], c.rms_eps)
+    aux = None
+    if ffn == "dense":
+        with jax.named_scope("ffn.dense"):
+            f = moe.swiglu(y, lp, lget, dtype)
+    else:
+        f, aux = moe.apply_expert_share(
+            lp["moe"], y.reshape(b * t, -1), c.experts, lora=lget("moe"),
+        )
+        f = f.reshape(b, t, -1)
+    return x + _rms_norm(f, lp["post_mlp_norm"], c.rms_eps), aux
+
+
+def _split_scalars(tree):
+    """``(arrays, rebuild)``: the tree's leaves that have a leading dim
+    to scan over, and ``rebuild(arrays)`` putting the scalars (an
+    adapter's ``scale``) back between them."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    scalar = [jnp.ndim(leaf) == 0 for leaf in leaves]
+
+    def rebuild(arrays):
+        it = iter(arrays)
+        return treedef.unflatten([
+            leaf if is_scalar else next(it)
+            for leaf, is_scalar in zip(leaves, scalar)
+        ])
+
+    return [l for l, s in zip(leaves, scalar) if not s], rebuild
+
+
+_SAVE_SELECTION = jax.checkpoint_policies.save_only_these_names(
+    "moe.selected"
+)
+
+
+def apply_decoder(
+    params: Params,
+    input_ids: jax.Array,
+    config: DecoderConfig,
+    *,
+    lora: Optional[Params] = None,
+    attn_fn: Callable = dot_product_attention,
+):
+    """``[B, T]`` ids -> (``[B, T, V]`` float32 logits, ``aux``).
+
+    ``aux`` maps each expert layer's index to what
+    :func:`moe.apply_expert_share` reports (``counts``,
+    ``held_assignments``, ``selected``, ``scores``).  ``lora`` mirrors
+    ``params`` (what :func:`rayfed_tpu.models.lora.init_lora` makes of
+    them: ``layers`` keyed by the group's index as a string).
+    """
+    c = config
+    x = embed(params, input_ids, c)
+    lora_groups = (lora or {}).get("layers", {})
+    aux = {}
+    for g, (start, stop) in enumerate(c.groups()):
+        specs = c.layers[start:stop]
+        kinds = {s.attention for s in specs}
+        adapters, rebuild = _split_scalars(lora_groups.get(str(g)))
+
+        def body(x, scanned, specs=specs, kinds=kinds, rebuild=rebuild):
+            lp, adapters, windowed = scanned
+            return apply_block(
+                x, lp, c, ffn=specs[0].ffn, attn_fn=attn_fn,
+                attention=specs[0].attention if len(kinds) == 1 else windowed,
+                lora=rebuild(adapters),
+            )
+
+        if c.remat:
+            body = jax.checkpoint(body, policy=_SAVE_SELECTION)
+        windowed = jnp.asarray([s.attention == "window" for s in specs])
+        with jax.named_scope(f"layers{start}-{stop - 1}"):
+            x, stacked = jax.lax.scan(
+                body, x, (params["layers"][g], adapters, windowed),
+            )
+        if stacked is not None:
+            for i in range(start, stop):
+                aux[i] = jax.tree_util.tree_map(lambda a: a[i - start], stacked)
+    x = _rms_norm(x, params["final_norm"], c.rms_eps)
+    logits = jax.lax.dot_general(
+        x.astype(c.dtype), params["lm_head"].astype(c.dtype),
+        (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    return logits, aux
+
+
+def routing_counts(aux) -> Optional[jax.Array]:
+    """``[expert layers, held + 1]`` int32: every expert layer's rows per
+    held expert, and in the last column its held assignments."""
+    if not aux:
+        return None
+    return jnp.stack([
+        jnp.concatenate([a["counts"], a["held_assignments"][None]])
+        for _, a in sorted(aux.items())
+    ])
+
+
+def lora_loss(lora, base, ids, config: DecoderConfig, *,
+              attn_fn: Callable = dot_product_attention):
+    """Next-token loss of ``ids`` [B, T] with adapters ``lora`` on the
+    frozen ``base``, and ``apply_decoder``'s ``aux``: what the LoRA step
+    differentiates."""
+    logits, aux = apply_decoder(base, ids, config, lora=lora, attn_fn=attn_fn)
+    return lm_loss(logits[:, :-1], ids[:, 1:]), aux
+
+
+def make_lora_train_step(
+    config: DecoderConfig,
+    lr: float = 1e-4,
+    *,
+    attn_fn: Callable = dot_product_attention,
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
+):
+    """Adam step over LoRA adapters only; base, router and selection
+    bias frozen.  ``(lora, opt, base, ids) -> (lora, opt, loss,
+    counts)`` with ``counts`` as :func:`routing_counts` gives them (None
+    for a decoder with no expert layer).  The jitted program is
+    ``step.jitted`` (``jit_decoder_lora_step`` on a device trace).
+
+    While the flight recorder is armed the step keeps each call's
+    counts, still on the device, and ``step.flush_routing()`` writes one
+    ``moe.counts`` record for every call the calling thread made since
+    its last flush (:func:`routing_detail`).  The flush fetches them, so
+    it waits for those steps: call it where the host waits anyway (the
+    end of a round).  Disarmed, nothing is kept or fetched."""
+
+    def loss_fn(lora, base, ids):
+        loss, aux = lora_loss(lora, base, ids, config, attn_fn=attn_fn)
+        return loss, routing_counts(aux)
+
+    def decoder_lora_step(lora, opt, base, ids):
+        (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            lora, base, ids
+        )
+        lora, opt = _adam_update(lora, grads, opt, lr, b1, b2, eps)
+        return lora, opt, loss, counts
+
+    jitted = jax.jit(decoder_lora_step)
+    kept = collections.defaultdict(list)  # thread -> [(t, counts, tokens)]
+
+    def step(lora, opt, base, ids):
+        out = jitted(lora, opt, base, ids)
+        if telemetry.armed() and out[3] is not None:
+            kept[threading.get_ident()].append((time.time(), out[3], ids.size))
+        return out
+
+    def flush_routing():
+        for t, counts, tokens in kept.pop(threading.get_ident(), ()):
+            telemetry.emit("moe.counts", t_start=t,
+                           detail=routing_detail(counts, config, tokens))
+
+    step.jitted, step.flush_routing = jitted, flush_routing
+    return step
+
+
+def routing_detail(counts, config: DecoderConfig, tokens: int) -> dict:
+    """The ``moe.counts`` record's detail from a step's counts (fetches
+    them).  Per expert layer the rows each held expert's grouped
+    products were given, the held share of the step's ``tokens * top_k``
+    assignments, and ``dropped``: held assignments that no product was
+    given (the layer has no capacity, so 0 unless the chunk loop skipped
+    rows)."""
+    import numpy as np
+
+    counts = np.asarray(counts)
+    moe_layers = [i for i, s in enumerate(config.layers) if s.ffn == "moe"]
+    assignments = tokens * config.experts.top_k
+    return {
+        "tokens": int(tokens),
+        "top_k": config.experts.top_k,
+        "held": list(config.experts.held),
+        # rows of one chunk of the sorted buffer (moe._routed's loop)
+        "chunk_rows": moe._chunk_rows(tokens, config.experts)[0],
+        "layers": [
+            {
+                "layer": i,
+                "counts": [int(v) for v in row[:-1]],
+                "held_share": float(row[-1]) / assignments,
+                "dropped": int(row[-1] - row[:-1].sum()),
+            }
+            for i, row in zip(moe_layers, counts)
+        ],
+        "dropped": int((counts[:, -1] - counts[:, :-1].sum(axis=1)).sum()),
+    }

@@ -10,16 +10,27 @@ all-to-alls when tokens and experts live on different mesh axes.
 
 Gating: top-k softmax gating with auxiliary load-balancing loss
 (Switch/GShard style).
+
+Two layers live here.  :func:`apply_moe` is the capacity-factor layer
+above (drops overflow).  :func:`apply_expert_share` is one chip's share
+of a published sparse-expert layer (sigmoid scores, a selection bias
+that takes no gradient, normalised and scaled weights, a shared
+expert): it is told which experts it holds, routes over all of them,
+and computes its own experts' part of the result for every token routed
+to them — no capacity, no drop, static shapes — through grouped matrix
+products on tokens sorted by expert.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
@@ -192,3 +203,415 @@ def apply_moe(
         "dropped_fraction": 1.0
         - jnp.mean(keep.any(axis=-1).astype(jnp.float32)),
     }
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of a sparse-expert layer (expert parallelism's unit)
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShareConfig:
+    """A routed + shared expert layer of which ``held`` experts live here.
+
+    ``num_experts`` is the router's width (every expert of the layer,
+    held or not); ``held`` the ids whose weights this chip has.  The
+    layer selects ``top_k`` of all ``num_experts`` per token and computes
+    the part of the result its own experts give; what the absent ones
+    would add is another chip's to compute and is not stood in for.
+    The held experts' matrices are the frozen base of an adapter
+    fine-tune: :func:`apply_expert_share` stops their gradient (their
+    weight-gradient grouped product is not built).
+    """
+
+    num_experts: int = 128
+    held: Tuple[int, ...] = tuple(range(16))
+    top_k: int = 8
+    d_model: int = 2048
+    d_ff: int = 1024  # width of one routed expert, and of the shared one
+    route_scale: float = 1.0
+
+    def __post_init__(self):
+        if len(set(self.held)) != len(self.held) or not self.held:
+            raise ValueError(f"held expert ids must be distinct: {self.held}")
+        if not all(0 <= e < self.num_experts for e in self.held):
+            raise ValueError(
+                f"held ids {self.held} outside the router's "
+                f"{self.num_experts} outputs"
+            )
+
+
+def init_expert_share(key: jax.Array, config: ExpertShareConfig,
+                      dtype=jnp.float32) -> Params:
+    """Random share: router over all experts, a non-zero selection bias
+    (so that a path that drops it is seen), the held experts stacked on
+    dim 0, one shared expert.  Normal, ``fan_in ** -0.5``."""
+    e, d, f = config.num_experts, config.d_model, config.d_ff
+    n = len(config.held)
+    ks = jax.random.split(key, 8)
+
+    def dense(key, *shape, fan_in):
+        return (jax.random.normal(key, shape) * fan_in**-0.5).astype(dtype)
+
+    return {
+        "router": dense(ks[0], d, e, fan_in=d),
+        "router_bias": jax.random.normal(ks[1], (e,), jnp.float32) * 0.1,
+        "experts": {
+            "w_gate": dense(ks[2], n, d, f, fan_in=d),
+            "w_up": dense(ks[3], n, d, f, fan_in=d),
+            "w_down": dense(ks[4], n, f, d, fan_in=f),
+        },
+        "shared": {
+            "w_gate": dense(ks[5], d, f, fan_in=d),
+            "w_up": dense(ks[6], d, f, fan_in=d),
+            "w_down": dense(ks[7], f, d, fan_in=f),
+        },
+    }
+
+
+def route_tokens(params: Params, x: jax.Array, config: ExpertShareConfig):
+    """``x`` [N, d] -> (``selected`` [N, k] expert ids, ``weights``
+    [N, k], ``scores`` [N, E]).
+
+    Scores are ``sigmoid`` of the float32 router output; the selection
+    is the top ``k`` of ``scores + params["router_bias"]`` (the bias
+    steers load and takes no gradient; it never enters the weights); the
+    weights are the selected scores normalised over ALL ``k`` selected
+    experts, held here or not, times ``route_scale``.  The selection
+    carries the checkpoint name ``"moe.selected"``: a rematerialised
+    layer must save it (``decoder.apply_decoder`` does), or its backward
+    pass selects again, from scores that another fusion of the same
+    arithmetic rounds differently, and differentiates another function
+    than the forward pass computed.
+    """
+    logits = jax.lax.dot_general(
+        x, params["router"].astype(x.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + params["router_bias"].astype(jnp.float32)
+    _, selected = jax.lax.top_k(jax.lax.stop_gradient(biased), config.top_k)
+    selected = checkpoint_name(selected, "moe.selected")
+    # The selected scores through a one-hot product, not a gather: on
+    # the TPU a gather of 65,536 scalars (and its scatter-add backward)
+    # costs a millisecond, the fused product nothing.
+    chosen = jax.nn.one_hot(selected, scores.shape[-1], dtype=scores.dtype)
+    picked = jnp.einsum("ne,nke->nk", scores, chosen)
+    weights = config.route_scale * picked / (
+        picked.sum(axis=-1, keepdims=True) + 1e-20
+    )
+    return selected, weights, scores
+
+
+def _take_rows_impl(x, rows):
+    # Out-of-range row ids (padding) read zeros.
+    return jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
+
+
+def _sum_rows_impl(y, rows, n_out):
+    # Out-of-range row ids (padding) are dropped.  A scatter-add of the
+    # chunk's rows: measured on the chip at a quarter of the time of the
+    # gather form (every token reading its top_k possible rows).
+    out = jnp.zeros((n_out, y.shape[1]), jnp.float32)
+    return out.at[rows].add(y.astype(jnp.float32), mode="drop").astype(y.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, rows, n_out):
+    """``out[r] = x[rows[r]]``: the dispatch gather (``n_out`` is
+    ``len(x)``).  Its transpose is :func:`_sum_rows`, written out so
+    that the pair stays a gather and a scatter-add of one chunk's rows
+    whichever way autodiff turns them."""
+    return _take_rows_impl(x, rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _sum_rows(y, rows, n_out):
+    """``out[t] = sum of y[r] over rows[r] == t``, ``t < n_out``: the
+    combine, transpose of :func:`_take_rows`."""
+    return _sum_rows_impl(y, rows, n_out)
+
+
+_take_rows.defvjp(
+    lambda x, rows, n_out: (_take_rows_impl(x, rows), rows),
+    lambda n_out, rows, g: (_sum_rows(g, rows, n_out), None),
+)
+_sum_rows.defvjp(
+    lambda y, rows, n_out: (_sum_rows_impl(y, rows, n_out), rows),
+    lambda n_out, rows, g: (_take_rows(g, rows, n_out), None),
+)
+
+
+def _grouped_impl() -> str:
+    """Which grouped product the sorted rows go through: ``"megablox"``
+    (the Pallas gmm jax ships; visits only the row tiles of held
+    experts) where kernels compile, ``"ragged"`` (``jax.lax.ragged_dot``)
+    on the CPU.  (The CPU tests of the kernel's wiring make it return
+    ``"megablox-interpret"``.)"""
+    return "ragged" if jax.default_backend() == "cpu" else "megablox"
+
+
+# Row, contraction and column tile of the megablox product: an expert's
+# whole [K, N/2] half-matrix stays in fast memory while its row tiles
+# pass, so the weights are read once a group.  Not swept on the chip;
+# the products read 45% of the bf16 peak with it (PERF.md section 5).
+GMM_TILING = (512, 2048, 512)
+# The sorted rows pass through the experts in chunks sized for the
+# expected held assignments of a call (tokens * top_k * held /
+# num_experts) times this headroom; a call takes as many chunks as its
+# assignments fill, so nothing is ever dropped.  A second chunk costs a
+# whole gather, product and scatter again, so the headroom is what keeps
+# the usual call at one: a sequence's own preferences move its held
+# share between 10 and 26% of its assignments where 12.5% is expected
+# (PERF.md section 6), and 2.5 holds 31%.  Padding rows are gathered and
+# scattered but not multiplied (the kernel visits held experts' tiles).
+CHUNK_HEADROOM = 2.5
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` [R, K] rows sorted by group, ``rhs`` [G, K, N],
+    ``group_sizes`` [G + 1] whose last entry counts the trailing rows
+    that belong to no group here: rows of group ``g`` times ``rhs[g]``;
+    the trailing rows come out zero and are not multiplied."""
+    impl = _grouped_impl()
+    with jax.named_scope("grouped_matmul"):
+        if impl == "ragged":
+            return jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1])
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        tiling = tuple(
+            min(want, have) for want, have in zip(
+                GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])
+            )
+        )
+        if lhs.shape[0] % tiling[0]:
+            raise ValueError(
+                f"{lhs.shape[0]} sorted rows are no multiple of the row "
+                f"tile {tiling[0]} (see _chunk_rows)"
+            )
+        return megablox.gmm(
+            lhs, rhs, group_sizes, lhs.dtype, tiling,
+            jnp.zeros((), jnp.int32), None, False,
+            impl == "megablox-interpret",
+        )
+
+
+def _expert_linear(xs, w, group_sizes, row_expert, lora_entry):
+    """Sorted rows through their experts' matrix ``w`` [G, in, out], plus
+    the experts' LoRA bypass.  The bypass is two dense products: every
+    row times all experts' ``A`` side by side ([in, G * rank], the MXU's
+    width at 16 experts of rank 8), masked to the row's own expert,
+    times the stacked ``B``: rank-sized grouped products would leave the
+    MXU idle, and an expert no row reached gets an exactly zero
+    gradient."""
+    out = grouped_matmul(xs, w, group_sizes)
+    if lora_entry is None:
+        return out
+    a, b = (lora_entry[side].astype(xs.dtype) for side in "ab")
+    scale = jax.lax.stop_gradient(lora_entry["scale"]).astype(xs.dtype)
+    g, d_in, rank = a.shape
+    own = jax.nn.one_hot(row_expert, g, dtype=xs.dtype)  # padding: zeros
+    u = xs @ a.transpose(1, 0, 2).reshape(d_in, g * rank)
+    u = (u.reshape(-1, g, rank) * own[:, :, None]).reshape(-1, g * rank)
+    return out + (u @ b.reshape(g * rank, -1)) * scale
+
+
+def swiglu(x, p, lget, dtype):
+    """``(silu(x Wg) * (x Wu)) Wd`` with each matrix's optional LoRA
+    entry from ``lget(name)``."""
+    from rayfed_tpu.models.llama import _linear
+
+    gate = jax.nn.silu(_linear(x, p["w_gate"], lget("w_gate"), dtype))
+    up = _linear(x, p["w_up"], lget("w_up"), dtype)
+    return _linear(gate * up, p["w_down"], lget("w_down"), dtype)
+
+
+def _chunk_sizes(static, c, group_sizes):
+    """``[held + 1]``: of the sorted rows ``[c * rows, (c + 1) * rows)``
+    how many are each held expert's, and how many (the rest) nobody's
+    here: what the grouped products of chunk ``c`` are given."""
+    _, n_held, rows = static
+    lo = c * rows
+    ends = jnp.cumsum(group_sizes[:n_held])
+    starts = ends - group_sizes[:n_held]
+    held = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    return jnp.concatenate([held, rows - held.sum(keepdims=True)])
+
+
+def _routed_chunk(static, c, x, weights, experts, elora, route):
+    """The held experts' part of the routed sum for the sorted rows
+    ``[c * rows, (c + 1) * rows)``: gather, three grouped products with
+    their adapters, weighted sum back onto the tokens."""
+    k, _, rows = static
+    n_tok, dtype = x.shape[0], x.dtype
+    order, sorted_local, group_sizes = route
+    lo = c * rows
+    with jax.named_scope("moe.dispatch"):
+        # The flat (token, choice) of each row of the chunk.  (Absent
+        # experts' assignments that fall inside it are padding rows: the
+        # grouped product returns zeros for them.)
+        row_slot = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        row_expert = jax.lax.dynamic_slice(sorted_local, (lo,), (rows,))
+        row_token = row_slot // k
+        xs = _take_rows(x, row_token, n_tok)
+        w_row = _take_rows(weights.reshape(-1, 1), row_slot, n_tok * k)
+        sizes = _chunk_sizes(static, c, group_sizes)
+    with jax.named_scope("moe.experts"):
+        lget = lambda name: None if elora is None else elora.get(name)
+        gate = _expert_linear(xs, experts["w_gate"], sizes, row_expert,
+                              lget("w_gate"))
+        up = _expert_linear(xs, experts["w_up"], sizes, row_expert,
+                            lget("w_up"))
+        ys = _expert_linear(jax.nn.silu(gate) * up, experts["w_down"], sizes,
+                            row_expert, lget("w_down"))
+    with jax.named_scope("moe.combine"):
+        ys = (ys.astype(jnp.float32) * w_row).astype(dtype)
+        return _sum_rows(ys, row_token, n_tok)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(static, chunks, x, weights, experts, elora, route):
+    """``(out, multiplied)``: the routed sum, and per held expert the
+    rows its grouped products were given.  The sorted rows pass through
+    the experts in chunks of ``rows`` rows, as many (``chunks``, counted
+    on the device) as the held assignments of this call fill: one as a
+    rule, up to every assignment of every token when routing collapses
+    onto this chip.  Shapes stay static, nothing is dropped, and the
+    buffers are one chunk's.  A loop of a length only the device knows
+    cannot be differentiated by tracing, so the backward pass is written
+    out: the same loop, each chunk's pullback summed.  ``experts`` are
+    the frozen base (see :func:`apply_expert_share`) and get a zero
+    cotangent."""
+    f32 = jnp.float32
+    n_held = static[1]
+
+    def body(c, carry):
+        acc, multiplied = carry
+        part = _routed_chunk(static, c, x, weights, experts, elora, route)
+        sizes = _chunk_sizes(static, c, route[2])
+        return acc + part.astype(f32), multiplied + sizes[:n_held]
+
+    out, multiplied = jax.lax.fori_loop(
+        0, chunks, body,
+        (jnp.zeros(x.shape, f32), jnp.zeros((n_held,), jnp.int32)),
+    )
+    return out.astype(x.dtype), multiplied
+
+
+def _routed_fwd(static, chunks, x, weights, experts, elora, route):
+    out = _routed(static, chunks, x, weights, experts, elora, route)
+    return out, (chunks, x, weights, experts, elora, route)
+
+
+def _routed_bwd(static, res, cts):
+    chunks, x, weights, experts, elora, route = res
+    g = cts[0]  # the counts' cotangent is float0
+    f32 = jnp.float32
+    wide = lambda tree: jax.tree_util.tree_map(
+        lambda v: jnp.zeros(v.shape, f32), tree
+    )
+
+    def body(c, acc):
+        _, pull = jax.vjp(
+            lambda x, weights, elora: _routed_chunk(
+                static, c, x, weights, experts, elora, route
+            ), x, weights, elora,
+        )
+        return jax.tree_util.tree_map(
+            lambda a, d: a + d.astype(f32), acc, pull(g)
+        )
+
+    dx, dw, dlora = jax.lax.fori_loop(
+        0, chunks, body, (wide(x), wide(weights), wide(elora))
+    )
+    narrow = lambda like, tree: jax.tree_util.tree_map(
+        lambda v, d: d.astype(v.dtype), like, tree
+    )
+    return (None, dx.astype(x.dtype), dw.astype(weights.dtype),
+            jax.tree_util.tree_map(jnp.zeros_like, experts),
+            narrow(elora, dlora), None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def _chunk_rows(n_tok: int, config: ExpertShareConfig) -> Tuple[int, int]:
+    """(rows of one chunk, most chunks a call can need).  A chunk holds
+    the expected held assignments of ``n_tok`` tokens with
+    :data:`CHUNK_HEADROOM` to spare, rounded up to the row tile."""
+    k, n_held = config.top_k, len(config.held)
+    tile = GMM_TILING[0]
+    worst = n_tok * min(k, n_held)
+    expected = n_tok * k * n_held / config.num_experts
+    rows = min(max(int(CHUNK_HEADROOM * expected), 1), worst)
+    if rows >= tile:
+        rows = -(-rows // tile) * tile
+    return rows, -(-worst // rows)
+
+
+def apply_expert_share(
+    params: Params,
+    x: jax.Array,
+    config: ExpertShareConfig,
+    *,
+    lora: Optional[Params] = None,
+):
+    """``x`` [N, d] -> (``out`` [N, d], ``aux``): the shared expert plus
+    the held experts' part of the routed sum.
+
+    ``aux``: ``counts`` [held] the rows each held expert's grouped
+    products were given, ``held_assignments`` () the (token, choice)
+    pairs whose expert is held (nothing is dropped, so the counts sum to
+    it), ``selected`` [N, k] the experts chosen, ``scores`` [N, E] the
+    sigmoid scores they were chosen by.  ``lora`` mirrors ``params``
+    (entries under ``experts`` and ``shared``; the router takes none).
+    The held experts' matrices are a FROZEN base: their gradient is
+    stopped here, so ``jax.grad`` with respect to them is zero by
+    statement, not by omission.
+    """
+    n_tok = x.shape[0]
+    k, n_held = config.top_k, len(config.held)
+    lora = lora or {}
+
+    with jax.named_scope("moe.route"):
+        selected, weights, scores = route_tokens(params, x, config)
+        # Local id of every (token, choice): 0..held-1, or `held` where
+        # another chip has the expert (by comparison, not a table
+        # look-up: scalar gathers are slow on the TPU).
+        mine = selected[..., None] == jnp.asarray(config.held, jnp.int32)
+        local = jnp.where(
+            mine.any(-1), jnp.argmax(mine, -1).astype(jnp.int32), n_held
+        ).reshape(-1)  # [N * k]
+        held_assignments = jnp.sum(local < n_held, dtype=jnp.int32)
+        flat = jnp.arange(n_tok * k, dtype=jnp.int32)
+        # Stable sort by expert: rows of one expert are consecutive, in
+        # token order; assignments of absent experts sort to the end.
+        sorted_local, order = jax.lax.sort((local, flat), num_keys=1)
+        group_sizes = jnp.sum(
+            jax.nn.one_hot(local, n_held + 1, dtype=jnp.int32), axis=0
+        )
+
+    rows, most = _chunk_rows(n_tok, config)
+    # Sentinels past the last assignment, so that every chunk is whole:
+    # row -> no token (reads zeros), expert -> none held.
+    pad = max(most * rows - n_tok * k, 0)
+    route = (
+        jnp.pad(order, (0, pad), constant_values=n_tok * k),
+        jnp.pad(sorted_local, (0, pad), constant_values=n_held),
+        group_sizes,
+    )
+    chunks = -(-held_assignments // rows)
+    out, multiplied = _routed(
+        (k, n_held, rows), chunks, x, weights,
+        jax.lax.stop_gradient(params["experts"]), lora.get("experts"), route,
+    )
+    with jax.named_scope("moe.shared"):
+        shared_lora = lora.get("shared", {})
+        out = out + swiglu(x, params["shared"], shared_lora.get, x.dtype)
+    aux = {
+        "counts": multiplied,
+        "held_assignments": held_assignments,
+        "selected": selected,
+        "scores": scores,
+    }
+    return out, aux
