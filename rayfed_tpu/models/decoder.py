@@ -219,9 +219,6 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
         v = _linear(y, lp["wv"], lget("wv"), dtype).reshape(b, t, kv, dh)
         q = _rms_norm(q, lp["q_norm"], c.rms_eps)
         k = _rms_norm(k, lp["k_norm"], c.rms_eps)
-        if kv != h:
-            k = jnp.repeat(k, h // kv, axis=2)
-            v = jnp.repeat(v, h // kv, axis=2)
     attend = functools.partial(_attend, config=c, attn_fn=attn_fn)
     if isinstance(attention, str):
         o = attend(q, k, v, rope, kind=attention)

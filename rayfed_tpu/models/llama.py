@@ -316,18 +316,15 @@ def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
                emit_kv=False):
     """One decoder layer (norm→qkv→RoPE→GQA attn→out→MLP) — the single
     implementation behind the training forward AND prefill, so their
-    numerics cannot drift.  With ``emit_kv`` also returns the pre-repeat
+    numerics cannot drift.  GQA: every ``attn_fn`` takes the
+    ``num_kv_heads`` K/V as they are (the flash kernel reads them by KV
+    head, dense attention contracts over the group, ring and Ulysses
+    repeat inside their wrapper).  With ``emit_kv`` also returns that
     k/v (for KV-cache assembly)."""
-    h, kv = config.num_heads, config.num_kv_heads
     y = _rms_norm(x, lp["attn_norm"], config.rms_eps)
     q, k, v = _qkv_proj(y, lp, config, b, t, lget)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    k_out, v_out = k, v
-    if kv != h:  # GQA: repeat kv heads to match query heads
-        reps = h // kv
-        k = jnp.repeat(k, reps, axis=2)
-        v = jnp.repeat(v, reps, axis=2)
     if config.sliding_window is not None:
         # Both dense and flash attn_fns accept window=; an attn_fn that
         # cannot honor it (ring/Ulysses wrappers) fails loudly here
@@ -337,7 +334,7 @@ def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
         attn = attn_fn(q, k, v, causal=True)
     x = _attn_out(x, attn, lp, config, b, t, lget)
     x = _mlp_block(x, lp, config, lget)
-    return (x, (k_out, v_out)) if emit_kv else (x, None)
+    return (x, (k, v)) if emit_kv else (x, None)
 
 
 def _lm_head(x, params, config):

@@ -25,6 +25,29 @@ import jax.numpy as jnp
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() flushable
 
 
+def kv_group(q: jax.Array, k: jax.Array, v: jax.Array) -> int:
+    """Query heads a K/V head serves: ``k``/``v`` are ``[B, T, KV, D]``
+    with ``KV`` dividing ``q``'s ``H`` (grouped-query attention; query
+    head ``h`` reads K/V head ``h // (H // KV)``).  1 for plain MHA."""
+    h, kv = q.shape[2], k.shape[2]
+    if h % kv or v.shape[2] != kv:
+        raise ValueError(
+            f"K/V heads ({kv}, {v.shape[2]}) must agree and divide the "
+            f"query heads ({h})"
+        )
+    return h // kv
+
+
+def repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
+    """Grouped K/V repeated to ``q``'s heads, for an attention that
+    wants equal head counts (ring, Ulysses); the flash kernel and
+    :func:`dot_product_attention` read grouped K/V as it is."""
+    group = kv_group(q, k, v)
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -43,6 +66,9 @@ def dot_product_attention(
     / key token — used when q and k are shards of a longer sequence (the
     causal mask must compare *global* positions).  ``window`` (requires
     ``causal``) restricts each query to its last ``window`` keys.
+    ``k``/``v`` may hold fewer (grouped) heads than ``q``
+    (:func:`kv_group`); the group is a dimension of the contractions,
+    never a repeated copy.
     """
     if window is not None:
         if not causal:
@@ -54,8 +80,11 @@ def dot_product_attention(
     orig_dtype = q.dtype
     head_dim = q.shape[-1]
     scale = sm_scale if sm_scale is not None else head_dim**-0.5
-    qf = q.astype(jnp.float32) * scale
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
+    b, t_q, h, _ = q.shape
+    t_k, kv, group = k.shape[1], k.shape[2], kv_group(q, k, v)
+    qf = (q.astype(jnp.float32) * scale).reshape(b, t_q, kv, group, head_dim)
+    s = jnp.einsum("bqngd,bknd->bngqk", qf, k.astype(jnp.float32))
+    s = s.reshape(b, h, t_q, t_k)
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = kv_offset + jnp.arange(k.shape[1])
@@ -71,8 +100,11 @@ def dot_product_attention(
     # key is in the future): softmax of all-NEG_INF must yield zeros.
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.max(s, axis=-1, keepdims=True) <= NEG_INF / 2, 0.0, p)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return o.astype(orig_dtype)
+    o = jnp.einsum(
+        "bngqk,bknd->bqngd",
+        p.reshape(b, kv, group, t_q, t_k), v.astype(jnp.float32),
+    )
+    return o.reshape(b, t_q, h, head_dim).astype(orig_dtype)
 
 
 def as_attn_fn(sharded, built_causal: bool, built_scale, builder: str):
@@ -82,6 +114,8 @@ def as_attn_fn(sharded, built_causal: bool, built_scale, builder: str):
     causal=..., sm_scale=...)``; a ring/Ulysses builder bakes masking and
     scale in at build time, so the wrapper accepts those kwargs and
     rejects *conflicting* values instead of silently ignoring them.
+    Grouped K/V is repeated to the query heads here (:func:`repeat_kv`),
+    so a model hands every ``attn_fn`` the same unrepeated K/V.
     """
 
     def apply(q, k, v, *, causal=None, sm_scale=None, mask=None, window=None):
@@ -119,7 +153,7 @@ def as_attn_fn(sharded, built_causal: bool, built_scale, builder: str):
                     f"sm_scale={sm_scale} conflicts with the {builder}(...) "
                     f"build-time scale {effective}"
                 )
-        return sharded(q, k, v)
+        return sharded(q, *repeat_kv(q, k, v))
 
     return apply
 

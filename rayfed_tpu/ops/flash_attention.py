@@ -13,6 +13,14 @@ Backward is two tiled pallas kernels (dQ and dK/dV) that recompute the
 score tile from the saved per-row log-sum-exp — the standard
 flash-attention backward formulation, O(T·block) live memory.
 
+Which tiles the three kernels compute is one schedule
+(:func:`block_schedule`, counted from the shapes alone): the innermost
+grid dimension walks only the band of blocks that hold a visible pair
+(:class:`_Band`); a block the diagonal or the band's far edge crosses is
+walked in sub-tiles, each skipped, computed unmasked, or masked
+(:func:`_dispatch`).  Grouped K/V ``[B, T, KV, D]`` is read by KV head,
+never repeated; dK/dV accumulate over a group's query heads in VMEM.
+
 On the CPU backend (the test mode) the kernels run in pallas interpret
 mode, so the CPU test mesh exercises the same code path; on any other
 backend they are compiled — there is no silent interpreted run on a chip.
@@ -21,12 +29,17 @@ backend they are compiled — there is no silent interpreted run on a chip.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import operator
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from rayfed_tpu import telemetry
+from rayfed_tpu.ops.attention import kv_group
 
 NEG_INF = -1e30
 
@@ -37,44 +50,349 @@ def _interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _causal_dispatch(
-    compute, causal, qi, ki, block_q, block_k, q_offset, kv_offset,
-    window=None,
-):
-    """Run ``compute(masked)`` under the causal block classification.
+# How many pieces each edge of a block that straddles the diagonal or the
+# band's far edge is cut into, where the block allows (`_fit_split`).  Not
+# a setting: 2 x 2 against the block masked whole, one call at 32 x 128
+# heads, 8,192 tokens (`tool/flash_sweep.py --splits 1,2`, my chip run,
+# PR 30), forward / dQ / dK/dV ms: window 2,048 on 4 K/V heads 3.08 / 3.47 /
+# 4.18 -> 2.66 / 3.00 / 3.57; no window 5.27 / 6.13 / 7.14 -> 5.03 / 5.85 /
+# 6.78; window 4,096 on 8 K/V heads 4.33 / 4.94 / 5.95 -> 3.98 / 4.54 / 5.42.
+SPLIT = 2
 
-    A block strictly past the diagonal contributes nothing (skipped); a
-    block entirely at-or-before it needs no mask; only blocks straddling
-    the diagonal pay for the iota/compare/select.  Shared by all three
-    kernels so the boundary conditions cannot drift.
+_UNMASKED = (False, False)
 
-    ``window`` (sliding-window attention, requires ``causal``): query q
-    sees keys in ``(q − window, q]``.  Blocks entirely below the band
-    are skipped the same way fully-future blocks are — the kernel's
-    FLOPs scale with O(T·window) instead of O(T²/2).
+
+def _tile_kinds(diff, rows, cols, window):
+    """What a ``rows x cols`` tile needs: ``{(cut_diagonal, cut_band): holds}``.
+
+    ``diff`` is the position of the tile's first query less that of its
+    first key, so ``q − k`` runs over ``[lo, hi]`` in the tile and a pair
+    is visible where ``0 <= q − k`` (the diagonal) ``< window`` (the
+    band's far edge).  A tile for which no entry holds has no visible
+    pair; the ``(False, False)`` entry is the tile no edge cuts.  Only
+    comparisons and ``&``, so ``diff`` may be a Python int
+    (:func:`block_schedule`) or a traced scalar (the kernels); kinds the
+    sizes rule out are left out statically.
     """
+    lo, hi = diff - (cols - 1), diff + (rows - 1)
+    below, across = lo >= 0, (lo < 0) & (hi >= 0)
+    if window is None:
+        return {_UNMASKED: below, (True, False): across}
+    inside, edge = hi < window, (hi >= window) & (lo < window)
+    kinds = {(True, False): across & inside, (False, True): below & edge}
+    if window > rows + cols - 2:  # wide enough to hold a whole tile
+        kinds[_UNMASKED] = below & inside
+    if window < rows + cols - 2:  # narrow enough for both edges to cut one
+        kinds[(True, True)] = across & edge
+    return kinds
+
+
+def _static_when(holds):
+    return lambda body: body() if holds else None
+
+
+def _static_loop(n, body):
+    for t in range(n):
+        body(t)
+
+
+def _traced_loop(n, body):
+    jax.lax.fori_loop(0, n, lambda t, _: body(t), None)
+
+
+def _dispatch(
+    compute, when, loop, diff, block_q, block_k, sub_q, sub_k, causal, window
+):
+    """Run ``compute(rows, cols, edges)`` over what the block at ``diff``
+    needs — the ONE tile classification of the three kernels
+    (``pl.when`` and a ``fori_loop``) and of :func:`block_schedule` (a
+    plain ``if`` and ``for``).
+
+    ``rows``/``cols`` are ``(start, size)`` within the block.  A block no
+    edge cuts is one unmasked ``compute`` (``edges`` None); a block with
+    no visible pair is skipped; a block an edge cuts is walked in
+    ``sub_q x sub_k`` sub-tiles by ONE loop whose body classifies the
+    sub-tile from its own scalar ``diff``: skipped, unmasked, or masked
+    with ``edges = ((cut_diagonal, cut_band), diff)`` (:func:`_visible`).
+    So a kernel traces ``compute`` at most four times however fine the
+    cut (whole block; sub-tile unmasked, cut by the diagonal, cut by the
+    band's far edge; a fifth only where the window is narrower than a
+    sub-tile pair): what a process pays to trace and lower a step does
+    not grow with the cut (13 copies of the body cost two cells their
+    set-up bound: ledger, PR 29).  A sub-tile's ``start`` is then traced,
+    a multiple of its size: every slice is along rows.  The grid step and
+    its DMA stay at the block size; the cut's gain is the sub-tiles it
+    skips (a masked tile costs 8-20% more than an unmasked one).
+    """
+    whole = (0, block_q), (0, block_k)
     if not causal:
-        compute(False)
+        compute(*whole, None)
         return
-    q_first = q_offset + qi * block_q
-    q_last = q_first + block_q - 1
-    kv_first = kv_offset + ki * block_k
-    kv_last = kv_first + block_k - 1
-    active = kv_first <= q_last
-    straddles = kv_last > q_first
-    if window is not None:
-        # Band-active: some pair satisfies q − k < window.
-        active = active & (kv_last > q_first - window)
-        # Band-straddling: the OLDEST pair falls outside the window.
-        straddles = straddles | (q_last - kv_first >= window)
 
-    @pl.when(active & jnp.logical_not(straddles))
-    def _full():
-        compute(False)
+    def classified(d, rows, cols):
+        for kind, holds in _tile_kinds(d, rows[1], cols[1], window).items():
+            when(holds)(functools.partial(
+                compute, rows, cols, None if kind == _UNMASKED else (kind, d)
+            ))
 
-    @pl.when(active & straddles)
-    def _diag():
-        compute(True)
+    n_q, n_k = block_q // sub_q, block_k // sub_k
+    if n_q * n_k == 1:
+        classified(diff, *whole)
+        return
+    kinds = _tile_kinds(diff, block_q, block_k, window)
+    if _UNMASKED in kinds:
+        when(kinds.pop(_UNMASKED))(functools.partial(compute, *whole, None))
+
+    @when(functools.reduce(operator.or_, kinds.values()))
+    def _straddling():
+        def sub_tile(t):
+            a, b = _divmod(t, n_k)
+            q0, k0 = _multiple_of(a * sub_q, sub_q), _multiple_of(b * sub_k, sub_k)
+            classified(diff + q0 - k0, (q0, sub_q), (k0, sub_k))
+
+        loop(n_q * n_k, sub_tile)
+
+
+def _divmod(t, n):
+    if isinstance(t, int):
+        return divmod(t, n)
+    return jax.lax.div(t, n), jax.lax.rem(t, n)  # t >= 0: one equation each
+
+
+def _multiple_of(x, size):
+    return x if isinstance(x, int) else pl.multiple_of(x, size)
+
+
+def _visible(edges, rows, cols, window):
+    """The mask of a tile an edge cuts: one position difference, and
+    only the comparison each cutting edge needs (both where one tile
+    holds both edges)."""
+    (cut_diagonal, cut_band), diff = edges
+    row_less_col = jax.lax.broadcasted_iota(
+        jnp.int32, (rows, cols), 0
+    ) - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    visible = None
+    if cut_diagonal:
+        visible = row_less_col >= -diff
+    if cut_band:
+        band = row_less_col < window - diff
+        visible = band if visible is None else visible & band
+    return visible
+
+
+def _across(lanes, cols):
+    """A row statistic kept lane-replicated ``(rows, 128)`` (m, l, lse,
+    delta), spread over ``cols`` columns: whole copies of its vregs
+    where the width allows, which costs no lane broadcast — slicing
+    lane 0 and broadcasting it took 11% of a forward call at 8,192
+    tokens and 45% at 512 (ISSUE 30, from PR 29's sweep; the 512-token
+    forward reads 1.37 -> 0.87 ms with it, my chip run, PR 30) — else
+    lane 0 broadcast (head widths and toy blocks under 128)."""
+    reps, rest = divmod(cols, lanes.shape[1])
+    if rest == 0:
+        return jnp.tile(lanes, (1, reps))
+    return jnp.broadcast_to(lanes[:, :1], (lanes.shape[0], cols))
+
+
+def _clip(x, lo, hi):
+    if isinstance(x, int):
+        return max(lo, min(x, hi))
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _block_of(pos, size, last):
+    """The block of ``size`` that holds position ``pos``, clipped to
+    ``0..last``.  Three equations where ``pos`` is traced (the floor
+    division of a numerator already clipped at 0 needs no sign repair,
+    which alone is eleven): the band's arithmetic runs in every kernel
+    and index map, and what is traced there a process pays at set-up."""
+    if isinstance(pos, int):
+        return _clip(pos // size, 0, last)
+    return jnp.minimum(jax.lax.div(jnp.maximum(pos, 0), size), last)
+
+
+class _Band(NamedTuple):
+    """Which blocks of the OTHER axis block ``x`` of one axis walks.
+
+    A pair is visible where its difference (``base + x·size + a −
+    y·other_size − b`` for element ``a`` of block ``x`` and ``b`` of the
+    other axis' block ``y``) lies in ``[lowest, highest]`` (None =
+    unbounded), so the blocks that hold one are a run ``first..last``,
+    the band, of at most ``steps`` blocks for any ``x``.  The grid's
+    innermost dimension is ``steps`` long, not the whole axis: a grid
+    step that computes nothing still costs its DMA and about a
+    microsecond (1.0-2.1 us a head: ISSUE 30, from PR 29's sweep — a
+    third of a window-2,048 call when the grid was the whole square).  ``x`` may be
+    a Python int (:func:`block_schedule`) or traced (index maps, kernels).
+    """
+
+    size: int
+    other_size: int
+    n_other: int
+    base: int
+    lowest: Optional[int]
+    highest: Optional[int]
+    steps: int
+
+    def _first(self, x, last):
+        if self.highest is None or self.n_other == 1:
+            return 0
+        return _block_of(
+            self.base - self.highest + x * self.size, self.other_size, last
+        )
+
+    def run(self, x):
+        """First and last block of the run, clipped to the axis."""
+        end = self.n_other - 1
+        if self.lowest is None or end == 0:
+            return self._first(x, end), end
+        return self._first(x, end), _block_of(
+            self.base + self.size - 1 - self.lowest + x * self.size,
+            self.other_size, end,
+        )
+
+    def block(self, x, step):
+        """The block grid step ``step`` of ``x`` is about: the run from
+        its first block, shifted down where it would leave the axis
+        (the blocks then before the run classify as invisible)."""
+        return self._first(x, self.n_other - self.steps) + step
+
+    def fetch(self, x, step):
+        """The block to hold in VMEM at that step: ``block`` inside the
+        run, the nearest block of the run outside it — an index that
+        does not change costs no DMA."""
+        first, last = self.run(x)
+        return _clip(self.block(x, step), first, last)
+
+
+def _band(n, size, n_other, other_size, base, lowest, highest) -> _Band:
+    band = _Band(size, other_size, n_other, base, lowest, highest, 1)
+    runs = [band.run(x) for x in range(n)]
+    return band._replace(steps=max(1, max(b - a + 1 for a, b in runs)))
+
+
+def _bands(t_q, t_k, block_q, block_k, causal, window, q_offset, kv_offset):
+    """(the kv blocks a q block walks, the q blocks a kv block walks)."""
+    n_q, n_k = t_q // block_q, t_k // block_k
+    near = 0 if causal else None  # q − k >= 0
+    far = None if window is None else window - 1  # q − k <= window − 1
+    return (
+        _band(n_q, block_q, n_k, block_k, q_offset - kv_offset, near, far),
+        _band(n_k, block_k, n_q, block_q, kv_offset - q_offset,
+              None if far is None else -far, near),
+    )
+
+
+class Schedule(NamedTuple):
+    """What one head's call computes (:func:`block_schedule`)."""
+
+    grid: Tuple[int, int]  # forward and dQ: (q blocks, kv steps of each)
+    grid_dkv: Tuple[int, int]  # dK/dV: (kv blocks, q steps of each)
+    sub_q: int  # a sub-tile's rows
+    sub_k: int  # and columns
+    steps_skipped: int  # forward grid steps that compute nothing
+    blocks_unmasked: int  # blocks computed whole, no mask
+    tiles_skipped: int  # sub-tiles of straddling blocks: nothing visible,
+    tiles_unmasked: int  # every pair visible,
+    tiles_masked: int  # an edge crosses
+    pairs_visible: int
+    pairs_computed: int
+    useful_share: float  # visible over computed
+
+
+def _fit_split(block: int, want: int) -> int:
+    """Pieces (<= want) an edge of ``block`` is cut into: each a multiple
+    of 512 — a 256-wide sub-tile cost more in fixed work than the pairs
+    it leaves out save (PR 29's sweep, before `_across`: dK/dV +6% and
+    dQ −3% at 512 tokens, every kernel slower at 8,192; not measured
+    again since) — or, for a block under 256 (toy sequences,
+    the CPU tests), of 8; 1 where no such cut exists."""
+    align = 512 if block >= 256 else 8
+    n = max(int(want), 1)
+    while n > 1 and block % (n * align):
+        n -= 1
+    return n
+
+
+def _sub_tile(block_q: int, block_k: int, sub: int) -> Tuple[int, int]:
+    """A sub-tile's (rows, columns) where a block's edges are cut in
+    ``sub`` pieces, as far as the blocks allow."""
+    return (block_q // _fit_split(block_q, sub),
+            block_k // _fit_split(block_k, sub))
+
+
+def schedule_visits(
+    t_q, t_k, block_q, block_k, sub, causal, window, q_offset, kv_offset
+):
+    """Every rectangle one head's call computes, in the forward grid's
+    order: ``(first query row, rows, first key row, cols, masked)``."""
+    sub_q, sub_k = _sub_tile(block_q, block_k, sub)
+    walk, _ = _bands(
+        t_q, t_k, block_q, block_k, causal, window, q_offset, kv_offset
+    )
+    visits = []
+    for qi in range(t_q // block_q):
+        for step in range(walk.steps):
+            q0, k0 = qi * block_q, walk.block(qi, step) * block_k
+            _dispatch(
+                lambda rows, cols, edges: visits.append(
+                    (q0 + rows[0], rows[1], k0 + cols[0], cols[1],
+                     edges is not None)
+                ),
+                _static_when, _static_loop, (q_offset + q0) - (kv_offset + k0),
+                block_q, block_k, sub_q, sub_k, causal, window,
+            )
+    return visits
+
+
+def block_schedule(
+    t_q, t_k, block_q, block_k, sub, causal, window, q_offset, kv_offset
+) -> Schedule:
+    """What one head's call does, counted from the shapes alone.
+
+    The kernels walk exactly this (the same :func:`_dispatch` over the
+    same :func:`_bands` and :func:`_fit_split`), and the tests hold them
+    to :func:`schedule_visits`.  ``sub`` is how many pieces a straddling
+    block's edges are cut into where the block allows.
+    """
+    visits = schedule_visits(
+        t_q, t_k, block_q, block_k, sub, causal, window, q_offset, kv_offset
+    )
+    sub_q, sub_k = _sub_tile(block_q, block_k, sub)
+    walk, walk_dkv = _bands(
+        t_q, t_k, block_q, block_k, causal, window, q_offset, kv_offset
+    )
+    grid = (t_q // block_q, walk.steps)
+    tiles = [v for v in visits if v[4] or (v[1], v[3]) != (block_q, block_k)]
+    straddling = {(v[0] // block_q, v[2] // block_k) for v in tiles}
+    masked = sum(v[4] for v in tiles)
+    unmasked_blocks = len(visits) - len(tiles)
+    pairs_visible = t_q * t_k
+    if causal:  # per query: its keys from the band's far edge to itself
+        q = q_offset + np.arange(t_q)
+        first = kv_offset if window is None else np.maximum(
+            kv_offset, q - window + 1
+        )
+        last = np.minimum(q, kv_offset + t_k - 1)
+        pairs_visible = int(np.maximum(last - first + 1, 0).sum())
+    pairs_computed = sum(v[1] * v[3] for v in visits)
+    return Schedule(
+        grid=grid,
+        grid_dkv=(t_k // block_k, walk_dkv.steps),
+        sub_q=sub_q,
+        sub_k=sub_k,
+        steps_skipped=grid[0] * grid[1] - unmasked_blocks - len(straddling),
+        blocks_unmasked=unmasked_blocks,
+        tiles_skipped=(
+            len(straddling) * (block_q // sub_q) * (block_k // sub_k)
+            - len(tiles)
+        ),
+        tiles_unmasked=len(tiles) - masked,
+        tiles_masked=masked,
+        pairs_visible=pairs_visible,
+        pairs_computed=pairs_computed,
+        useful_share=pairs_visible / max(pairs_computed, 1),
+    )
 
 
 def _flash_fwd_kernel(
@@ -91,82 +409,138 @@ def _flash_fwd_kernel(
     causal: bool,
     block_q: int,
     block_k: int,
+    sub_q: int,
+    sub_k: int,
     q_offset: int,
     kv_offset: int,
+    walk: _Band,
     window=None,
 ):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    step = pl.program_id(2)
+    ki = walk.block(qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Under causality a kv block strictly after the last query row of this
-    # q block contributes nothing — skip its matmuls entirely; a block
-    # entirely at-or-before the diagonal needs no mask — skip the iota/
-    # compare/select (only diagonal-straddling blocks pay for masking).
-    # Offsets are static (compile-time) positions of the first q/kv token.
-    def _compute(masked: bool):
+    # One online-softmax step of the query rows `rows` over the keys
+    # `cols` (static slices of the resident blocks).  Offsets are static
+    # (compile-time) positions of the first q/kv token.
+    def _compute(rows, cols, edges):
+        r, c = pl.ds(*rows), pl.ds(*cols)
         # Feed the MXU native-dtype (bf16) operands — casting to f32 first
         # would force f32 matmul passes at a fraction of bf16 throughput.
         # Accumulation is f32 via preferred_element_type.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        q = q_ref[0, r, :]
+        k = k_ref[0, c, :]
+        v = v_ref[0, c, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (block_q, block_k) f32
-        if masked:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_offset + ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            visible = q_pos >= k_pos
-            if window is not None:
-                visible = visible & (q_pos - k_pos < window)
-            s = jnp.where(visible, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]  # (block_q, 1)
-        l_prev = l_ref[:, :1]
+        ) * scale  # (rows, cols) f32
+        m_prev = m_ref[r, :]  # (rows, 128), lane-replicated: `_across`
+        l_prev = l_ref[r, :]
+        if edges is not None:
+            s = jnp.where(_visible(edges, rows[1], cols[1], window), s, NEG_INF)
         m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-        # Fully-masked rows keep m_cur == NEG_INF; clamp the shift so
-        # their p = exp(NEG_INF - 0) == 0 instead of exp(0) == 1 (same
-        # guard as attention.blockwise_accumulate).
-        m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
-        p = jnp.exp(s - m_safe)
-        correction = jnp.exp(
-            jnp.where(m_prev <= NEG_INF / 2, NEG_INF, m_prev) - m_safe
-        )
+        if edges is None:
+            # Every pair visible: the row maximum is a real score, so the
+            # guards below have nothing to guard (exp(NEG_INF − m) is 0).
+            m_safe, m_from = m_cur, m_prev
+        else:
+            # Fully-masked rows keep m_cur == NEG_INF; clamp the shift so
+            # their p = exp(NEG_INF - 0) == 0 instead of exp(0) == 1 (same
+            # guard as attention.blockwise_accumulate).
+            m_safe = jnp.where(m_cur <= NEG_INF / 2, 0.0, m_cur)
+            m_from = jnp.where(m_prev <= NEG_INF / 2, NEG_INF, m_prev)
+        p = jnp.exp(s - _across(m_safe, cols[1]))
+        correction = jnp.exp(m_from - m_safe)
         l_cur = l_prev * correction + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] = acc_ref[...] * correction + pv
-        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+        acc_ref[r, :] = (
+            acc_ref[r, :] * _across(correction, acc_ref.shape[1]) + pv
+        )
+        m_ref[r, :] = m_cur
+        l_ref[r, :] = l_cur
 
-    _causal_dispatch(
-        _compute, causal, qi, ki, block_q, block_k, q_offset, kv_offset,
-        window=window,
+    _dispatch(
+        _compute, pl.when, _traced_loop,
+        (q_offset - kv_offset) + qi * block_q - ki * block_k,
+        block_q, block_k, sub_q, sub_k, causal, window,
     )
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
-        l_final = l_ref[:, :1]
-        l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0] = (
+            acc_ref[...] / _across(l_safe, acc_ref.shape[1])
+        ).astype(o_ref.dtype)
         lse_ref[0] = (
             m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-37))
         ).astype(lse_ref.dtype)
 
 
+def _plan(q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+          interpret):
+    """What the three ``pallas_call``s share: the checked block sizes
+    and sub-tile sizes (the kernels' static arguments), the query heads a
+    K/V head serves, and the two bands their grids walk."""
+    bh, t_q, _ = q.shape
+    bkv, t_k, _ = k.shape
+    if bh % bkv:
+        raise ValueError(
+            f"K/V heads ({bkv} with the batch) must divide the query "
+            f"heads ({bh})"
+        )
+    block_q = min(block_q, t_q)
+    block_k = min(block_k, t_k)
+    if t_q % block_q or t_k % block_k:
+        raise ValueError(
+            f"block sizes ({block_q}, {block_k}) must divide the "
+            f"sequence lengths ({t_q}, {t_k})"
+        )
+    if not interpret and (block_q % 8 or block_k % 8):
+        raise ValueError(
+            f"TPU tiling requires block sizes divisible by 8, got "
+            f"({block_q}, {block_k})"
+        )
+    sub_q, sub_k = _sub_tile(block_q, block_k, SPLIT)
+    common = dict(
+        causal=causal,
+        block_q=block_q,
+        block_k=block_k,
+        sub_q=sub_q,
+        sub_k=sub_k,
+        q_offset=q_offset,
+        kv_offset=kv_offset,
+        window=window,
+    )
+    walk, walk_dkv = _bands(
+        t_q, t_k, block_q, block_k, causal, window, q_offset, kv_offset
+    )
+    return common, bh // bkv, walk, walk_dkv
+
+
+# Each wrapper of a ``pallas_call`` is a ``jax.jit`` of its own, keyed by
+# the static arguments: a process traces each distinct kernel ONCE, and
+# the jaxpr is then found again by the forward that a checkpoint
+# recomputes, by both branches of a ``cond`` over layer kinds, by every
+# scanned group and by every party's thread.  Tracing a kernel is host
+# Python that runs before the compile cache can be asked, under one GIL
+# for all the parties of a process (ledger, PR 29: +11.8 s of set-up
+# with four parties; `tool/flash_sweep.py --lowering`).
+_STATIC = (
+    "scale", "causal", "block_q", "block_k", "q_offset", "kv_offset",
+    "interpret", "out_dtype", "window",
+)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_forward(
     q: jax.Array,
     k: jax.Array,
@@ -182,7 +556,9 @@ def _flash_forward(
     out_dtype=None,
     window=None,
 ):
-    """Run the pallas kernel on [BH, T, D] inputs; returns (o, lse).
+    """Run the pallas kernel on q [BH, T, D], k/v [B·KV, T, D] inputs;
+    returns (o, lse).  Query head ``h`` reads K/V head ``h // (H // KV)``
+    (the index map's ``b // group``): grouped K/V is never repeated.
 
     ``out_dtype`` overrides the output dtype of ``o`` (default: q's) —
     ring callers take f32 so per-step partials are not rounded to bf16
@@ -195,55 +571,64 @@ def _flash_forward(
     the TPU (8, 128) tiling rule, then lane 0 is taken.
     """
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
-    block_q = min(block_q, t_q)
-    block_k = min(block_k, t_k)
-    if t_q % block_q or t_k % block_k:
-        raise ValueError(
-            f"block sizes ({block_q}, {block_k}) must divide the "
-            f"sequence lengths ({t_q}, {t_k})"
-        )
-    if not interpret and (block_q % 8 or block_k % 8):
-        raise ValueError(
-            f"TPU tiling requires block sizes divisible by 8, got "
-            f"({block_q}, {block_k})"
-        )
-    grid = (bh, t_q // block_q, t_k // block_k)
-    kernel = functools.partial(
-        _flash_fwd_kernel,
-        scale=scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        q_offset=q_offset,
-        kv_offset=kv_offset,
-        window=window,
+    common, group, walk, _ = _plan(
+        q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+        interpret,
     )
-    scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
-    ]
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((bh, t_q, 128), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(q, k, v)
+    block_q, block_k = common["block_q"], common["block_k"]
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block_k, d), lambda b, i, s: (jax.lax.div(b, group), walk.fetch(i, s), 0)
+    )
+    lane_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, s: (b, i, 0))
+    with jax.named_scope("flash.fwd"):
+        o, lse = pl.pallas_call(
+            functools.partial(
+                _flash_fwd_kernel, scale=scale, walk=walk, **common
+            ),
+            grid=(bh, t_q // block_q, walk.steps),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, lane_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
+                jax.ShapeDtypeStruct((bh, t_q, 128), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
+            interpret=interpret,
+        )(q, k, v)
     return o, lse[..., 0]
+
+
+def _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows,
+                   cols, edges, scale, window):
+    """What both backward kernels recompute of a tile: its operands,
+    ``p`` (zeroed where invisible by the mask's own predicate) and
+    ``ds = p ∘ (dO Vᵀ − D)``."""
+    r, c = pl.ds(*rows), pl.ds(*cols)
+    # Native-dtype (bf16) MXU operands, f32 accumulation — see fwd.
+    q = q_ref[0, r, :]
+    k = k_ref[0, c, :]
+    v = v_ref[0, c, :]
+    do = do_ref[0, r, :]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # (rows, cols)
+    p = jnp.exp(s - _across(lse_ref[0, r, :], cols[1]))
+    if edges is not None:
+        # Fully-masked rows have lse ~ NEG_INF and p = inf there — every
+        # pair of such a row is invisible, so the select zeroes it too.
+        p = jax.lax.select(
+            _visible(edges, rows[1], cols[1], window), p, jnp.zeros_like(p)
+        )
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - _across(delta_ref[0, r, :], cols[1]))
+    return q, k, do, p, ds
 
 
 def _flash_bwd_dq_kernel(
@@ -260,60 +645,40 @@ def _flash_bwd_dq_kernel(
     causal: bool,
     block_q: int,
     block_k: int,
+    sub_q: int,
+    sub_k: int,
     q_offset: int,
     kv_offset: int,
+    walk: _Band,
     window=None,
 ):
-    """dQ = (P ∘ (dO Vᵀ − D)) K · scale, accumulated over kv blocks."""
+    """dQ = (P ∘ (dO Vᵀ − D)) K · scale, accumulated over the kv blocks
+    of the q block's band."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    step = pl.program_id(2)
+    ki = walk.block(qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _compute(masked: bool):
-        # Native-dtype (bf16) MXU operands, f32 accumulation — see fwd.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if masked:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_offset + ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            visible = q_pos >= k_pos
-            if window is not None:
-                visible = visible & (q_pos - k_pos < window)
-            s = jnp.where(visible, s, NEG_INF)
-            # exp(s - lse); fully-masked rows have lse ~ NEG_INF — zero.
-            p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        else:
-            p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    def _compute(rows, cols, edges):
+        _, k, _, _, ds = _backward_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
+            edges, scale, window,
         )
-        ds = p * (dp - delta)
-        acc_ref[...] += jax.lax.dot_general(
+        acc_ref[pl.ds(*rows), :] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    _causal_dispatch(
-        _compute, causal, qi, ki, block_q, block_k, q_offset, kv_offset,
-        window=window,
+    _dispatch(
+        _compute, pl.when, _traced_loop,
+        (q_offset - kv_offset) + qi * block_q - ki * block_k,
+        block_q, block_k, sub_q, sub_k, causal, window,
     )
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == walk.steps - 1)
     def _finalize():
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
@@ -334,64 +699,47 @@ def _flash_bwd_dkv_kernel(
     causal: bool,
     block_q: int,
     block_k: int,
+    sub_q: int,
+    sub_k: int,
     q_offset: int,
     kv_offset: int,
+    walk: _Band,
     window=None,
 ):
-    """dV = Pᵀ dO and dK = dSᵀ Q · scale, accumulated over q blocks."""
+    """dV = Pᵀ dO and dK = dSᵀ Q · scale of ONE K/V head, accumulated
+    over the innermost grid dimension: the query heads that read it, and
+    for each the q blocks of the kv block's band."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    num_q = pl.num_programs(2)
+    step = pl.program_id(2)
+    qi = walk.block(ki, jax.lax.rem(step, walk.steps))
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def _compute(masked: bool):
-        # Native-dtype (bf16) MXU operands, f32 accumulation — see fwd.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (block_q, block_k)
-        if masked:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kv_offset + ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            visible = q_pos >= k_pos
-            if window is not None:
-                visible = visible & (q_pos - k_pos < window)
-            s = jnp.where(visible, s, NEG_INF)
-            p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        else:
-            p = jnp.exp(s - lse)
-        dv_acc_ref[...] += jax.lax.dot_general(
+    def _compute(rows, cols, edges):
+        q, _, do, p, ds = _backward_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
+            edges, scale, window,
+        )
+        c = pl.ds(*cols)
+        dv_acc_ref[c, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # pᵀ @ do: (block_k, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dk_acc_ref[...] += jax.lax.dot_general(
+        )  # pᵀ @ do: (cols, d)
+        dk_acc_ref[c, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # dsᵀ @ q (un-normalized; scale applied at finalize)
 
-    _causal_dispatch(
-        _compute, causal, qi, ki, block_q, block_k, q_offset, kv_offset,
-        window=window,
+    _dispatch(
+        _compute, pl.when, _traced_loop,
+        (q_offset - kv_offset) + qi * block_q - ki * block_k,
+        block_q, block_k, sub_q, sub_k, causal, window,
     )
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -412,88 +760,88 @@ def _lse_delta_lanes(o, lse, do):
     return lse_b, delta_b
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_backward_pallas(
     q, k, v, o, lse, do, *, scale: float, causal: bool,
     block_q: int, block_k: int, q_offset: int, kv_offset: int, interpret: bool,
     lse_delta_b=None, out_dtype=None, window=None,
 ):
-    """Pallas flash backward on [BH, T, D] inputs → (dq, dk, dv).
+    """Pallas flash backward on q [BH, T, D], k/v [B·KV, T, D] inputs →
+    (dq, dk, dv), dk/dv of k/v's shape.
 
     ``out_dtype`` overrides the gradients' dtype (default: the inputs') —
     ring callers take f32 so per-step partials are not rounded to bf16
     before cross-step accumulation.
 
     Two tiled kernels: dQ iterates kv blocks innermost (accumulator over
-    the q row block), dK/dV iterates q blocks innermost (accumulators
-    over the kv block).  ``delta = rowsum(dO ∘ O)`` and the saved lse are
+    the q row block), dK/dV iterates innermost over the q blocks of every
+    query head of its K/V head's group (accumulators over the kv block,
+    written once).  ``delta = rowsum(dO ∘ O)`` and the saved lse are
     lane-broadcast to 128 so their blocks satisfy TPU (8, 128) tiling;
     pass ``lse_delta_b`` (from :func:`_lse_delta_lanes`) to reuse them
     across calls that share (o, lse, do).
     """
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
-    block_q = min(block_q, t_q)
-    block_k = min(block_k, t_k)
-    if t_q % block_q or t_k % block_k:
-        raise ValueError(
-            f"block sizes ({block_q}, {block_k}) must divide the "
-            f"sequence lengths ({t_q}, {t_k})"
-        )
+    bkv, t_k, _ = k.shape
+    common, group, walk, walk_dkv = _plan(
+        q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+        interpret,
+    )
+    block_q, block_k = common["block_q"], common["block_k"]
     if lse_delta_b is None:
         lse_delta_b = _lse_delta_lanes(o, lse, do)
     lse_b, delta_b = lse_delta_b
 
-    common = dict(
-        scale=scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        q_offset=q_offset,
-        kv_offset=kv_offset,
-        window=window,
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block_k, d), lambda b, i, s: (jax.lax.div(b, group), walk.fetch(i, s), 0)
     )
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(bh, t_q // block_q, t_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, do, lse_b, delta_b)
+    lane_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, s: (b, i, 0))
+    with jax.named_scope("flash.dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_dq_kernel, scale=scale, walk=walk, **common
+            ),
+            grid=(bh, t_q // block_q, walk.steps),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, lane_spec, lane_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, lse_b, delta_b)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(bh, t_k // block_k, t_q // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_k, d), out_dtype or k.dtype),
-            jax.ShapeDtypeStruct((bh, t_k, d), out_dtype or v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, lse_b, delta_b)
+    # Grid (K/V head, kv block, its group's query heads x their q steps).
+    num_k, q_steps = t_k // block_k, walk_dkv.steps
+
+    def of_q(width):
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, j, s: (
+                b * group + jax.lax.div(s, q_steps),
+                walk_dkv.fetch(j, jax.lax.rem(s, q_steps)),
+                0,
+            ),
+        )
+
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0))
+    with jax.named_scope("flash.dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_dkv_kernel, scale=scale, walk=walk_dkv, **common
+            ),
+            grid=(bkv, num_k, group * q_steps),
+            in_specs=[of_q(d), kv_spec, kv_spec, of_q(d), of_q(128), of_q(128)],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bkv, t_k, d), out_dtype or k.dtype),
+                jax.ShapeDtypeStruct((bkv, t_k, d), out_dtype or v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            interpret=interpret,
+        )(q, k, v, do, lse_b, delta_b)
 
     return dq, dk, dv
 
@@ -566,7 +914,8 @@ def _flash_bwd_bthd(
         interpret=interpret,
         window=window,
     )
-    return _bht_to_bthd(dq, b, h), _bht_to_bthd(dk, b, h), _bht_to_bthd(dv, b, h)
+    kv = k.shape[2]
+    return _bht_to_bthd(dq, b, h), _bht_to_bthd(dk, b, kv), _bht_to_bthd(dv, b, kv)
 
 
 _flash_bthd.defvjp(_flash_fwd_bthd, _flash_bwd_bthd)
@@ -579,11 +928,15 @@ def flash_attention(
     *,
     causal: bool = False,
     sm_scale: Optional[float] = None,
-    # Defaults from an on-chip sweep (v5e, b=4 T=2048 h=16 dh=64 bf16,
-    # fwd+bwd, min-of-3 over a 60-iter scan delta): 1024/1024 = 4.0 ms vs
-    # 512/1024 = 4.3, 512/512 = 5.1, 128/512 = 8.8, dense = 15.6.  Large
-    # tiles amortize per-step overhead; bigger (1024/2048) exceeds the
-    # 16 MB scoped-VMEM limit in the dkv kernel.
+    # 1024/1024: a block is the unit of DMA and of the grid.  With no
+    # window (36 computed blocks a head, 32 x 128 heads, 8,192 tokens,
+    # bf16; `tool/flash_sweep.py` on a v5e, my chip run, PR 30, blocks
+    # masked whole) a block costs 4.6 us a head forward, 5.3 dQ, 6.2 dK/dV
+    # (2.7, 4.1, 5.4 us of matmuls at peak); a grid step that computes
+    # nothing cost 1.0-2.1 us while it still fetched its block (PR 29's
+    # sweep), hence the band.  Blocks of 512 (the 16 x 512 shape) cost 1.7
+    # us a head where a quarter of the work would be 1.1-1.6.  Bigger
+    # (1024/2048) exceeds the 16 MB scoped-VMEM limit in dK/dV.
     block_q: int = 1024,
     block_k: int = 1024,
     q_offset: int = 0,
@@ -595,6 +948,11 @@ def flash_attention(
     """Tiled flash attention, BTHD layout — drop-in for
     :func:`rayfed_tpu.ops.attention.dot_product_attention` (also as the
     ``attn_fn`` of Ulysses attention).
+
+    ``k``/``v`` are ``[B, T, KV, D]`` with ``KV`` dividing ``q``'s ``H``
+    heads (grouped-query attention): query head ``h`` reads K/V head
+    ``h // (H // KV)`` straight from the unrepeated arrays, and dK/dV
+    come back ``[B, T, KV, D]``, summed over each group in the kernel.
 
     ``q_offset``/``kv_offset`` are *static* global positions of the first
     q/kv token (sharded-causal use).  Arbitrary dense ``mask`` is not
@@ -617,6 +975,7 @@ def flash_attention(
             raise ValueError("window= requires causal=True (Mistral SWA)")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
+    kv_group(q, k, v)  # K/V heads must agree and divide the query heads
     if interpret is None:
         interpret = _interpret_default()
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
@@ -633,10 +992,27 @@ def flash_attention(
             f"8-aligned block split for the compiled TPU kernel — pad the "
             f"sequence to a multiple of 8 or use dot_product_attention"
         )
+    q_offset, kv_offset = int(q_offset), int(kv_offset)
+    window = None if window is None else int(window)
+    if telemetry.armed():
+        # One record a call traced while the recorder is armed: what the
+        # kernels of this call (forward and backward) compute.
+        schedule = block_schedule(
+            q.shape[1], k.shape[1], block_q, block_k, SPLIT, causal, window,
+            q_offset, kv_offset,
+        )
+        telemetry.emit(
+            "attn.schedule",
+            detail=dict(
+                schedule._asdict(), batch=q.shape[0], heads=q.shape[2],
+                kv_heads=k.shape[2], t_q=q.shape[1], t_k=k.shape[1],
+                block_q=block_q, block_k=block_k, causal=causal,
+                window=window, q_offset=q_offset, kv_offset=kv_offset,
+            ),
+        )
     return _flash_bthd(
-        q, k, v, scale, causal, block_q, block_k,
-        int(q_offset), int(kv_offset), interpret,
-        None if window is None else int(window),
+        q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset,
+        interpret, window,
     )
 
 

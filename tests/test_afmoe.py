@@ -197,6 +197,39 @@ def test_float32_system_matches_the_reference(grouped):
         assert rel_rms(got, want) < 1e-4, path
 
 
+@pytest.mark.parametrize("attn", ["dense", "flash"])
+def test_unrepeated_kv_matches_repeated(attn):
+    """The decoder hands its 2 K/V heads to the ``attn_fn`` unrepeated
+    (and rotates the unrepeated K): logits and adapter gradients equal
+    those of the explicit repeat to 4 heads, dense and through the flash
+    kernel (blocks of 8: one window)."""
+    from rayfed_tpu.ops.flash_attention import flash_attention
+
+    cfg, base, adapters, ids = make(seed=11)
+
+    def repeated(q, k, v, **kw):
+        rep = lambda x: jnp.repeat(x, HEADS // KV, axis=2)
+        return dot_product_attention(q, rep(k), rep(v), **kw)
+
+    attn_fn = dot_product_attention if attn == "dense" else (
+        lambda q, k, v, **kw: flash_attention(q, k, v, block_q=8, block_k=8, **kw)
+    )
+
+    def loss(a, attn_fn):
+        logits, _ = decoder.apply_decoder(base, ids, cfg, lora=a, attn_fn=attn_fn)
+        return llama.lm_loss(logits[:, :-1], ids[:, 1:]), logits
+
+    (_, got), g_got = jax.value_and_grad(loss, has_aux=True)(adapters, attn_fn)
+    (_, want), g_want = jax.value_and_grad(loss, has_aux=True)(adapters, repeated)
+    assert rel_rms(got, want) < 1e-5
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves(g_want)
+    ):
+        if path[-1].key == "scale":
+            continue  # the system holds it constant (stop_gradient)
+        assert rel_rms(a, b) < 1e-4, path
+
+
 def test_recomputing_layers_changes_no_gradient():
     """``remat`` (``jax.checkpoint`` of the scanned body) keeps a layer's
     inputs and runs it again in the backward pass: the gradients, of the

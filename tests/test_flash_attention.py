@@ -211,3 +211,358 @@ def test_flash_window_validation():
         flash_attention(q, k, v, window=8)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, k, v, causal=True, window=0)
+
+
+# --- grouped K/V, the band walk, sub-tiled edges, the schedule, the size budget ---
+
+import importlib
+
+from rayfed_tpu import telemetry
+
+fa = importlib.import_module("rayfed_tpu.ops.flash_attention")
+
+
+def _grouped_qkv(key, t_q, t_k, h, kv, d=8, b=1):
+    kq, kk, kv_ = jax.random.split(key, 3)
+    return (
+        jax.random.normal(kq, (b, t_q, h, d), jnp.float32),
+        jax.random.normal(kk, (b, t_k, kv, d), jnp.float32),
+        jax.random.normal(kv_, (b, t_k, kv, d), jnp.float32),
+    )
+
+
+# name -> (t_q, t_k, block, keywords).  Blocks of 16 are cut into
+# sub-tiles of 8 (SPLIT 2), so the windows below are smaller than a
+# sub-tile, equal to one, not a multiple of one, and larger than the
+# sequence; 96 tokens shrink a block of 64 to 48 (sub-tiles of 24), 48
+# tokens shrink a block of 32 to 24 (which no aligned cut divides).
+GROUPED_CASES = {
+    "no_window": (64, 64, 16, {}),
+    "window_below_sub_tile": (64, 64, 16, dict(window=3)),
+    "window_is_sub_tile": (64, 64, 16, dict(window=8)),
+    "window_not_a_multiple": (64, 64, 16, dict(window=13)),
+    "window_beyond_sequence": (64, 64, 16, dict(window=1000)),
+    "unequal_offsets": (64, 64, 16, dict(window=13, q_offset=24, kv_offset=5)),
+    "keys_partly_future": (32, 32, 16, dict(q_offset=3, kv_offset=10)),
+    "t_q_less_than_t_k": (32, 64, 16, dict(window=20, q_offset=32)),
+    "fit_block_shrunk_96": (96, 96, 64, dict(window=30)),
+    "fit_block_shrunk_48": (48, 48, 32, dict(window=7)),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_flash_grouped_kv_matches_dense_on_repeated(group, case):
+    """Forward and all three gradients on grouped K/V ``[B, T, KV, D]``
+    equal dense attention on K/V explicitly repeated to the query heads
+    (so dK/dV are the group's sums), across the band's edge cases."""
+    t_q, t_k, block, kw = GROUPED_CASES[case]
+    q, k, v = _grouped_qkv(jax.random.PRNGKey(group), t_q, t_k, 8, 8 // group)
+
+    def flash(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block, **kw
+        )
+
+    def dense(q, k, v):
+        rep = lambda x: jnp.repeat(x, group, axis=2)
+        return dot_product_attention(q, rep(k), rep(v), causal=True, **kw)
+
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=1e-5, rtol=1e-5)
+    weight = jax.random.normal(jax.random.PRNGKey(99), q.shape)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * weight)
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for gf, gd, like in zip(g_flash, g_dense, (q, k, v)):
+        assert gf.shape == like.shape
+        np.testing.assert_allclose(gf, gd, atol=1e-4, rtol=1e-4)
+
+
+@pytest.fixture()
+def split(request, monkeypatch):
+    """``fa.SPLIT`` set for one test.  The kernels' own ``jax.jit`` keys on
+    their static arguments, not on the constant: drop what it holds."""
+    monkeypatch.setattr(fa, "SPLIT", request.param)
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("split", [1, 2, 4], indirect=True)
+def test_flash_every_split_gives_the_same(split):
+    """The cut changes which pairs sit in a masked tile, never a result:
+    blocks of 32 whole, in sub-tiles of 16, and of 8."""
+    q, k, v = _grouped_qkv(jax.random.PRNGKey(5), 64, 64, 4, 2)
+    kw = dict(causal=True, window=21, q_offset=7)
+
+    def loss(f, **blocks):
+        return lambda q, k, v: jnp.sum(f(q, k, v, **kw, **blocks) ** 2)
+
+    got = jax.value_and_grad(
+        loss(fa.flash_attention, block_q=32, block_k=32), argnums=(0, 1, 2)
+    )(q, k, v)
+    want = jax.value_and_grad(loss(dot_product_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def test_flash_grouped_kv_validation():
+    q, k, v = _grouped_qkv(jax.random.PRNGKey(0), 16, 16, 4, 3)
+    with pytest.raises(ValueError, match="divide"):
+        fa.flash_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="agree"):
+        fa.flash_attention(q, k[:, :, :2], v[:, :, :1], causal=True)
+    with pytest.raises(ValueError, match="divide"):
+        dot_product_attention(q, k, v, causal=True)
+
+
+def _visible_pairs(t_q, t_k, causal, window, q_offset, kv_offset):
+    """Brute force: the boolean matrix of visible (query, key) pairs."""
+    q = q_offset + np.arange(t_q)[:, None]
+    k = kv_offset + np.arange(t_k)[None, :]
+    if not causal:
+        return np.ones((t_q, t_k), bool)
+    seen = q >= k
+    return seen if window is None else seen & (q - k < window)
+
+
+# (t_q, t_k, block_q, block_k, sub, causal, window, q_offset, kv_offset)
+SCHEDULE_CASES = {
+    "trinity_2x2": (8192, 8192, 1024, 1024, 2, True, 2048, 0, 0),
+    "trinity_4x4": (8192, 8192, 2048, 2048, 4, True, 2048, 0, 0),
+    "trinity_unsplit": (8192, 8192, 1024, 1024, 1, True, 2048, 0, 0),
+    "mistral_2x2": (8192, 8192, 1024, 1024, 2, True, 4096, 0, 0),
+    "full_causal": (4096, 4096, 1024, 1024, 2, True, None, 0, 0),
+    "one_block_512": (512, 512, 512, 512, 2, True, 4096, 0, 0),
+    "odd_window_offsets": (768, 1280, 128, 160, 2, True, 333, 700, 130),
+    "narrow_band": (1024, 1024, 128, 128, 4, True, 50, 0, 0),
+    "unequal_blocks": (2048, 1024, 1024, 64, 2, True, 600, 0, 1000),
+    "all_future": (512, 512, 64, 64, 2, True, None, 0, 4096),
+    "not_causal": (512, 1024, 256, 512, 2, False, None, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(SCHEDULE_CASES))
+def test_block_schedule_against_brute_force(case):
+    """``pairs_visible`` is the brute-force count; every visible pair
+    lies in exactly one visited rectangle; an unmasked rectangle holds
+    only visible pairs, a masked one both kinds (or it would have been
+    unmasked or skipped); the counts add up to the grid."""
+    args = SCHEDULE_CASES[case]
+    t_q, t_k, block_q, block_k, sub, causal, window, q_off, kv_off = args
+    seen = _visible_pairs(t_q, t_k, causal, window, q_off, kv_off)
+    s = fa.block_schedule(*args)
+    assert s.pairs_visible == int(seen.sum())
+    covered = np.zeros_like(seen, np.int32)
+    computed = 0
+    for q0, rows, k0, cols, masked in fa.schedule_visits(*args):
+        part = seen[q0:q0 + rows, k0:k0 + cols]
+        assert part.any()
+        assert part.all() != masked
+        covered[q0:q0 + rows, k0:k0 + cols] += 1
+        computed += rows * cols
+    assert covered.max(initial=0) <= 1 and (covered[seen] == 1).all()
+    assert s.pairs_computed == computed
+    assert s.useful_share == pytest.approx(int(seen.sum()) / max(computed, 1))
+    per_block = (block_q // s.sub_q) * (block_k // s.sub_k)
+    straddling = (s.tiles_skipped + s.tiles_unmasked + s.tiles_masked) // per_block
+    assert s.steps_skipped + s.blocks_unmasked + straddling == s.grid[0] * s.grid[1]
+
+
+@pytest.mark.parametrize("case", list(SCHEDULE_CASES))
+def test_the_grids_walk_every_block_with_a_visible_pair(case):
+    """Both bands (the kv blocks a q block walks; the q blocks a kv
+    block walks, for dK/dV): every block that holds a visible pair is
+    walked exactly once, within the axis, and the block held in VMEM
+    changes only inside the run (no DMA for a step that computes
+    nothing)."""
+    t_q, t_k, block_q, block_k, _, causal, window, q_off, kv_off = (
+        SCHEDULE_CASES[case]
+    )
+    seen = _visible_pairs(t_q, t_k, causal, window, q_off, kv_off)
+    blocks = seen.reshape(t_q // block_q, block_q, t_k // block_k, block_k)
+    active = blocks.any(axis=(1, 3))  # [q block, kv block]
+    bands = fa._bands(t_q, t_k, block_q, block_k, causal, window, q_off, kv_off)
+    for band, table in zip(bands, (active, active.T)):
+        assert band.steps <= table.shape[1]
+        for x, row in enumerate(table):
+            walked = [band.block(x, s) for s in range(band.steps)]
+            assert walked == list(range(walked[0], walked[0] + band.steps))
+            assert 0 <= walked[0] and walked[-1] < table.shape[1]
+            assert set(np.flatnonzero(row)) <= set(walked)
+            held = [band.fetch(x, s) for s in range(band.steps)]
+            assert all(0 <= h < table.shape[1] for h in held)
+            for s, (w, h) in enumerate(zip(walked, held)):
+                if row[w]:
+                    assert h == w
+            if row.any():
+                assert set(held) == set(np.flatnonzero(row))
+            else:
+                assert len(set(held)) == 1
+        if table.any():  # no narrower grid would do
+            assert band.steps == max(
+                np.flatnonzero(r)[-1] - np.flatnonzero(r)[0] + 1
+                for r in table if r.any()
+            )
+
+
+def test_block_schedule_at_the_benchmark_shape():
+    """T = 8,192, window 2,048, blocks of 1,024: 14,681,088 visible
+    pairs.  Whole straddling blocks (the parent's schedule) compute 21
+    blocks = 22.0M pairs, 67% useful; cut 2 x 2, 70 sub-tiles of 512 =
+    18.35M, 80%."""
+    args = (8192, 8192, 1024, 1024)
+    whole = fa.block_schedule(*args, 1, True, 2048, 0, 0)
+    assert whole.pairs_visible == 14_681_088
+    assert (whole.blocks_unmasked, whole.tiles_masked) == (7, 14)
+    assert whole.pairs_computed == 21 * 1024 * 1024 == 22_020_096
+    assert round(100 * whole.useful_share) == 67
+    cut = fa.block_schedule(*args, 2, True, 2048, 0, 0)
+    assert (cut.sub_q, cut.sub_k) == (512, 512)
+    # The grid is the band, not the square: 8 x 3 steps a head where the
+    # parent walked 8 x 8 and skipped 43 of them (each still a DMA).
+    assert (cut.grid, cut.grid_dkv, cut.steps_skipped) == ((8, 3), (8, 3), 3)
+    assert 4 * cut.blocks_unmasked + cut.tiles_unmasked + cut.tiles_masked == 70
+    assert (cut.tiles_unmasked, cut.tiles_masked, cut.tiles_skipped) == (14, 28, 14)
+    assert cut.pairs_computed == 70 * 512 * 512 == 18_350_080
+    assert round(100 * cut.useful_share) == 80
+
+
+def test_fit_split_keeps_sub_tiles_aligned():
+    assert [fa._fit_split(b, 2) for b in (1024, 2048, 512, 256, 640)] == [2, 2, 1, 1, 1]
+    assert [fa._fit_split(b, 4) for b in (1024, 2048, 1536, 768)] == [2, 4, 3, 1]
+    assert [fa._fit_split(b, 2) for b in (16, 24, 48, 8, 1)] == [2, 1, 2, 1, 1]
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_kernels_visit_exactly_the_schedule(kernel):
+    """A NaN planted in one sub-tile's keys (queries, for dK/dV) reaches
+    exactly the rows of the rectangles ``schedule_visits`` lists over it
+    — a masked tile multiplies it by zero, which is NaN; a skipped tile
+    never reads it.  Interpret mode."""
+    t, block, sub, window, d = 64, 32, 16, 24, 8
+    kw = dict(scale=d ** -0.5, causal=True, block_q=block, block_k=block,
+              q_offset=0, kv_offset=0, interpret=True, window=window)
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v, do = (jax.random.normal(key, (1, t, d)) for key in keys)
+    o, lse = fa._flash_forward(q, k, v, **kw)
+    visits = fa.schedule_visits(t, t, block, block, fa.SPLIT, True, window, 0, 0)
+    assert {(v_[1], v_[3]) for v_ in visits} == {(sub, sub)}  # no whole block
+    skipped = 0
+    for start in range(0, t, sub):
+        poison = lambda x: x.at[:, start:start + sub].set(jnp.nan)
+        if kernel == "fwd":
+            got, _ = fa._flash_forward(q, k, poison(v), **kw)
+        elif kernel == "dq":
+            got = fa._flash_backward_pallas(q, poison(k), v, o, lse, do, **kw)[0]
+        else:
+            got = fa._flash_backward_pallas(poison(q), k, v, o, lse, do, **kw)[1]
+        want = np.zeros(t, bool)
+        for q0, rows, k0, cols, _ in visits:
+            if kernel == "dkv" and q0 == start:
+                want[k0:k0 + cols] = True
+            if kernel != "dkv" and k0 == start:
+                want[q0:q0 + rows] = True
+        np.testing.assert_array_equal(np.isnan(got[0]).any(-1), want)
+        skipped += int((~want).sum())
+    assert skipped > 0  # some sub-tile over each strip really is skipped
+
+
+def test_attn_schedule_record_when_armed():
+    """Armed while a kernel is traced, the flight recorder gets one
+    ``attn.schedule`` record carrying ``block_schedule``'s result."""
+    q, k, v = _grouped_qkv(jax.random.PRNGKey(1), 64, 64, 4, 2)
+    call = lambda: fa.flash_attention(
+        q, k, v, causal=True, window=24, block_q=32, block_k=32
+    )
+    call()  # disarmed: nothing recorded, nothing raised
+    rec = telemetry.install(party="alice")
+    try:
+        call()
+        got = [r for r in rec.records() if r.phase == "attn.schedule"]
+    finally:
+        telemetry.uninstall()
+    assert len(got) == 1  # the forward's; a gradient adds the backward's
+    want = fa.block_schedule(64, 64, 32, 32, fa.SPLIT, True, 24, 0, 0)
+    detail = got[0].detail
+    assert detail["useful_share"] == want.useful_share
+    assert detail["tiles_masked"] == want.tiles_masked
+    assert tuple(detail["grid"]) == want.grid == (2, 2)
+    assert (detail["heads"], detail["kv_heads"], detail["window"]) == (4, 2, 24)
+
+
+# --- what a process pays to trace the kernels: the size budget -------------
+
+# (query heads, K/V heads, window) of the three 8,192-token attention
+# shapes the benchmark runs: Trinity-Mini's two kinds and Mistral's.
+BENCHMARK_SHAPES = {
+    "trinity_window_2048": (32, 4, 2048),
+    "trinity_full": (32, 4, None),
+    "mistral_window_4096": (32, 8, 4096),
+}
+# Equations in the forward, dQ and dK/dV kernels' jaxprs: twice what the
+# kernels had with two copies of the tile body (145 / 98 / 113 at the
+# parent of PR 30).  Thirteen copies (700 / 498 / 581, 26 / 39 / 52
+# products) cost two cells their set-up bound (ledger, PR 29).
+MAX_EQUATIONS = (290, 196, 226)
+# Matrix products: 2 / 3 / 4 a tile body, at most four bodies a kernel
+# (whole block; sub-tile unmasked, cut by the diagonal, cut by the band).
+MAX_PRODUCTS = (8, 12, 16)
+
+
+@pytest.mark.parametrize("shape", list(BENCHMARK_SHAPES))
+def test_kernel_programs_stay_within_the_size_budget(shape):
+    """Tracing and lowering the kernels is host work every party's thread
+    does before it can ask the compile cache: its size is held here, as
+    numbers, at the shapes the benchmark runs (nothing is compiled)."""
+    from tool.flash_sweep import attention_grad, kernel_counts
+
+    heads, kv_heads, window = BENCHMARK_SHAPES[shape]
+    grad, args = attention_grad(fa, (1, 8192, heads, kv_heads, 128, window))
+    counts = kernel_counts(jax.make_jaxpr(grad)(*args).jaxpr)
+    assert len(counts) == 3  # forward, dQ, dK/dV
+    for (equations, products), most, most_products in zip(
+        counts, MAX_EQUATIONS, MAX_PRODUCTS
+    ):
+        assert equations <= most
+        assert products <= most_products
+    assert sum(n for n, _ in counts) <= 2 * 356
+
+
+def test_a_process_traces_each_kernel_once(monkeypatch):
+    """The wrappers of the three ``pallas_call``s are jitted on their
+    static arguments: a second program that holds the same call (another
+    party's step, the forward a checkpoint recomputes, the other branch
+    of a ``cond``) finds the kernels' jaxprs, and another window does not."""
+    traced = []
+    for name in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+        def counting(*args, _kernel=getattr(fa, name), _name=name, **kw):
+            traced.append(_name)
+            return _kernel(*args, **kw)
+
+        monkeypatch.setattr(fa, name, counting)
+    q, k, v = _grouped_qkv(jax.random.PRNGKey(2), 32, 32, 4, 2)
+
+    def program(window):
+        def loss(q, k, v):
+            out = fa.flash_attention(
+                q, k, v, causal=True, window=window, block_q=16, block_k=16
+            )
+            return jnp.sum(out ** 2)
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+    jax.clear_caches()
+    first = program(9)(q, k, v)
+    # Once each, though a gradient traces the forward twice (the primal
+    # function, then the rule that keeps the residuals).
+    assert sorted(traced) == [
+        "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel", "_flash_fwd_kernel"
+    ]
+    again = program(9)(q, k, v)  # a new program, the same kernels
+    assert len(traced) == 3
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    program(10)(q, k, v)
+    assert len(traced) == 6
+    jax.clear_caches()  # the counting kernels leave with the test

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from rayfed_tpu.models import bert, llama, logistic, lora, resnet
+from rayfed_tpu.ops.attention import dot_product_attention
 from rayfed_tpu.ops.flash_attention import flash_attention
 from rayfed_tpu.parallel import create_mesh
 from rayfed_tpu.parallel.sharding import ShardingStrategy, shard_params_by_rules
@@ -163,6 +164,49 @@ def test_llama_flash_attention_matches_dense():
         ),
     )
     np.testing.assert_allclose(dense, flash, atol=1e-4, rtol=1e-4)
+
+
+def _repeated_then_dense(q, k, v, **kw):
+    """What the models did before the kernels read K/V by KV head:
+    repeat to the query heads, then attend."""
+    reps = q.shape[2] // k.shape[2]
+    return dot_product_attention(
+        q, jnp.repeat(k, reps, axis=2), jnp.repeat(v, reps, axis=2), **kw
+    )
+
+
+@pytest.mark.parametrize("attn", ["dense", "flash"])
+@pytest.mark.parametrize("window", [None, 8])
+def test_llama_unrepeated_kv_matches_repeated(attn, window):
+    """GQA (4 query heads on 2 K/V heads): logits and adapter gradients
+    with K/V handed to the ``attn_fn`` unrepeated equal those of the
+    explicit repeat, dense and through the flash kernel."""
+    cfg = llama.llama_tiny(sliding_window=window)
+    params = llama.init_llama(jax.random.PRNGKey(0), cfg)
+    adapters = lora.init_lora(
+        jax.random.PRNGKey(2), params, lora.LoraConfig(rank=4, targets=(r"w[qkv]$",))
+    )
+    adapters = jax.tree_util.tree_map(
+        lambda x: x + 0.02 if x.ndim > 2 else x, adapters
+    )
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
+    attn_fn = dot_product_attention if attn == "dense" else (
+        lambda q, k, v, **kw: flash_attention(q, k, v, block_q=16, block_k=16, **kw)
+    )
+
+    def loss(adapters, attn_fn):
+        logits = llama.apply_llama(params, ids, cfg, lora=adapters, attn_fn=attn_fn)
+        return llama.lm_loss(logits[:, :-1], ids[:, 1:]), logits
+
+    (l_got, got), g_got = jax.value_and_grad(loss, has_aux=True)(adapters, attn_fn)
+    (l_want, want), g_want = jax.value_and_grad(loss, has_aux=True)(
+        adapters, _repeated_then_dense
+    )
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves(g_want)
+    ):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4, err_msg=str(path))
 
 
 def test_llama_ring_sp_matches_dense():

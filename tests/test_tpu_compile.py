@@ -59,7 +59,7 @@ def _on_chip(tree, sharding=None):
     )
 
 
-def _flash_fwd_bwd(t, d, heads=2, **kw):
+def _flash_fwd_bwd(t, d, heads=2, kv_heads=None, **kw):
     from rayfed_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
@@ -69,7 +69,8 @@ def _flash_fwd_bwd(t, d, heads=2, **kw):
         )
 
     q = jax.ShapeDtypeStruct((1, t, heads, d), jnp.bfloat16)
-    q, k, v = _on_chip((q, q, q))
+    k = jax.ShapeDtypeStruct((1, t, kv_heads or heads, d), jnp.bfloat16)
+    q, k, v = _on_chip((q, k, k))
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
 
 
@@ -165,6 +166,16 @@ CASES = {
     "flash_t4096_d64": (lambda: _flash_fwd_bwd(4096, 64), True),
     "flash_t2048_d128_window1024": (
         lambda: _flash_fwd_bwd(2048, 128, window=1024), True,
+    ),
+    # Grouped K/V read by KV head, as the benchmark's two models run it
+    # (Trinity-Mini: 32/4 heads, window 2,048; Mistral: 32/8, 4,096).
+    "flash_t8192_d128_h32_kv4_window2048": (
+        lambda: _flash_fwd_bwd(8192, 128, heads=32, kv_heads=4, window=2048),
+        True,
+    ),
+    "flash_t8192_d128_h32_kv8_window4096": (
+        lambda: _flash_fwd_bwd(8192, 128, heads=32, kv_heads=8, window=4096),
+        True,
     ),
     "resnet18_fed_train_step": (_resnet18_fed_step, False),
     "quantized_accum_kernel_resnet18": (_quantized_accum, False),
