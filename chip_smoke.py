@@ -14,7 +14,7 @@ process (``rayfed_tpu.inprocess`` — a chip belongs to one process at a
 time), 3 rounds after warm-up, through ``fed.init`` / ``@fed.remote`` /
 ``run_fedavg_rounds`` / ``fed.shutdown``, in two wire forms (packed
 bf16; uint8 codes folded by ``quantized_accum_kernel``); then two train
-steps of a Llama at the ``bench.py`` widths with the Pallas flash
+steps of a Llama (widths below; depth cut to 2) with the Pallas flash
 kernel, against the dense attention reference.
 
 ``--chips 4`` runs only: (A) the same ResNet-18 rounds with each party
@@ -46,7 +46,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 PLATFORM = "tpu"  # what every device must be; the script has no CPU mode
 PARTIES = ("alice", "bob", "carol", "dave")
 ROUNDS = 3  # measured rounds, after warm-up
-BATCH, HW = 32, 32  # per-party CIFAR-shaped batch (bench.py RESNET_*)
+BATCH, HW = 32, 32  # per-party CIFAR-shaped batch
 
 # The two wire forms take different device code.  Warm-up rounds compile:
 # the quantized form needs two (its first round has no observed delta to
@@ -76,7 +76,7 @@ BF16_RTOL = 2.0**-8 * 1.01
 QUANT_DELTA_FRAC = 0.1
 # Flash vs dense attention, same bf16 step: losses near ln(vocab) = 9.7.
 LLAMA_LOSS_ATOL = 0.05
-LLAMA_LAYERS = 2  # depth cut from bench.py's 16; every width as there
+LLAMA_LAYERS = 2  # depth cut from 16 to keep the compile short
 
 
 class SmokeError(RuntimeError):
@@ -191,7 +191,7 @@ def run_resnet_rounds(form: str, seed: int, party_devices, coordinator):
 
     spec = WIRE_FORMS[form]
     cfg = resnet.resnet18(num_classes=10)
-    # ONE jit shared by the party actors (bench.py's trainer shape).
+    # ONE jit shared by the party actors.
     fed_step = resnet.make_fed_train_step(cfg, lr=0.05)
     records: dict = {}
 

@@ -41,9 +41,8 @@ def run(party: str, rounds: int = ROUNDS) -> float:
     # Adapters on attention projections + the lm_head.
     lcfg = lora.LoraConfig(rank=4, targets=(r"w[qv]$", r"lm_head$"))
 
-    # Same tuner shape as tests/test_fl_lora.py and bench.py's LoRA
-    # config — change them together (CI drives this file directly via
-    # tests/test_examples.py).
+    # Same tuner shape as tests/test_fl_lora.py — change them together
+    # (CI drives this file directly via tests/test_examples.py).
     @fed.remote
     class Tuner:
         """Party-local fine-tuner: frozen base + private ids stay resident."""

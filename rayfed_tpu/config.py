@@ -193,7 +193,7 @@ class JobConfig:
     # env var arms it too, like RAYFED_CHAOS).  Disarmed, every
     # emission site costs one module-global read; armed, a span write
     # is a ring append — never a sleep, never I/O — so tracing adds
-    # ~zero to the round wall (bench-gated: trace_overhead_frac
-    # <= 0.03).  trace_capacity bounds the ring (records, not bytes).
+    # ~zero to the round wall (the chip benchmark's ``trace_overhead``
+    # metric).  trace_capacity bounds the ring (records, not bytes).
     trace: bool = False
     trace_capacity: int = 16384

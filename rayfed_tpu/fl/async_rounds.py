@@ -735,9 +735,9 @@ def run_async_fleet(
 ) -> Dict[str, Any]:
     """In-process virtual-party harness: N loopback TransportManagers
     (local-link auto-upgrade), one thread per party, the first name
-    coordinating — the PR 16/17 bench topology, packaged so tests and
-    ``bench.py --smoke`` drive the identical fleet instead of two
-    hand-rolled copies.  No party subprocesses, by design: the tier-1
+    coordinating — the in-process topology of the hierarchy tests,
+    packaged so every test drives the identical fleet instead of a
+    hand-rolled copy.  No party subprocesses, by design: the tier-1
     budget rides in-process fleets (ISSUE 20 satellite 6).
     """
     import socket

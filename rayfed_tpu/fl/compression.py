@@ -375,30 +375,6 @@ def decompress(tree: Any, dtype=jnp.float32) -> Any:
         return cast_floats(tree, dtype)
 
 
-# Re-export the shared-grid integer codec: one import surface for wire
-# forms.  Lazy (PEP 562) because rayfed_tpu.fl.quantize subclasses
-# PackedTree and therefore imports THIS module first — an eager import
-# here would be circular when quantize is imported before compression.
-_QUANTIZE_EXPORTS = (
-    "QuantCompressor",
-    "QuantGrid",
-    "QuantizedPackedTree",
-    "dequantize_packed",
-    "make_round_grid",
-    "quantize_packed",
-)
-
-
-def __getattr__(name: str):
-    if name in _QUANTIZE_EXPORTS:
-        from rayfed_tpu.fl import quantize
-
-        return getattr(quantize, name)
-    raise AttributeError(
-        f"module {__name__!r} has no attribute {name!r}"
-    )
-
-
 __all__ = [
     "PackSpec",
     "PackedTree",
@@ -408,10 +384,4 @@ __all__ = [
     "decompress",
     "pack_tree",
     "unpack_tree",
-    "QuantCompressor",
-    "QuantGrid",
-    "QuantizedPackedTree",
-    "dequantize_packed",
-    "make_round_grid",
-    "quantize_packed",
 ]

@@ -98,8 +98,8 @@ region coordinator: the region's partial-sum gather (~2·|codes| at
 int16) + one buffer up + the broadcast fan-down — independent of N for
 a fixed region COUNT, and bounded by the region size otherwise.  The
 root's ingress is (regions−1) partial-sum buffers — no node at any
-level sees O(N) ingress (gated by ``bench.py --smoke``'s
-traffic-vs-N section at N ∈ {4, 16, 64}).
+level sees O(N) ingress (held by ``tests/test_wire_budget.py``'s
+``hier_*`` cases at N ∈ {4, 16}).
 
 **Failure story.**  Any mid-round failure poisons every key the
 failing party owed (the ring's cascade, tree-shaped: errors travel up
@@ -587,9 +587,9 @@ class HierarchyRound:
     Deliberately driven through a :class:`~rayfed_tpu.transport.manager.
     TransportManager`-shaped object (``send``/``send_many``/``recv``/
     ``recv_stream_many``/``cancel_stream``) rather than the fed runtime:
-    the fed wrapper (:func:`hierarchy_aggregate`), the traffic bench
-    (``bench.py``'s N∈{4,16,64} virtual parties) and the in-process
-    chaos tests all drive EXACTLY this class, so what the bench gates is
+    the fed wrapper (:func:`hierarchy_aggregate`), the traffic budgets
+    (``tests/test_wire_budget.py``'s virtual parties) and the in-process
+    chaos tests all drive EXACTLY this class, so what the tests hold is
     what the driver ships.
 
     ``keys`` are the round's six rendezvous ids ``(rs, ps, up, down,

@@ -44,8 +44,8 @@ close.
 
 This module also absorbs the seed-era :mod:`rayfed_tpu.fl.secure` demo:
 its in-process fixed-point primitives (:func:`pairwise_key`,
-:func:`mask_update`, :func:`unmask_sum`) live here now, and
-``fl/secure.py`` is a thin deprecated shim.
+:func:`mask_update`, :func:`unmask_sum`) live here now; the
+``fl/secure.py`` shim that re-exported them is gone.
 """
 
 from __future__ import annotations
@@ -571,8 +571,8 @@ class MaskedRoundCodec(RoundCodec):
 
 
 # ---------------------------------------------------------------------------
-# Seed-era in-process primitives (moved from fl/secure.py — that module
-# is now a deprecated shim over these)
+# Seed-era in-process primitives (moved from fl/secure.py, which is
+# gone)
 # ---------------------------------------------------------------------------
 
 _MOD = 2**32

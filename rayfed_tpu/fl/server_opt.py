@@ -184,8 +184,8 @@ def fedac(lam: float = 1.0, gamma: float = 3.0,
     coupling weight of the aggressive sequence in the next broadcast
     point.  ``lam=1, beta=0`` is plain FedAvg bit-exactly; moderate
     ``gamma``/``beta`` provably cut rounds-to-target on smooth
-    objectives (benched on the quadratic + toy-logistic workloads —
-    ``fedac_rounds_to_target_frac`` in ``bench.py --smoke``).
+    objectives (``tests/test_server_opt.py`` holds at most 0.8x plain
+    FedAvg's rounds to target on the quadratic workload).
     """
     return PackedServerOpt("fedac", (lam, gamma, beta))
 

@@ -362,6 +362,8 @@ class ObjectPlane:
             if owner:
                 fut = _futures.Future()
                 self._inflight[fp] = fut
+            else:
+                self.stats["blob_dedup_waits"] += 1
         if not owner:
             # Concurrent-fetch dedup: ride the in-flight transfer.  The
             # owner may legitimately spend up to one backstop PER named
@@ -369,7 +371,6 @@ class ObjectPlane:
             # holder count — and a waiter timeout surfaces as the
             # plane's own loud error type, never a bare futures
             # TimeoutError.
-            self.stats["blob_dedup_waits"] += 1
             t0_wall, t0 = time.time(), time.perf_counter()
             try:
                 data = fut.result(
@@ -469,8 +470,9 @@ class ObjectPlane:
                         self.party, fp, holder, exc.kind, exc,
                     )
                 continue
-            self.stats["blob_fetches"] += 1
-            self.stats["blob_fetch_bytes"] += len(data)
+            with self._lock:
+                self.stats["blob_fetches"] += 1
+                self.stats["blob_fetch_bytes"] += len(data)
             return data
         raise ObjectPlaneError(
             f"blob pull of {fp} failed at every named holder: "

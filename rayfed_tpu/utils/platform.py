@@ -1,7 +1,7 @@
 """Platform and compile-cache helpers.
 
-Two ways to run the program.  On the CPU (tests, the ``bench.py
---smoke`` tripwire, the multi-process examples) a process pins JAX to
+Two ways to run the program.  On the CPU (tests and the
+multi-process examples) a process pins JAX to
 the CPU platform with N virtual devices — :func:`force_cpu_devices`,
 called before the first backend initialization.  On the chip JAX's
 default platform is the accelerator and ONE process holds it, so every

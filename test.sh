@@ -9,7 +9,7 @@ set -x
 cd "$(dirname "$0")"
 
 if command -v ruff >/dev/null 2>&1; then
-  ruff check rayfed_tpu tests bench.py
+  ruff check rayfed_tpu tests
 else
   echo "ruff not installed; skipping lint"
 fi
@@ -48,126 +48,5 @@ print('secagg suite under test: kex=%s prg=%s%s' % (
 "
 
 JAX_PLATFORMS=cpu python -m pytest tests/ -q "$@"
-
-# Fast bench smoke: drives the streaming-aggregation + delta-cache
-# pipeline, the 4-party ring reduce-scatter round, the pipelined
-# (overlap=True) round engine AND the arena/multi-rail coordinator
-# send path end-to-end over real sockets (small bundles) so a
-# transport/aggregation regression fails CI, not the next bench round.
-# Gates: coord_bytes_in_frac <= 0.4 (the ring must keep the
-# coordinator's share of cluster ingress at ~1/N; the hub pins it at
-# ~0.5), overlap_hidden_comm_frac >= 0.5 (the pipelined engine must
-# hide at least half the per-round comms wall under local compute),
-# wire_vs_push_capability >= 0.5 (the FedAvg exchange must sustain at
-# least half the same-box push capability — the r05 send-path gap was
-# 0.24), send_vs_read_wall_ratio <= 1.5 (no full-payload
-# serialization barrier in front of the coordinator's broadcast; the
-# r05 send/read imbalance was 2.7x), the COMPRESSED-DOMAIN gates:
-# compressed_bytes_on_wire_frac <= 0.55 (shared-grid uint8 rounds vs
-# the bf16 path, both directions), compressed_fold_speedup >= 1.0
-# (the donated-i32 integer fold must beat dequantize-first),
-# compressed_agg_bitexact (streamed integer fold == one-shot
-# packed_quantized_sum) and compressed_loss_ratio <= 1.05 (8-bit+EF
-# converges with f32 — equal converged accuracy), the SECURE-AGGREGATION
-# gates: secagg_bitexact (the pairwise-masked round's aggregate is
-# BYTE-identical to the plain quantized round's — masks cancel in the
-# integer ring, never approximately) and secagg_overhead_frac <= 0.05
-# (masks ride zero wire bytes and the keystream prefetch hides under
-# the local step, so masking costs at most 5% of a realistic round),
-# the SERVER-OPTIMIZATION gates
-# (fl.server_opt, packed FedAC at the single finalize):
-# fedac_rounds_to_target_frac <= 0.8 (FedAC reaches the quadratic
-# smoke workload's target loss in at most 0.8x plain FedAvg's rounds —
-# the ROUNDS lever, now that the seconds-per-round north-star sits at
-# 0.93; measured ~0.15) and server_opt_agg_bitexact (the POST-step
-# quantized downlink, decoded from serialized wire bytes as a
-# receiving controller would, is byte-identical across the streaming
-# fold, the quorum-cutoff subset refold feeding the step, and the
-# hierarchy's regrouped presummed fold),
-# and the CHAOS gate:
-# under a
-# seeded schedule injecting 1 straggler past the round deadline, 1
-# hard party crash at N=4, AND a hard kill of the COORDINATOR between
-# round 2's quorum cutoff and its broadcast, run_fedavg_rounds(
-# quorum=2) must complete every round on every surviving controller
-# with identical bytes, a strict-subset round-1 quorum, a roster epoch
-# advanced >= 2 (both corpses dropped without any runtime restart),
-# and coordinator_failovers >= 1 on every survivor (the killed round
-# was re-established at the deterministic successor).
-# OBJECT-PLANE gates (content-addressed pull-on-demand,
-# transport/objectstore.py): rejoin_welcome_bytes_frac <= 0.1 — a
-# WARM welcome-by-handle rejoin (the joiner's content cache already
-# holds the round model, as every quorum participant's does) moves at
-# most 0.1x the eager welcome push's payload bytes (measured ~2e-4:
-# only the fingerprint handle crosses the wire);
-# blob_dedup_single_transfer — 6 concurrent fetches of one
-# fingerprint collapse to exactly ONE BLOB_GET/BLOB_PUT transfer;
-# blob_handle_state_identical — handle-resolved state is
-# BYTE-identical to the eager-push state (receiver-decoded bytes).
-# HIERARCHY gates (traffic-vs-N flatness, fl.hierarchy): at
-# N ∈ {4, 16, 64} in-process virtual parties (2 regions, region rings
-# + quantized cross-region partial-sum streaming), every N must hold
-# (1) hier_bitexact — the hierarchical aggregate BYTE-identical to the
-# one-shot packed_quantized_sum over all N contributions, (2)
-# hier_party_bytes_frac_N <= 1.25 — mean per-party bytes-on-wire within
-# 1.25x of 2·|model| (the flat-traffic budget: one contribution out,
-# one broadcast in), and (3) hier_ingress_flatness <= 1.6 — the
-# max-ingress-at-any-node ratio between N=64 and N=4 stays ~flat (no
-# O(N) hub at ANY level; the flat hub's coordinator ingress scales
-# ~N/2x over the same range), and (4) hier_round_ratio_64_over_16 <= 12
-# — the N=64 round wall stays well sublinear in the ~14x message-count
-# growth over N=16 (the local-link fast path's per-message-cost gate;
-# ~23x before it), with flight-recorder trace_phases attribution
-# landing in the report alongside the number.  The denominator is the
-# slower of two N=16 walls bracketing the N=64 leg so host-speed drift
-# between measurement windows cannot read as a per-message regression;
-# the threshold is 12, not 8, because identical code (clean HEAD
-# included) measured 6.8-10.2 across back-to-back runs on a 1-vCPU CI
-# host — the ~200ms N=16 leg's min-of-3 swings 40% on scheduler luck.  MULTI-LEVEL gates (N=256, 16
-# regions x 16 folding through branch=4 interior nodes, quorum-hub
-# leaves + region-ring downlink; FD-ceiling-checked, skipped only
-# when the soft limit cannot reach 4096): (5)
-# hier_round_ratio_256_over_64 <= 4 — the thousand-silo scaling gate
-# (per-level trace_phases + hier_level_ingress_256 name the guilty
-# tree level on a trip), (6) hier_root_egress_frac_256 <= 8 — root
-# bytes out stay ~O(branch·|model|), flat in N (the region-ring
-# downlink; O(N) coordinator fan-out would sit ~32x), and (7) the
-# seeded straggling-region chaos round completes with >= 1 per-region
-# quorum cutoff, ZERO abort-and-flatten fallbacks, and full
-# cross-party byte agreement (hier_chaos_fallbacks == 0,
-# hier_chaos_agree, hier_chaos_cutoffs >= 1).
-# LOCAL-LINK gates (transport/local.py, per-link backend upgrade):
-# local_link_vs_wire >= 2.0 — a colocated pair (shm handoff via
-# local_link="auto") must move the send-path payload shape at >= 2x
-# the loopback-TCP FedAvg-path wire rate — and the auto probe must
-# actually have picked the shm backend for a same-interpreter pair
-# (local_link_backend == "shm"; uds is reported alongside as
-# local_link_uds_GBps).
-# TELEMETRY gates (flight recorder, rayfed_tpu/telemetry.py):
-# trace_overhead_frac <= 0.03 — paired armed-vs-disarmed
-# streaming-aggregation round deltas (order-balanced pairs; drift
-# cancels in-pair), gated on the MIN over three block medians (a real
-# hot-path sleep/IO shifts every block; scheduler noise must strike
-# all three) staying within 3% (an emission is a bounded ring append,
-# never blocking I/O);
-# trace_critical_path_agrees — the cross-manager merged trace
-# (TRACE_GET/TRACE_PUT collection + clock-offset alignment) yields
-# tool/trace_report per-round critical-path walls that reconcile with
-# the driver's own measured walls within 25%, exports non-empty
-# Perfetto trace_event JSON, and carries spans from all 4 parties.
-# BUFFERED-ASYNC gates (fl/async_rounds.py, ROADMAP item 2):
-# async_tt_frac <= 0.8 — time-to-target-loss of the buffered-async
-# fleet at most 0.8x the synchronous barrier's on the SAME quadratic
-# workload under the SAME seeded 2-10x local_slowdown straggler
-# schedule (the barrier pays the straggler's stretched step every
-# round; the buffer folds it in stale and shift-decayed instead);
-# async_refold_bitexact — every emitted model version BYTE-identical
-# to a sorted packed_quantized_sum refold of its recorded fold set
-# (the order-free exact-integer-decay contract, certified on the CI
-# host, not just in the unit suite); async_versions_per_sec >= 1.0 —
-# the N=64 in-process virtual-party fleet keeps emitting versions
-# (the coordinator's running donated-i32 fold + re-park loop must
-# not degrade to per-push model rebuilds; measured ~5/s).
-JAX_PLATFORMS=cpu python bench.py --smoke
 
 echo "All tests finished."

@@ -3,7 +3,7 @@
 Table-driven, in-process, one file: every PAIR of flagship round-loop
 features is classified as either COMPATIBLE — in which case
 ``fl.trainer.validate_round_config`` must accept the pair AND the table
-names the test/bench gate that verifies the composition bit-exactly —
+names the test that verifies the composition bit-exactly —
 or INCOMPATIBLE, in which case validation must raise a LOUD
 ``ValueError`` at ``run_fedavg_rounds`` entry.  A pair that is neither
 (validation silently accepts a combination nobody verifies, or a
@@ -109,21 +109,21 @@ RAISE = "raise"
 VERDICTS = {
     # --- wire_quant row ---------------------------------------------------
     ("wire_quant", "quorum"): (OK, "tests/test_secagg.py multiproc parity (quantized-quorum == quantized-streaming) + test_quantized_agg.py::test_quorum_subset_refold_bitexact"),
-    ("wire_quant", "ring"): (OK, "tests/test_ring.py quantized-gather recode identity (PR 12) + bench ring_quant_bytes_frac"),
-    ("wire_quant", "hierarchy"): (OK, "tests/test_hierarchy.py N=4 byte-identity vs flat + bench hier_bitexact"),
-    ("wire_quant", "secure_agg"): (OK, "tests/test_secagg.py stream_plain == stream_secure bytes + bench secagg_bitexact"),
-    ("wire_quant", "server_opt"): (OK, "tests/test_server_opt.py::test_quantized_downlink_after_step_parity + bench server_opt_agg_bitexact"),
+    ("wire_quant", "ring"): (OK, "tests/test_ring.py quantized-gather recode identity (PR 12)"),
+    ("wire_quant", "hierarchy"): (OK, "tests/test_hierarchy.py N=4 byte-identity vs flat"),
+    ("wire_quant", "secure_agg"): (OK, "tests/test_secagg.py stream_plain == stream_secure bytes"),
+    ("wire_quant", "server_opt"): (OK, "tests/test_server_opt.py::test_quantized_downlink_after_step_parity"),
     ("wire_quant", "server_opt_legacy"): (RAISE, "wire_quant is incompatible with"),
     ("wire_quant", "overlap"): (OK, "tests/test_overlap.py::test_overlap_quant_and_server_opt_compositions quantized-overlap RoundCodec replay (unified staleness recurrence: the corrected contribution's delta IS the local displacement)"),
     ("wire_quant", "checkpointer"): (OK, "tests/test_quorum.py::test_quorum_checkpoint_restore_roundtrip (quantized welcomes carry the grid delta)"),
-    ("wire_quant", "streaming_agg"): (OK, "tests/test_quantized_agg.py::test_streaming_integer_fold_bitexact_adversarial_order + bench compressed_agg_bitexact"),
+    ("wire_quant", "streaming_agg"): (OK, "tests/test_quantized_agg.py::test_streaming_integer_fold_bitexact_adversarial_order"),
     ("wire_quant", "error_feedback"): (RAISE, "wire_quant is incompatible with"),
     ("wire_quant", "sample"): (OK, "sampled quantized rounds ride the coordinator topology; tests/test_streaming_agg.py wire_quant e2e (full-set sample)"),
     # --- quorum row -------------------------------------------------------
     ("quorum", "ring"): (OK, "tests/test_quorum.py ring-mode fallback equality (quorum ring aborts re-aggregate with the cutoff)"),
     ("quorum", "hierarchy"): (OK, "tests/test_quorum.py quorum x hierarchy parity child (zero fallbacks, cross-party byte agreement)"),
     ("quorum", "secure_agg"): (OK, "tests/test_secagg.py quorum_secure == quorum_plain bytes + chaos e2e mask recovery"),
-    ("quorum", "server_opt"): (OK, "tests/test_server_opt.py::test_quorum_subset_refold_feeds_step_bitexact + bench server_opt_agg_bitexact (subset leg)"),
+    ("quorum", "server_opt"): (OK, "tests/test_server_opt.py::test_quorum_subset_refold_feeds_step_bitexact"),
     ("quorum", "server_opt_legacy"): (RAISE, "quorum is incompatible with"),
     ("quorum", "overlap"): (RAISE, "quorum is incompatible with"),
     ("quorum", "checkpointer"): (OK, "tests/test_quorum.py::test_quorum_checkpoint_restore_roundtrip (PR 7)"),
@@ -142,7 +142,7 @@ VERDICTS = {
     ("ring", "sample"): (RAISE, "requires full participation"),
     # --- hierarchy row ----------------------------------------------------
     ("hierarchy", "secure_agg"): (RAISE, "mutually"),
-    ("hierarchy", "server_opt"): (OK, "tests/test_server_opt.py::test_hierarchy_regrouped_fold_step_downlink_bitexact + bench server_opt_agg_bitexact (hierarchy leg)"),
+    ("hierarchy", "server_opt"): (OK, "tests/test_server_opt.py::test_hierarchy_regrouped_fold_step_downlink_bitexact"),
     ("hierarchy", "server_opt_legacy"): (RAISE, "wire_quant is incompatible with"),
     ("hierarchy", "overlap"): (RAISE, "overlap=True is incompatible with mode='hierarchy'"),
     ("hierarchy", "checkpointer"): (OK, "hierarchy rides the classic/quorum loops whose snapshots are topology-agnostic; tests/test_quorum.py restore"),

@@ -6,7 +6,7 @@ exercise of ``aggregate(mode="coordinator")``.  Mirrors the reference's
 multi-party test pattern (``/root/reference/tests/test_fed_get.py:47-82``)
 with a CV workload instead of scalars.
 
-The model is a deliberately tiny ResNet (the bench runs the full
+The model is a deliberately tiny ResNet (``chip_smoke.py`` runs the full
 ResNet-18; this host's test mesh is 1 CPU core shared by 4 processes) —
 what's under test is the cross-party protocol, not conv throughput.
 """
@@ -33,10 +33,10 @@ def run_resnet_fedavg(party, cluster=RESNET_CLUSTER):
     cfg = resnet.ResNetConfig(stage_sizes=(1, 1), width=8, num_classes=4)
     n, hw = 32, 8  # 8x8 images: conv stack is real, compute is tiny
 
-    # Same trainer shape as bench.py::_run_resnet_party (full ResNet-18
+    # Same trainer shape as chip_smoke.py's ResNet phase (full ResNet-18
     # there; tiny config here) — change them together: the fused
     # wire-dtype round (make_fed_train_step, bf16 bundles on the wire)
-    # is exactly the program the bench measures.
+    # is exactly the program that phase runs on the chip.
     @fed.remote
     class Trainer:
         def __init__(self, seed: int):

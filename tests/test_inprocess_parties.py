@@ -196,13 +196,22 @@ def test_shutdown_of_one_party_leaves_the_others_working():
     bob_done = threading.Event()
     errors = []
 
-    @fed.remote
-    def produce():
-        return {"x": np.arange(8, dtype=np.float32)}
+    def program():
+        # Each party decorates its own copy, as the other tests of this
+        # file do inside party_main: FedRemoteFunction.party() binds the
+        # SHARED decorated object to the calling thread's runtime, so
+        # two party threads calling one object race (alice's call then
+        # draws its seq id from bob's runtime and bob waits for a push
+        # that is never made: ROADMAP Queue 3 item 13).
+        @fed.remote
+        def produce():
+            return {"x": np.arange(8, dtype=np.float32)}
 
-    @fed.remote
-    def consume(v):
-        return float(np.sum(v["x"]))
+        @fed.remote
+        def consume(v):
+            return float(np.sum(v["x"]))
+
+        return consume.party("bob").remote(produce.party("alice").remote())
 
     def carol_main():
         try:
@@ -220,8 +229,7 @@ def test_shutdown_of_one_party_leaves_the_others_working():
                      process_default=False, logging_level="warning")
             assert carol_down.wait(60)
             assert get_runtime().party == "bob"
-            obj = consume.party("bob").remote(produce.party("alice").remote())
-            bob_got.append(obj.get_local_ref().resolve(timeout=60))
+            bob_got.append(program().get_local_ref().resolve(timeout=60))
             fed.shutdown()
         except BaseException as e:  # noqa: BLE001 — reported below
             errors.append(e)
@@ -249,7 +257,7 @@ def test_shutdown_of_one_party_leaves_the_others_working():
         assert seen and seen[0] is not None and seen[0].party == "alice"
         # alice runs the same program: her task's result is pushed to
         # its consumer, bob.
-        consume.party("bob").remote(produce.party("alice").remote())
+        program()
         assert bob_done.wait(60)
         assert not errors, errors
         assert bob_got == [28.0]

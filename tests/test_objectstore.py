@@ -20,6 +20,7 @@ welcome resolves by fingerprint there).
 import logging
 import os
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -202,6 +203,22 @@ def test_concurrent_fetch_single_transfer(manager_trio):
     handle = mgrs["alice"].objects.handle_for(fp, n)
     results, errors = [], []
 
+    # The holder serves only once the five other fetchers are parked
+    # on the owner's in-flight entry: a fetcher that started after the
+    # 128 KiB pull had landed would take the cache-hit branch, and the
+    # exact counts below would depend on thread start-up order.
+    serve = mgrs["alice"].objects._serve
+    bob_stats = mgrs["bob"].objects.stats
+
+    def _serve_when_all_parked(requester, req):
+        deadline = time.monotonic() + 20
+        while (bob_stats["blob_dedup_waits"] < 5
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        serve(requester, req)
+
+    mgrs["alice"].objects._serve = _serve_when_all_parked
+
     def _fetch():
         try:
             results.append(mgrs["bob"].objects.fetch(handle, timeout_s=30))
@@ -382,11 +399,19 @@ def test_welcome_by_handle_rejoin_byte_identity(manager_trio):
     bytes on both paths)."""
     mgrs = manager_trio
     model = _tree(10, n=1 << 14)
+
+    def bob_received():
+        # The joiner's side of the link: complete once its recv
+        # resolves, and nothing else of this test is sent to bob.
+        return mgrs["bob"].get_stats()["receive_bytes"]
+
     # Eager path: coordinator pushes the params inline.
+    received0 = bob_received()
     mgrs["alice"].send("bob", {"params": model}, "w.eager", "roster")
     eager = mgrs["bob"].recv("alice", "w.eager", "roster").resolve(
         timeout=30
     )["params"]
+    eager_bytes = bob_received() - received0
     # Handle path: coordinator publishes + sends the handle; the joiner
     # pulls (cold) and decodes.  Residency-canonicalized, exactly like
     # the quorum loop's publish sites.
@@ -412,11 +437,22 @@ def test_welcome_by_handle_rejoin_byte_identity(manager_trio):
     # transfer.
     mgrs["bob"].objects.publish(objects.canonical_host(eager))
     serves0 = mgrs["alice"].objects.stats["blob_serves"]
-    resolved_warm = mgrs["bob"].objects.fetch(got["model"], timeout_s=30)
+    received0 = bob_received()
+    mgrs["alice"].send("bob", welcome, "w.warm", "roster")
+    got_warm = mgrs["bob"].recv("alice", "w.warm", "roster").resolve(
+        timeout=30
+    )
+    resolved_warm = mgrs["bob"].objects.fetch(
+        got_warm["model"], timeout_s=30
+    )
     np.testing.assert_array_equal(
         np.asarray(resolved_warm.buf), np.asarray(eager.buf)
     )
     assert mgrs["alice"].objects.stats["blob_serves"] == serves0
+    # rejoin_welcome_bytes_frac: the warm welcome moves the handle
+    # alone, at most a tenth of the eager welcome's bytes.
+    warm_bytes = bob_received() - received0
+    assert 0 < warm_bytes <= 0.1 * eager_bytes, (warm_bytes, eager_bytes)
 
 
 def test_welcome_server_opt_state_roundtrip(manager_trio):

@@ -194,6 +194,9 @@ def run_allowlist(party, cluster):
         cross_silo_serializing_allowed_list={"numpy": "*", "numpy.core.numeric": "*"},
         cross_silo_timeout_in_seconds=10,
         cross_silo_retry_policy={"maxAttempts": 2, "initialBackoff": "0.2s"},
+        # Two attempts 0.2 s apart do not outlast a peer process that
+        # starts seconds later on a loaded host: wait for it first.
+        enable_waiting_for_other_parties_ready=True,
     )
 
     @fed.remote

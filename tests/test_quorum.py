@@ -727,7 +727,7 @@ def test_quorum_coordinator_crash_failover(tmp_path_factory):
     # rounds the report's wall reconciles with the driver's own
     # measured wall; the failover round is bounded by health-monitor
     # waits the ring records too, so it must at least be present.
-    report = round_report(records, tolerance=0.5)
+    report = round_report(records, tolerance=0.25)
     assert set(report) == tagged
     clean = [r for r in sorted(tagged) if r >= 2]
     assert clean and all(report[r]["wall_agrees"] for r in clean), {

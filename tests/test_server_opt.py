@@ -300,8 +300,7 @@ def test_quantized_downlink_after_step_parity(cutoff):
 
 def test_hierarchy_regrouped_fold_step_downlink_bitexact():
     """Region partial sums folded at the root + ONE step + downlink ==
-    the flat streaming fold + the SAME step + downlink, byte-exact —
-    the server_opt_agg_bitexact bench gate's in-process mirror."""
+    the flat streaming fold + the SAME step + downlink, byte-exact."""
     from rayfed_tpu.fl.hierarchy import RegionSumTree, partial_sum_dtype
     from rayfed_tpu.fl.compression import PackSpec
 

@@ -29,9 +29,9 @@ raw N-party logs cannot: **which party/phase bounded the round wall**.
   peer's contributions lost to the integer shift decay.
 
 The driver's own measured wall (``driver.round`` duration) reconciles
-with the report's window within tolerance — ``bench.py --smoke``'s
-``trace_critical_path_agrees`` gates exactly that, via
-:func:`round_report`.
+with the report's window within tolerance (:func:`round_report`'s
+``wall_agrees``; ``tests/test_quorum.py::test_quorum_coordinator_crash_failover``
+holds it on a merged cross-party trace).
 
 Usage::
 
@@ -62,9 +62,9 @@ def _hier_level(phase: str) -> Optional[str]:
 
     The hierarchy driver stamps the level into the span name itself
     (``hier.up.l2`` = the fold INTO level-2 interior nodes,
-    ``hier.down.l1`` = the fan-down FROM level-1 coordinators), so an
-    N=256 ratio-gate failure localizes to a tree level straight from
-    the bench's ``trace_phases`` block — no per-party log digging.
+    ``hier.down.l1`` = the fan-down FROM level-1 coordinators), so a
+    slow deep-tree round localizes to a tree level straight from the
+    report — no per-party log digging.
     Leaf phases (``region_rs``/``region_gather``) map to ``leaf``; the
     in-region broadcast phases (``down.relay``/``down.fan``/
     ``broadcast``) map to ``leaf.down``; everything else (``commit``)
