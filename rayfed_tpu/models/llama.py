@@ -29,9 +29,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from rayfed_tpu.models.moe import SELECTED_NAME
 from rayfed_tpu.ops.attention import NEG_INF, dot_product_attention
+from rayfed_tpu.ops.flash_attention import RESIDUAL_NAMES
 
 Params = Dict[str, Any]
+
+# What a rematerialized layer body keeps besides its input, here and in
+# ``decoder.py``: the experts a routed layer selected (selected again
+# from recomputed scores they need not be the same) and the flash
+# kernel's output and row statistics, which only the kernel can make
+# again.  Everything else of the layer's forward is recomputed.
+REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(
+    SELECTED_NAME, *RESIDUAL_NAMES
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,12 +65,21 @@ class LlamaConfig:
     # first moment follows this dtype; the second moment is always
     # float32 (see init_adam for why).
     param_dtype: Any = jnp.float32
+    # ``jax.checkpoint`` of the scanned layer body.  A layer keeps its
+    # input and, where ``attn_fn`` is the flash kernel, the kernel's
+    # output and row statistics (``REMAT_SAVED``), so the backward pass
+    # runs the layer's projections, norms, rotary embedding and FFN
+    # again but not the attention kernel: ``B*T*H*Dh`` elements of
+    # ``dtype`` + ``B*H*T*4`` bytes a layer, 68.2 MB a sequence of 8,192
+    # at 32 x 128 heads in bf16 (2.2 GB at 32 layers), about a
+    # fourteenth of what ``remat=False`` would keep.
     remat: bool = False
     # Rematerialization policy for the scanned layer body: None =
-    # recompute everything (lowest memory); "dots" = keep matmul outputs
-    # with no batch dims resident (jax.checkpoint_policies.
-    # dots_with_no_batch_dims_saveable) — ~5% higher MFU when the
-    # activations fit (v5e 1B bench: 0.522 -> 0.566 at b=2 seq=2048).
+    # recompute everything but the above (lowest memory); "dots" = also
+    # keep matmul outputs with no batch dims resident
+    # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) — ~5%
+    # higher MFU when the activations fit (v5e 1B bench: 0.522 -> 0.566
+    # at b=2 seq=2048).
     remat_policy: Optional[str] = None
     # int8 KV cache (per-position-per-head symmetric scales over the
     # head dim): halves the cache's HBM footprint AND the per-token
@@ -412,11 +432,14 @@ def apply_llama(
         # dispatch stays exhaustive so a future policy added to the
         # whitelist cannot silently fall through to the wrong one.
         if config.remat_policy is None:
-            layer_body = jax.checkpoint(layer_body)
+            layer_body = jax.checkpoint(layer_body, policy=REMAT_SAVED)
         elif config.remat_policy == "dots":
             layer_body = jax.checkpoint(
                 layer_body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                policy=jax.checkpoint_policies.save_from_both_policies(
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                    REMAT_SAVED,
+                ),
             )
         else:  # pragma: no cover — unreachable past __post_init__
             raise AssertionError(config.remat_policy)

@@ -35,6 +35,9 @@ from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
 
+# The checkpoint name of `route_tokens`'s selection.
+SELECTED_NAME = "moe.selected"
+
 
 @dataclasses.dataclass(frozen=True)
 class MoeConfig:
@@ -278,8 +281,9 @@ def route_tokens(params: Params, x: jax.Array, config: ExpertShareConfig):
     steers load and takes no gradient; it never enters the weights); the
     weights are the selected scores normalised over ALL ``k`` selected
     experts, held here or not, times ``route_scale``.  The selection
-    carries the checkpoint name ``"moe.selected"``: a rematerialised
-    layer must save it (``decoder.apply_decoder`` does), or its backward
+    carries the checkpoint name ``"moe.selected"`` (``SELECTED_NAME``):
+    a rematerialised layer must save it (``llama.REMAT_SAVED``, which
+    ``decoder.apply_decoder`` applies, does), or its backward
     pass selects again, from scores that another fusion of the same
     arithmetic rounds differently, and differentiates another function
     than the forward pass computed.
@@ -291,7 +295,7 @@ def route_tokens(params: Params, x: jax.Array, config: ExpertShareConfig):
     scores = jax.nn.sigmoid(logits)
     biased = scores + params["router_bias"].astype(jnp.float32)
     _, selected = jax.lax.top_k(jax.lax.stop_gradient(biased), config.top_k)
-    selected = checkpoint_name(selected, "moe.selected")
+    selected = checkpoint_name(selected, SELECTED_NAME)
     # The selected scores through a one-hot product, not a gather: on
     # the TPU a gather of 65,536 scalars (and its scatter-add backward)
     # costs a millisecond, the fused product nothing.

@@ -35,6 +35,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -58,6 +59,14 @@ def _interpret_default() -> bool:
 # 4.18 -> 2.66 / 3.00 / 3.57; no window 5.27 / 6.13 / 7.14 -> 5.03 / 5.85 /
 # 6.78; window 4,096 on 8 K/V heads 4.33 / 4.94 / 5.95 -> 3.98 / 4.54 / 5.42.
 SPLIT = 2
+
+# The checkpoint names of the two residuals only the forward kernel can
+# make, `out` [B, T, H, D] and the row statistics `lse` [BH, T] float32
+# (`_flash_fwd_bthd`).  A `jax.checkpoint` whose policy saves them
+# (`models.llama.REMAT_SAVED`) does not run the forward kernel again in
+# its backward pass; it pays `residual_bytes` a call (`B*T*H*D` elements
+# of the output's dtype + `B*H*T*4`: 68.2 MB at 1 x 8,192 x 32 x 128 bf16).
+RESIDUAL_NAMES = ("flash.out", "flash.lse")
 
 _UNMASKED = (False, False)
 
@@ -853,7 +862,7 @@ def _flash_bthd(
     q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset, interpret,
     window,
 ):
-    out, _ = _flash_fwd_bthd(
+    out, _ = _flash_out_lse(
         q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset,
         interpret, window,
     )
@@ -870,7 +879,7 @@ def _bht_to_bthd(x, b, h):  # [B*H, T, D] -> [B,T,H,D]
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd_bthd(
+def _flash_out_lse(
     q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset, interpret,
     window,
 ):
@@ -888,7 +897,23 @@ def _flash_fwd_bthd(
         interpret=interpret,
         window=window,
     )
-    out = _bht_to_bthd(o, b, h)
+    return _bht_to_bthd(o, b, h), lse
+
+
+def _flash_fwd_bthd(
+    q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset, interpret,
+    window,
+):
+    out, lse = _flash_out_lse(
+        q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset,
+        interpret, window,
+    )
+    # The names are on the forward RULE's residuals (outside a
+    # checkpoint they are the identity): `out` after the transpose back
+    # to [B, T, H, D] and the [BH, T] float32 `lse`, never its
+    # lane-replicated [BH, T, 128] form.
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
@@ -1008,6 +1033,9 @@ def flash_attention(
                 kv_heads=k.shape[2], t_q=q.shape[1], t_k=k.shape[1],
                 block_q=block_q, block_k=block_k, causal=causal,
                 window=window, q_offset=q_offset, kv_offset=kv_offset,
+                # what a checkpoint that saves RESIDUAL_NAMES keeps a call
+                residual_bytes=q.size * q.dtype.itemsize
+                + q.shape[0] * q.shape[2] * q.shape[1] * 4,
             ),
         )
     return _flash_bthd(

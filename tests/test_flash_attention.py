@@ -215,6 +215,7 @@ def test_flash_window_validation():
 
 # --- grouped K/V, the band walk, sub-tiled edges, the schedule, the size budget ---
 
+import functools
 import importlib
 
 from rayfed_tpu import telemetry
@@ -566,3 +567,117 @@ def test_a_process_traces_each_kernel_once(monkeypatch):
     program(10)(q, k, v)
     assert len(traced) == 6
     jax.clear_caches()  # the counting kernels leave with the test
+
+
+# --- a checkpointed layer keeps the kernel's output and row statistics -----
+
+
+def _forward_kernels(jaxpr, inside=False):
+    """How many forward ``pallas_call``s a jaxpr and all it holds run:
+    those inside the kernel's jitted wrapper, ``_flash_forward`` (a scan
+    may leave an empty equation of that name where it hoisted nothing)."""
+    from tool.flash_sweep import _sub_jaxprs
+
+    return sum(
+        (inside and eqn.primitive.name == "pallas_call")
+        + sum(
+            _forward_kernels(
+                sub, inside or eqn.params.get("name") == "_flash_forward"
+            )
+            for sub in _sub_jaxprs(eqn)
+        )
+        for eqn in jaxpr.eqns
+    )
+
+
+def _llama_case(**kw):
+    from rayfed_tpu.models import llama
+
+    cfg = llama.llama_tiny(remat=True, **kw)
+    params = llama.init_llama(jax.random.PRNGKey(0), cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, cfg.vocab_size)
+
+    def loss(p):
+        logits = llama.apply_llama(p, ids, cfg, attn_fn=flash_attention)
+        return llama.lm_loss(logits[:, :-1], ids[:, 1:])
+
+    return loss, params, 1  # one scanned body, one kernel
+
+
+def _decoder_case(*kinds):
+    from rayfed_tpu.models import decoder, llama
+
+    cfg = decoder.DecoderConfig(
+        layers=tuple(decoder.LayerSpec(kind, "dense") for kind in kinds),
+        vocab_size=64, hidden_size=32, num_heads=4, num_kv_heads=2,
+        head_dim=8, intermediate_size=48, sliding_window=8,
+        dtype=jnp.float32, remat=True,
+    )
+    params = decoder.init_decoder(jax.random.PRNGKey(0), cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 24), 0, cfg.vocab_size)
+
+    def loss(p):
+        logits, _ = decoder.apply_decoder(p, ids, cfg, attn_fn=flash_attention)
+        return llama.lm_loss(logits[:, :-1], ids[:, 1:])
+
+    # One group, one scanned body: a kernel a kind (both under the cond).
+    return loss, params, len(set(kinds))
+
+
+REMAT_CASES = {
+    "llama": _llama_case,
+    "llama_dots": functools.partial(_llama_case, remat_policy="dots"),
+    "decoder_one_kind": functools.partial(_decoder_case, "window", "window"),
+    "decoder_kinds_under_cond": functools.partial(_decoder_case, "window", "full"),
+}
+
+
+@pytest.mark.parametrize("case", list(REMAT_CASES))
+def test_checkpointed_layer_does_not_run_the_forward_kernel_again(
+    case, monkeypatch
+):
+    """``remat`` saves the two residuals only the kernel can make
+    (``RESIDUAL_NAMES``, named in the ``custom_vjp``'s forward rule), so
+    the gradient's program holds the forward kernel as often as the
+    forward pass alone, not twice; with nothing saved it holds it twice,
+    and the gradients are the same to the last bit (the same kernel on
+    the same values, kept in place of made again).  Interpret mode."""
+    from rayfed_tpu.models import decoder, llama
+
+    loss, params, kernels = REMAT_CASES[case]()
+    assert _forward_kernels(jax.make_jaxpr(loss)(params).jaxpr) == kernels
+    grad = jax.grad(loss)
+    assert _forward_kernels(jax.make_jaxpr(grad)(params).jaxpr) == kernels
+    with jax.disable_jit():  # op by op: no fusion to differ by an ulp
+        got = grad(params)
+
+    nothing = jax.checkpoint_policies.nothing_saveable
+    monkeypatch.setattr(llama, "REMAT_SAVED", nothing)
+    monkeypatch.setattr(decoder, "REMAT_SAVED", nothing)
+    grad = jax.grad(REMAT_CASES[case]()[0])  # jax keeps what it traced
+    assert _forward_kernels(jax.make_jaxpr(grad)(params).jaxpr) == 2 * kernels
+    with jax.disable_jit():
+        want = grad(params)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
+    ):
+        assert np.any(np.asarray(a)), path
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
+def test_attn_schedule_record_carries_the_residual_bytes():
+    """``residual_bytes`` is what a checkpoint that saves
+    ``RESIDUAL_NAMES`` keeps for the call: the output in its dtype and a
+    float32 statistic a (batch, head, query)."""
+    b, t, h, kv, d = 2, 64, 4, 2, 8
+    q, k, v = (
+        x.astype(jnp.bfloat16)
+        for x in _grouped_qkv(jax.random.PRNGKey(1), t, t, h, kv, d=d, b=b)
+    )
+    rec = telemetry.install(party="alice")
+    try:
+        fa.flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+        (record,) = [r for r in rec.records() if r.phase == "attn.schedule"]
+    finally:
+        telemetry.uninstall()
+    assert record.detail["residual_bytes"] == b * t * h * d * 2 + b * h * t * 4

@@ -204,3 +204,49 @@ def test_compiles_for_v5e(case):
         # Each of the four chips holds its quarter of the 8 MiB leaf.
         out_bytes = compiled.memory_analysis().output_size_in_bytes
         assert out_bytes == 2048 * 1024 * 4 // 4
+
+
+# cell -> `flash.fwd` kernels in its compiled step: one a scanned body
+# (Trinity-Mini: layer 0's, and both kinds under the expert group's
+# ``cond``), all in the forward pass.
+STEP_FLASH_FORWARDS = {
+    "mistral-7b-v0.1-d6.lora-2p": 1,
+    "trinity-mini-ep8.lora-all-linear-2p": 3,
+}
+
+
+@pytest.mark.parametrize("cell", list(STEP_FLASH_FORWARDS))
+def test_step_runs_the_flash_forward_once_and_two_parties_fit(cell, monkeypatch):
+    """The benchmark's LoRA step at the cell's shapes: the checkpointed
+    layers save the flash kernel's output and row statistics
+    (``llama.REMAT_SAVED``), so the backward pass' recompute holds no
+    forward kernel, and two parties' steps still fit one chip."""
+    import importlib
+
+    from rayfed_tpu.models import moe
+    from tool.flash_sweep import _step_lowering
+
+    flash = importlib.import_module("rayfed_tpu.ops.flash_attention")
+    # Both ask jax.default_backend(), the CPU here: steer them to what
+    # the chip runs.
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)
+    monkeypatch.setattr(moe, "_grouped_impl", lambda: "megablox")
+    _topology()
+    compiled = _step_lowering(cell, _on_chip)().compile()
+    forwards = [
+        line for line in compiled.as_text().splitlines()
+        if "custom-call(" in line and "flash.fwd" in line
+    ]
+    assert len(forwards) == STEP_FLASH_FORWARDS[cell]
+    for line in forwards:  # none in the backward pass or its recompute
+        assert "transpose(" not in line and "rematted_computation" not in line
+    memory = compiled.memory_analysis()
+    party = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes
+    )
+    # XLA's analysis, an upper bound (its own buffer report reads 0.7 GB
+    # a party less for `lora-2p`), not the chip's peak: `device_peak_GB`
+    # read 12.59 and 6.62 GB with both parties' steps in flight (PR 32).
+    print(f"{cell}: {party / 1e9:.3f} GB a party, {2 * party / 1e9:.3f} two")
+    assert 2 * party / 1e9 < 16.9

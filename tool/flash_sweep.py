@@ -323,7 +323,8 @@ def _timed(lower, events):
 
 def _step_lowering(name, on_chip):
     """A function that builds ``name``'s family anew (so nothing traced
-    is found again) and lowers its step from shapes."""
+    is found again) and lowers its step from shapes (returned lowered:
+    ``tests/test_tpu_compile.py`` compiles it)."""
     from benchmark import harness
 
     cell = harness.load_cell(name)
@@ -345,7 +346,7 @@ def _step_lowering(name, on_chip):
             init_opt, step = fam._llama.init_adam, fam._step
         opt = jax.eval_shape(init_opt, tree)
         ids = jax.ShapeDtypeStruct((fam.batch, fam.seq), jnp.int32)
-        step.lower(*on_chip((tree, opt, base, ids)))
+        return step.lower(*on_chip((tree, opt, base, ids)))
 
     return lower
 
