@@ -3,12 +3,17 @@
 :mod:`rayfed_tpu.models.llama` is one stacked scan of identical layers
 (one attention kind, one FFN, model-wide).  Here every layer has a
 :class:`LayerSpec`: its attention kind (a sliding window with rotary
-positions, or full causal attention with no position embedding) and its
-FFN kind (dense SwiGLU, or routed + shared experts through
-:func:`rayfed_tpu.models.moe.apply_expert_share`).  The block also has
-what the sparse-expert decoders published since 2025 share: an RMS norm
-of q and k over the head width, a sigmoid gate on the attention output,
-norms on both sides of each sub-block, a scaled embedding.
+positions, full causal attention with no position embedding, or LATENT
+attention: queries and keys/values through low-rank latents with a norm
+of their own, a score of two parts, ``q_nope · k_nope`` a head plus
+``q_pe · k_pe`` against ONE rotary key head all query heads share, and
+values of their own width) and its FFN kind (dense SwiGLU, or routed +
+shared experts through :func:`rayfed_tpu.models.moe.apply_expert_share`).
+What the sparse-expert decoders published since 2025 add to the block
+is the configuration's to switch (:class:`DecoderConfig`, on by default
+as the first configuration has them all): an RMS norm of q and k over
+the head width, a sigmoid gate on the attention output, norms after
+each sub-block, a scaled embedding; rotary frequencies may be YaRN's.
 
 Consecutive layers with one FFN kind are a GROUP: their parameters are
 stacked on a leading dim and the forward pass is one ``lax.scan`` a
@@ -28,9 +33,11 @@ static); the branches' residuals are of one shape and share the
 :func:`rayfed_tpu.models.lora.init_lora` mirrors it with the group's
 index as a string.  :func:`unstack` gives either tree layer by layer.
 
-The first configuration that runs through it is the AFMoE family's
+Two configurations run through it: the AFMoE family's
 (``benchmark/families/afmoe_lm.py``, reference in
-``benchmark/reference/afmoe.py``).  Helpers are shared with
+``benchmark/reference/afmoe.py``) and the ``kimi_k2`` / DeepSeek-V3
+block (``benchmark/families/kimi_k2_lm.py``, ``reference/kimi_k2.py``),
+all of whose layers are latent.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
 ``_linear``, ``lm_loss``, ``_adam_update``); ``llama.py``'s own programs
 do not pass through this module.
@@ -52,6 +59,7 @@ from rayfed_tpu import telemetry
 from rayfed_tpu.models import moe
 from rayfed_tpu.models.llama import (
     REMAT_SAVED,
+    YarnScaling,
     _adam_update,
     _linear,
     _rms_norm,
@@ -63,20 +71,39 @@ from rayfed_tpu.ops.attention import dot_product_attention
 
 Params = Dict[str, Any]
 
-# Every linear matrix of the block but the router (LoraConfig.targets).
-ALL_LINEAR = (r"/w[qkvoz]$", r"/w_(gate|up|down)$")
+# Every linear matrix of the block but the router (LoraConfig.targets):
+# wq wk wv wo and the output gate wz, or a latent layer's five (wq_a wq_b
+# wkv_a wkv_b wo); the dense FFN's, the shared expert's and each held
+# expert's three.
+ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$", r"/w_(gate|up|down)$")
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    attention: str = "window"  # "window" (with RoPE) | "full" (no positions)
+    # "window" (with RoPE) | "full" (no positions) | "latent" (RoPE on a
+    # part of the head, DecoderConfig.latent)
+    attention: str = "window"
     ffn: str = "dense"  # "dense" | "moe"
 
     def __post_init__(self):
-        if self.attention not in ("window", "full"):
+        if self.attention not in ("window", "full", "latent"):
             raise ValueError(f"unknown attention kind {self.attention!r}")
         if self.ffn not in ("dense", "moe"):
             raise ValueError(f"unknown ffn kind {self.ffn!r}")
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentConfig:
+    """The widths of a latent-attention layer: the ranks of the query
+    and key/value latents, and per head the part of the query-key width
+    that carries no position (``nope_dim``), the rotary part
+    (``rope_dim``) and the value width."""
+
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,6 +118,12 @@ class DecoderConfig:
     sliding_window: int = 2048
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
+    rope_scaling: Optional[YarnScaling] = None
+    latent: Optional[LatentConfig] = None  # a "latent" layer's widths
+    # The block's optional parts.
+    qk_norm: bool = True  # RMS norm of q and k over the head width
+    output_gate: bool = True  # attention output * sigmoid(x wz)
+    post_norms: bool = True  # a norm on each sub-block's output
     embed_scale: float = 1.0  # the residual stream starts at embed * this
     experts: Optional[moe.ExpertShareConfig] = None
     dtype: Any = jnp.bfloat16
@@ -102,6 +135,12 @@ class DecoderConfig:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if any(s.ffn == "moe" for s in self.layers) and self.experts is None:
             raise ValueError("a layer with ffn='moe' needs config.experts")
+        latent = [s.attention == "latent" for s in self.layers]
+        if any(latent) and self.latent is None:
+            raise ValueError("a layer with attention='latent' needs config.latent")
+        if any(latent) != all(latent):
+            # their parameters differ, so no group could stack them
+            raise ValueError("latent layers do not mix with other kinds")
 
     def groups(self) -> Tuple[Tuple[int, int], ...]:
         """``(first layer, one past the last)`` of every run of
@@ -130,19 +169,36 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     def layer(key, spec: LayerSpec) -> Params:
         ks = jax.random.split(key, 9)
         ones = lambda n: jnp.ones((n,), pdt)
-        lp = {
-            "attn_norm": ones(d),
-            "wq": dense(ks[0], d, h * dh, fan_in=d),
-            "wk": dense(ks[1], d, kv * dh, fan_in=d),
-            "wv": dense(ks[2], d, kv * dh, fan_in=d),
-            "wz": dense(ks[3], d, h * dh, fan_in=d),
-            "q_norm": ones(dh),
-            "k_norm": ones(dh),
-            "wo": dense(ks[4], h * dh, d, fan_in=h * dh),
-            "post_attn_norm": ones(d),
-            "mlp_norm": ones(d),
-            "post_mlp_norm": ones(d),
-        }
+        lp = {"attn_norm": ones(d), "mlp_norm": ones(d)}
+        if spec.attention == "latent":
+            m = c.latent
+            lp.update(
+                wq_a=dense(ks[0], d, m.q_rank, fan_in=d),
+                q_a_norm=ones(m.q_rank),
+                # a head: [nope | rope]
+                wq_b=dense(ks[1], m.q_rank, h * (m.nope_dim + m.rope_dim),
+                           fan_in=m.q_rank),
+                # [the key/value latent | the one rotary key head]
+                wkv_a=dense(ks[2], d, m.kv_rank + m.rope_dim, fan_in=d),
+                kv_a_norm=ones(m.kv_rank),
+                # a head: [k_nope | v]
+                wkv_b=dense(ks[3], m.kv_rank, h * (m.nope_dim + m.v_dim),
+                            fan_in=m.kv_rank),
+                wo=dense(ks[4], h * m.v_dim, d, fan_in=h * m.v_dim),
+            )
+        else:
+            lp.update(
+                wq=dense(ks[0], d, h * dh, fan_in=d),
+                wk=dense(ks[1], d, kv * dh, fan_in=d),
+                wv=dense(ks[2], d, kv * dh, fan_in=d),
+                wo=dense(ks[4], h * dh, d, fan_in=h * dh),
+            )
+            if c.qk_norm:
+                lp.update(q_norm=ones(dh), k_norm=ones(dh))
+        if c.output_gate:
+            lp["wz"] = dense(ks[3], d, lp["wo"].shape[0], fan_in=d)
+        if c.post_norms:
+            lp.update(post_attn_norm=ones(d), post_mlp_norm=ones(d))
         if spec.ffn == "dense":
             f = c.intermediate_size
             lp["w_gate"] = dense(ks[5], d, f, fan_in=d)
@@ -202,8 +258,47 @@ def _attend(q, k, v, rope, *, kind, config, attn_fn):
     with jax.named_scope(f"attn.{kind}"):
         if kind == "full":
             return attn_fn(q, k, v, causal=True)
+        if kind == "latent":
+            # Rotary positions on the rotary part alone; the score's two
+            # parts go to the kernel as they are (the shared rotary key
+            # is never copied to the heads, nor V padded to the
+            # query-key width: ops.flash_attention).
+            (q_nope, q_pe), (k_nope, k_pe) = q, k
+            m, scaling = config.latent, config.rope_scaling
+            scale = (m.nope_dim + m.rope_dim) ** -0.5 * (
+                scaling.softmax_scale() if scaling else 1.0
+            )
+            return attn_fn(
+                (q_nope, apply_rope(q_pe, *rope)),
+                (k_nope, apply_rope(k_pe, *rope)),
+                v, causal=True, sm_scale=scale,
+            )
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
         return attn_fn(q, k, v, causal=True, window=config.sliding_window)
+
+
+def _latent_qkv(y, lp, config: DecoderConfig, lget):
+    """A latent layer's heads from the normed stream ``y`` [B, T, D]:
+    ``(q_nope, q_pe)`` [B, T, H, nope | rope], ``(k_nope, k_pe)`` with
+    ``k_pe`` [B, T, 1, rope] the one rotary key head, ``v`` [B, T, H,
+    v_dim]."""
+    c, m = config, config.latent
+    b, t, _ = y.shape
+    h, dtype = c.num_heads, c.dtype
+    c_q = _linear(y, lp["wq_a"], lget("wq_a"), dtype)
+    c_q = _rms_norm(c_q, lp["q_a_norm"], c.rms_eps)
+    q = _linear(c_q, lp["wq_b"], lget("wq_b"), dtype)
+    q = q.reshape(b, t, h, m.nope_dim + m.rope_dim)
+    kv_a = _linear(y, lp["wkv_a"], lget("wkv_a"), dtype)
+    c_kv = _rms_norm(kv_a[..., : m.kv_rank], lp["kv_a_norm"], c.rms_eps)
+    k_pe = kv_a[..., m.kv_rank:].reshape(b, t, 1, m.rope_dim)
+    kv = _linear(c_kv, lp["wkv_b"], lget("wkv_b"), dtype)
+    kv = kv.reshape(b, t, h, m.nope_dim + m.v_dim)
+    return (
+        (q[..., : m.nope_dim], q[..., m.nope_dim:]),
+        (kv[..., : m.nope_dim], k_pe),
+        kv[..., m.nope_dim:],
+    )
 
 
 def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
@@ -218,14 +313,22 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
     b, t, _ = x.shape
     h, kv, dh, dtype = c.num_heads, c.num_kv_heads, c.head_dim, c.dtype
     lget = (lora or {}).get
-    rope = rope_tables(jnp.arange(t), dh, c.rope_theta)
+    latent = isinstance(attention, str) and attention == "latent"
+    rope = rope_tables(
+        jnp.arange(t), c.latent.rope_dim if latent else dh, c.rope_theta,
+        c.rope_scaling,
+    )
     with jax.named_scope("attn.proj"):
         y = _rms_norm(x, lp["attn_norm"], c.rms_eps)
-        q = _linear(y, lp["wq"], lget("wq"), dtype).reshape(b, t, h, dh)
-        k = _linear(y, lp["wk"], lget("wk"), dtype).reshape(b, t, kv, dh)
-        v = _linear(y, lp["wv"], lget("wv"), dtype).reshape(b, t, kv, dh)
-        q = _rms_norm(q, lp["q_norm"], c.rms_eps)
-        k = _rms_norm(k, lp["k_norm"], c.rms_eps)
+        if latent:
+            q, k, v = _latent_qkv(y, lp, c, lget)
+        else:
+            q = _linear(y, lp["wq"], lget("wq"), dtype).reshape(b, t, h, dh)
+            k = _linear(y, lp["wk"], lget("wk"), dtype).reshape(b, t, kv, dh)
+            v = _linear(y, lp["wv"], lget("wv"), dtype).reshape(b, t, kv, dh)
+            if c.qk_norm:
+                q = _rms_norm(q, lp["q_norm"], c.rms_eps)
+                k = _rms_norm(k, lp["k_norm"], c.rms_eps)
     attend = functools.partial(_attend, config=c, attn_fn=attn_fn)
     if isinstance(attention, str):
         o = attend(q, k, v, rope, kind=attention)
@@ -235,11 +338,14 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
             functools.partial(attend, kind="full"), q, k, v, rope,
         )
     with jax.named_scope("attn.proj"):
-        o = o.reshape(b, t, h * dh)
-        z = _linear(y, lp["wz"], lget("wz"), dtype)
-        o = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(dtype)
+        o = o.reshape(b, t, -1)
+        if c.output_gate:
+            z = _linear(y, lp["wz"], lget("wz"), dtype)
+            o = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(dtype)
         o = _linear(o, lp["wo"], lget("wo"), dtype)
-        x = x + _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
+        if c.post_norms:
+            o = _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
+        x = x + o
 
     y = _rms_norm(x, lp["mlp_norm"], c.rms_eps)
     aux = None
@@ -251,7 +357,9 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
             lp["moe"], y.reshape(b * t, -1), c.experts, lora=lget("moe"),
         )
         f = f.reshape(b, t, -1)
-    return x + _rms_norm(f, lp["post_mlp_norm"], c.rms_eps), aux
+    if c.post_norms:
+        f = _rms_norm(f, lp["post_mlp_norm"], c.rms_eps)
+    return x + f, aux
 
 
 def _split_scalars(tree):

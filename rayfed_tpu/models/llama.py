@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -267,11 +268,82 @@ def _rms_norm(x, scale, eps):
     return (norm * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin tables [T, head_dim/2] for the given absolute positions."""
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A config's ``rope_scaling`` of type ``yarn`` (transformers
+    ``modeling_rope_utils.py::_compute_yarn_parameters``): frequencies
+    that turn fewer than ``beta_slow`` times over the
+    ``original_max_position`` trained positions are divided by
+    ``factor`` (interpolated), those that turn more than ``beta_fast``
+    times are kept, and the ones between are blended linearly in the
+    frequency's index."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def magnitude(self, mscale: float) -> float:
+        """``m(s) = 0.1 s ln(factor) + 1`` (1 where nothing is scaled)."""
+        if self.factor <= 1:
+            return 1.0
+        return 0.1 * mscale * math.log(self.factor) + 1.0
+
+    def table_scale(self) -> float:
+        """What the cos and sin tables are multiplied by."""
+        if self.mscale and self.mscale_all_dim:
+            return self.magnitude(self.mscale) / self.magnitude(
+                self.mscale_all_dim
+            )
+        return self.magnitude(1.0)
+
+    def softmax_scale(self) -> float:
+        """What an attention whose scores the scaled positions enter
+        multiplies its ``width ** -0.5`` by (``DeepseekV3Attention``):
+        ``m(mscale_all_dim) ** 2``."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.magnitude(self.mscale_all_dim) ** 2
+
+    def blend(self, head_dim: int, theta: float):
+        """``(low, high)``: the frequency indices between which the
+        blend runs, clipped to ``[0, head_dim - 1]``."""
+        def turns_at(n):  # the index whose frequency turns n times
+            return head_dim * math.log(
+                self.original_max_position / (n * 2 * math.pi)
+            ) / (2 * math.log(theta))
+
+        low = max(math.floor(turns_at(self.beta_fast)), 0)
+        high = min(math.ceil(turns_at(self.beta_slow)), head_dim - 1)
+        return low, high
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling: Optional[YarnScaling] = None):
+    """The ``head_dim / 2`` rotary frequencies, float32."""
     freqs = 1.0 / theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if scaling is None:
+        return freqs
+    low, high = scaling.blend(head_dim, theta)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+        / (high - low if high != low else 0.001), 0.0, 1.0,
+    )
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                scaling: Optional[YarnScaling] = None):
+    """cos/sin tables [T, head_dim/2] for the given absolute positions
+    (``scaling``: YaRN's blended frequencies and table scale)."""
+    freqs = rope_frequencies(head_dim, theta, scaling)
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
+    by = 1.0 if scaling is None else scaling.table_scale()
+    if by == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * by, jnp.sin(angles) * by
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

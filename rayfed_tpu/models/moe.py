@@ -355,10 +355,14 @@ def _grouped_impl() -> str:
     return "ragged" if jax.default_backend() == "cpu" else "megablox"
 
 
-# Row, contraction and column tile of the megablox product: an expert's
-# whole [K, N/2] half-matrix stays in fast memory while its row tiles
-# pass, so the weights are read once a group.  Not swept on the chip;
-# the products read 45% of the bf16 peak with it (PERF.md section 5).
+# Row, contraction and column tile of the megablox product, at most:
+# where K is no more than 2,048 an expert's whole [K, 512] column block
+# stays in fast memory while its row tiles pass, so the weights are read
+# once a group; a larger K (7,168) is walked in its largest lane-aligned
+# divisor under that (`_contraction_tile`: 1,792, four steps), since a
+# tile that does not divide K is masked on every step.  Not swept on the
+# chip; the products read 45% of the bf16 peak with it at 512 rows an
+# expert of K = 2,048 (PERF.md section 5).
 GMM_TILING = (512, 2048, 512)
 # The sorted rows pass through the experts in chunks sized for the
 # expected held assignments of a call (tokens * top_k * held /
@@ -383,10 +387,10 @@ def grouped_matmul(lhs, rhs, group_sizes):
             return jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1])
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
-        tiling = tuple(
-            min(want, have) for want, have in zip(
-                GMM_TILING, (lhs.shape[0], lhs.shape[1], rhs.shape[2])
-            )
+        tm, tk, tn = GMM_TILING
+        tiling = (
+            min(tm, lhs.shape[0]), _contraction_tile(lhs.shape[1], tk),
+            min(tn, rhs.shape[2]),
         )
         if lhs.shape[0] % tiling[0]:
             raise ValueError(
@@ -398,6 +402,18 @@ def grouped_matmul(lhs, rhs, group_sizes):
             jnp.zeros((), jnp.int32), None, False,
             impl == "megablox-interpret",
         )
+
+
+def _contraction_tile(k: int, most: int) -> int:
+    """The largest multiple of 128 that divides ``k`` and is no larger
+    than ``most``; ``min(k, most)`` where there is none (a ``k`` under
+    ``most`` is its own tile)."""
+    if k <= most:
+        return k
+    for tile in range(most - most % 128, 0, -128):
+        if k % tile == 0:
+            return tile
+    return most
 
 
 def _expert_linear(xs, w, group_sizes, row_expert, lora_entry):

@@ -48,9 +48,45 @@ def repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
     return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
 
+def check_score_parts(q, k, v: jax.Array) -> int:
+    """A score given in PARTS: ``q`` a tuple of ``q_p`` ``[B, T, H, D_p]``,
+    ``k`` of ``k_p`` ``[B, T, KV_p, D_p]``, the score the sum over parts
+    of ``q_p · k_p``.  Latent attention's key is such a pair: a part a
+    head of its own, and a rotary part that ONE head holds for all of
+    them.  Raises unless the widths pair up and every K/V head count
+    divides the others' and the query heads; returns the most heads a
+    key part has."""
+    if len(q) != len(k) or not q:
+        raise ValueError(f"{len(q)} query parts for {len(k)} key parts")
+    h = q[0].shape[2]
+    for q_p, k_p in zip(q, k):
+        if q_p.shape[-1] != k_p.shape[-1] or q_p.shape[2] != h:
+            raise ValueError(
+                f"score part shapes {q_p.shape} and {k_p.shape} do not pair"
+            )
+    heads = max(k_p.shape[2] for k_p in k)
+    for x in (*k, v):
+        if h % x.shape[2] or heads % x.shape[2]:
+            raise ValueError(
+                f"K/V heads ({[x.shape[2] for x in (*k, v)]}) must divide "
+                f"one another and the query heads ({h})"
+            )
+    return heads
+
+
+def score_parts(q, k, v: jax.Array):
+    """The parts of a score (:func:`check_score_parts`) as one ``(q,
+    k)``: laid side by side, every ``k_p`` repeated to the most heads a
+    part has.  The flash kernel reads the parts as they are."""
+    heads = check_score_parts(q, k, v)
+    return jnp.concatenate(q, axis=-1), jnp.concatenate(
+        [jnp.repeat(k_p, heads // k_p.shape[2], axis=2) for k_p in k], axis=-1
+    )
+
+
 def dot_product_attention(
-    q: jax.Array,
-    k: jax.Array,
+    q,  # an array, or a tuple of score parts (:func:`score_parts`)
+    k,
     v: jax.Array,
     *,
     causal: bool = False,
@@ -68,8 +104,12 @@ def dot_product_attention(
     ``causal``) restricts each query to its last ``window`` keys.
     ``k``/``v`` may hold fewer (grouped) heads than ``q``
     (:func:`kv_group`); the group is a dimension of the contractions,
-    never a repeated copy.
+    never a repeated copy.  ``v``'s width may differ from the query-key
+    width (the output has ``v``'s), and ``q``/``k`` may be tuples of
+    score parts (:func:`score_parts`), as for the flash kernel.
     """
+    if isinstance(q, (tuple, list)):
+        q, k = score_parts(q, k, v)
     if window is not None:
         if not causal:
             raise ValueError("window= requires causal=True")
@@ -104,7 +144,7 @@ def dot_product_attention(
         "bngqk,bknd->bqngd",
         p.reshape(b, kv, group, t_q, t_k), v.astype(jnp.float32),
     )
-    return o.reshape(b, t_q, h, head_dim).astype(orig_dtype)
+    return o.reshape(b, t_q, h, v.shape[-1]).astype(orig_dtype)
 
 
 def as_attn_fn(sharded, built_causal: bool, built_scale, builder: str):

@@ -40,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from rayfed_tpu import telemetry
-from rayfed_tpu.ops.attention import kv_group
+from rayfed_tpu.ops.attention import check_score_parts, kv_group
 
 NEG_INF = -1e30
 
@@ -404,16 +404,29 @@ def block_schedule(
     )
 
 
+def _scores(q_refs, k_refs, r, c, scale):
+    """The score tile of query rows ``r`` and keys ``c``: the sum over
+    the score's parts of ``q_p k_p^T``, each product over that part's own
+    width, added in VMEM (one part: the usual ``q k^T``), times ``scale``.
+    Returns the operands too."""
+    qs = [ref[0, r, :] for ref in q_refs]
+    ks = [ref[0, c, :] for ref in k_refs]
+    s = None
+    for q, k in zip(qs, ks):
+        part = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        s = part if s is None else s + part
+    return qs, ks, s * scale
+
+
 def _flash_fwd_kernel(
-    q_ref,  # (1, block_q, d)
-    k_ref,  # (1, block_k, d)
-    v_ref,  # (1, block_k, d)
-    o_ref,  # (1, block_q, d)
-    lse_ref,  # (1, block_q, 128) — lane-broadcast so the block is tileable
-    acc_ref,  # VMEM (block_q, d) f32
-    m_ref,  # VMEM (block_q, 128) f32
-    l_ref,  # VMEM (block_q, 128) f32
-    *,
+    # parts x q (1, block_q, d_p); parts x k (1, block_k, d_p);
+    # v (1, block_k, d_v); out o (1, block_q, d_v), lse (1, block_q, 128),
+    # lane-broadcast so the block is tileable; VMEM f32 acc (block_q, d_v),
+    # m and l (block_q, 128)
+    *refs,
+    parts: int,
     scale: float,
     causal: bool,
     block_q: int,
@@ -425,6 +438,8 @@ def _flash_fwd_kernel(
     walk: _Band,
     window=None,
 ):
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[2 * parts:]
     qi = pl.program_id(1)
     step = pl.program_id(2)
     ki = walk.block(qi, step)
@@ -443,12 +458,8 @@ def _flash_fwd_kernel(
         # Feed the MXU native-dtype (bf16) operands — casting to f32 first
         # would force f32 matmul passes at a fraction of bf16 throughput.
         # Accumulation is f32 via preferred_element_type.
-        q = q_ref[0, r, :]
-        k = k_ref[0, c, :]
+        _, _, s = _scores(q_refs, k_refs, r, c, scale)  # (rows, cols) f32
         v = v_ref[0, c, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (rows, cols) f32
         m_prev = m_ref[r, :]  # (rows, 128), lane-replicated: `_across`
         l_prev = l_ref[r, :]
         if edges is not None:
@@ -494,18 +505,26 @@ def _flash_fwd_kernel(
         ).astype(lse_ref.dtype)
 
 
-def _plan(q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+def _parts(x) -> tuple:
+    """``x`` as the tuple of a score's parts: an array is one part."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def _plan(qs, kvs, block_q, block_k, causal, window, q_offset, kv_offset,
           interpret):
     """What the three ``pallas_call``s share: the checked block sizes
-    and sub-tile sizes (the kernels' static arguments), the query heads a
-    K/V head serves, and the two bands their grids walk."""
-    bh, t_q, _ = q.shape
-    bkv, t_k, _ = k.shape
-    if bh % bkv:
-        raise ValueError(
-            f"K/V heads ({bkv} with the batch) must divide the query "
-            f"heads ({bh})"
-        )
+    and sub-tile sizes (the kernels' static arguments), for every array
+    of the K/V side (``kvs``: the k parts, then v) the query heads one of
+    its heads serves, and the two bands their grids walk."""
+    bh, t_q, _ = qs[0].shape
+    t_k = kvs[0].shape[1]
+    for x in kvs:
+        if bh % x.shape[0]:
+            raise ValueError(
+                f"K/V heads ({x.shape[0]} with the batch) must divide the "
+                f"query heads ({bh})"
+            )
+    groups = tuple(bh // x.shape[0] for x in kvs)
     block_q = min(block_q, t_q)
     block_k = min(block_k, t_k)
     if t_q % block_q or t_k % block_k:
@@ -532,7 +551,24 @@ def _plan(q, k, block_q, block_k, causal, window, q_offset, kv_offset,
     walk, walk_dkv = _bands(
         t_q, t_k, block_q, block_k, causal, window, q_offset, kv_offset
     )
-    return common, bh // bkv, walk, walk_dkv
+    return common, groups, walk, walk_dkv
+
+
+def _band_specs(block_q, block_k, walk: _Band):
+    """The block specs of the forward and dQ grids ``(query head, q
+    block, kv step)``: ``of_q(width)`` for an array of the query side,
+    ``of_kv(x, group)`` for one of the K/V side, each of whose heads
+    serves ``group`` query heads and whose block is the band's."""
+    def of_q(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, s: (b, i, 0))
+
+    def of_kv(x, group):
+        return pl.BlockSpec(
+            (1, block_k, x.shape[-1]),
+            lambda b, i, s: (jax.lax.div(b, group), walk.fetch(i, s), 0),
+        )
+
+    return of_q, of_kv
 
 
 # Each wrapper of a ``pallas_call`` is a ``jax.jit`` of its own, keyed by
@@ -565,9 +601,12 @@ def _flash_forward(
     out_dtype=None,
     window=None,
 ):
-    """Run the pallas kernel on q [BH, T, D], k/v [B·KV, T, D] inputs;
-    returns (o, lse).  Query head ``h`` reads K/V head ``h // (H // KV)``
-    (the index map's ``b // group``): grouped K/V is never repeated.
+    """Run the pallas kernel on q [BH, T, D], k [B·KV, T, D], v [B·KV,
+    T, Dv] inputs; returns (o [BH, T, Dv], lse).  Query head ``h`` reads
+    K/V head ``h // (H // KV)`` (the index map's ``b // group``): grouped
+    K/V is never repeated.  ``q`` and ``k`` may be tuples of score PARTS
+    (``q_p`` [BH, T, D_p], ``k_p`` [B·KV_p, T, D_p]): the score is the sum
+    of the parts' products, each part of k read by its own head count.
 
     ``out_dtype`` overrides the output dtype of ``o`` (default: q's) —
     ring callers take f32 so per-step partials are not rounded to bf16
@@ -579,53 +618,50 @@ def _flash_forward(
     output is lane-broadcast to (bh, t_q, 128) so its block satisfies
     the TPU (8, 128) tiling rule, then lane 0 is taken.
     """
-    bh, t_q, d = q.shape
-    common, group, walk, _ = _plan(
-        q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+    qs, ks = _parts(q), _parts(k)
+    bh, t_q, _ = qs[0].shape
+    d_v = v.shape[-1]
+    common, groups, walk, _ = _plan(
+        qs, ks + (v,), block_q, block_k, causal, window, q_offset, kv_offset,
         interpret,
     )
     block_q, block_k = common["block_q"], common["block_k"]
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, block_k, d), lambda b, i, s: (jax.lax.div(b, group), walk.fetch(i, s), 0)
-    )
-    lane_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, s: (b, i, 0))
+    of_q, of_kv = _band_specs(block_q, block_k, walk)
+
     with jax.named_scope("flash.fwd"):
         o, lse = pl.pallas_call(
             functools.partial(
-                _flash_fwd_kernel, scale=scale, walk=walk, **common
+                _flash_fwd_kernel, parts=len(qs), scale=scale, walk=walk,
+                **common,
             ),
             grid=(bh, t_q // block_q, walk.steps),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, lane_spec],
+            in_specs=[of_q(x.shape[-1]) for x in qs]
+            + [of_kv(x, g) for x, g in zip(ks + (v,), groups)],
+            out_specs=[of_q(d_v), of_q(128)],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
+                jax.ShapeDtypeStruct((bh, t_q, d_v), out_dtype or qs[0].dtype),
                 jax.ShapeDtypeStruct((bh, t_q, 128), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, d_v), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
             ],
             interpret=interpret,
-        )(q, k, v)
+        )(*qs, *ks, v)
     return o, lse[..., 0]
 
 
-def _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows,
+def _backward_tile(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, rows,
                    cols, edges, scale, window):
-    """What both backward kernels recompute of a tile: its operands,
-    ``p`` (zeroed where invisible by the mask's own predicate) and
-    ``ds = p ∘ (dO Vᵀ − D)``."""
+    """What both backward kernels recompute of a tile: its operands (a
+    list a score part for q and k), ``p`` (zeroed where invisible by the
+    mask's own predicate) and ``ds = p ∘ (dO Vᵀ − D)``."""
     r, c = pl.ds(*rows), pl.ds(*cols)
     # Native-dtype (bf16) MXU operands, f32 accumulation — see fwd.
-    q = q_ref[0, r, :]
-    k = k_ref[0, c, :]
+    q, k, s = _scores(q_refs, k_refs, r, c, scale)  # (rows, cols)
     v = v_ref[0, c, :]
     do = do_ref[0, r, :]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (rows, cols)
     p = jnp.exp(s - _across(lse_ref[0, r, :], cols[1]))
     if edges is not None:
         # Fully-masked rows have lse ~ NEG_INF and p = inf there — every
@@ -641,15 +677,11 @@ def _backward_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows,
 
 
 def _flash_bwd_dq_kernel(
-    q_ref,  # (1, block_q, d)
-    k_ref,  # (1, block_k, d)
-    v_ref,  # (1, block_k, d)
-    do_ref,  # (1, block_q, d)
-    lse_ref,  # (1, block_q, 128)
-    delta_ref,  # (1, block_q, 128)
-    dq_ref,  # out (1, block_q, d)
-    acc_ref,  # VMEM (block_q, d) f32
-    *,
+    # parts x q (1, block_q, d_p); parts x k (1, block_k, d_p);
+    # v (1, block_k, d_v); do (1, block_q, d_v); lse, delta (1, block_q, 128);
+    # out parts x dq (1, block_q, d_p); VMEM f32 parts x (block_q, d_p)
+    *refs,
+    parts: int,
     scale: float,
     causal: bool,
     block_q: int,
@@ -661,25 +693,31 @@ def _flash_bwd_dq_kernel(
     walk: _Band,
     window=None,
 ):
-    """dQ = (P ∘ (dO Vᵀ − D)) K · scale, accumulated over the kv blocks
-    of the q block's band."""
+    """dQ = (P ∘ (dO Vᵀ − D)) K · scale (a score part: its own K),
+    accumulated over the kv blocks of the q block's band."""
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
+    dq_refs, acc_refs = refs[2 * parts + 4:3 * parts + 4], refs[3 * parts + 4:]
     qi = pl.program_id(1)
     step = pl.program_id(2)
     ki = walk.block(qi, step)
 
     @pl.when(step == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for acc_ref in acc_refs:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute(rows, cols, edges):
-        _, k, _, _, ds = _backward_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
+        _, ks, _, _, ds = _backward_tile(
+            q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
             edges, scale, window,
         )
-        acc_ref[pl.ds(*rows), :] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ds = ds.astype(ks[0].dtype)
+        for acc_ref, k in zip(acc_refs, ks):
+            acc_ref[pl.ds(*rows), :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     _dispatch(
         _compute, pl.when, _traced_loop,
@@ -689,21 +727,18 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(step == walk.steps - 1)
     def _finalize():
-        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        for dq_ref, acc_ref in zip(dq_refs, acc_refs):
+            dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref,  # (1, block_q, d)
-    k_ref,  # (1, block_k, d)
-    v_ref,  # (1, block_k, d)
-    do_ref,  # (1, block_q, d)
-    lse_ref,  # (1, block_q, 128)
-    delta_ref,  # (1, block_q, 128)
-    dk_ref,  # out (1, block_k, d)
-    dv_ref,  # out (1, block_k, d)
-    dk_acc_ref,  # VMEM (block_k, d) f32
-    dv_acc_ref,  # VMEM (block_k, d) f32
-    *,
+    # parts x q (1, block_q, d_p); parts x k (1, block_k, d_p);
+    # v (1, block_k, d_v); do (1, block_q, d_v); lse, delta (1, block_q, 128);
+    # out parts x dk (1, block_k, d_p), dv (1, block_k, d_v); VMEM f32
+    # accumulators of the outputs' shapes
+    *refs,
+    parts: int,
+    spans: tuple,
     scale: float,
     causal: bool,
     block_q: int,
@@ -715,32 +750,54 @@ def _flash_bwd_dkv_kernel(
     walk: _Band,
     window=None,
 ):
-    """dV = Pᵀ dO and dK = dSᵀ Q · scale of ONE K/V head, accumulated
-    over the innermost grid dimension: the query heads that read it, and
-    for each the q blocks of the kv block's band."""
+    """dV = Pᵀ dO and dK = dSᵀ Q · scale (a score part: its own Q),
+    accumulated over the innermost grid dimension: the query heads of the
+    K/V side's COARSEST head, and for each the q blocks of the kv
+    block's band.  ``spans`` (a k part, then v): None where the array's
+    head is that coarsest one, so its gradient sums over the whole
+    dimension (grouped K/V; a rotary key all query heads share); else
+    the steps one of its heads stays, after which its gradient is written
+    and its accumulator starts again."""
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
+    out_refs = refs[2 * parts + 4:3 * parts + 5]  # parts x dk, dv
+    acc_refs = refs[3 * parts + 5:]
+    dk_acc_refs, dv_acc_ref = acc_refs[:parts], acc_refs[parts]
     ki = pl.program_id(1)
     step = pl.program_id(2)
     qi = walk.block(ki, jax.lax.rem(step, walk.steps))
 
-    @pl.when(step == 0)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+    def at_end(span, last):
+        """Is this the first (``last``: the last) step of a head that
+        stays ``span`` steps."""
+        if span is None:
+            return step == (pl.num_programs(2) - 1 if last else 0)
+        return jax.lax.rem(step, span) == (span - 1 if last else 0)
+
+    grads = list(zip(spans, acc_refs, out_refs, (scale,) * parts + (None,)))
+    for span in dict.fromkeys(spans):
+        @pl.when(at_end(span, False))
+        def _init(span=span):
+            for of, acc_ref, _, _ in grads:
+                if of == span:
+                    acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute(rows, cols, edges):
-        q, _, do, p, ds = _backward_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
+        qs, _, do, p, ds = _backward_tile(
+            q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, rows, cols,
             edges, scale, window,
         )
         c = pl.ds(*cols)
         dv_acc_ref[c, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # pᵀ @ do: (cols, d)
-        dk_acc_ref[c, :] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # dsᵀ @ q (un-normalized; scale applied at finalize)
+        )  # pᵀ @ do: (cols, d_v)
+        ds = ds.astype(qs[0].dtype)
+        for dk_acc_ref, q in zip(dk_acc_refs, qs):
+            dk_acc_ref[c, :] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # dsᵀ @ q (un-normalized; scale applied at finalize)
 
     _dispatch(
         _compute, pl.when, _traced_loop,
@@ -748,10 +805,13 @@ def _flash_bwd_dkv_kernel(
         block_q, block_k, sub_q, sub_k, causal, window,
     )
 
-    @pl.when(step == pl.num_programs(2) - 1)
-    def _finalize():
-        dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+    for span in dict.fromkeys(spans):
+        @pl.when(at_end(span, True))
+        def _finalize(span=span):
+            for of, acc_ref, out_ref, by in grads:
+                if of == span:
+                    acc = acc_ref[...] if by is None else acc_ref[...] * by
+                    out_ref[0] = acc.astype(out_ref.dtype)
 
 
 def _lse_delta_lanes(o, lse, do):
@@ -775,8 +835,10 @@ def _flash_backward_pallas(
     block_q: int, block_k: int, q_offset: int, kv_offset: int, interpret: bool,
     lse_delta_b=None, out_dtype=None, window=None,
 ):
-    """Pallas flash backward on q [BH, T, D], k/v [B·KV, T, D] inputs →
-    (dq, dk, dv), dk/dv of k/v's shape.
+    """Pallas flash backward on q [BH, T, D], k [B·KV, T, D], v [B·KV, T,
+    Dv] inputs (``q``/``k`` may be tuples of score parts, see
+    :func:`_flash_forward`) → (dq, dk, dv), each of its input's shape
+    and structure.
 
     ``out_dtype`` overrides the gradients' dtype (default: the inputs') —
     ring callers take f32 so per-step partials are not rounded to bf16
@@ -790,69 +852,91 @@ def _flash_backward_pallas(
     pass ``lse_delta_b`` (from :func:`_lse_delta_lanes`) to reuse them
     across calls that share (o, lse, do).
     """
-    bh, t_q, d = q.shape
-    bkv, t_k, _ = k.shape
-    common, group, walk, walk_dkv = _plan(
-        q, k, block_q, block_k, causal, window, q_offset, kv_offset,
+    qs, ks = _parts(q), _parts(k)
+    kvs = ks + (v,)
+    bh, t_q, _ = qs[0].shape
+    t_k, d_v = v.shape[1], v.shape[-1]
+    common, groups, walk, walk_dkv = _plan(
+        qs, kvs, block_q, block_k, causal, window, q_offset, kv_offset,
         interpret,
     )
     block_q, block_k = common["block_q"], common["block_k"]
     if lse_delta_b is None:
         lse_delta_b = _lse_delta_lanes(o, lse, do)
     lse_b, delta_b = lse_delta_b
+    of_q, of_kv = _band_specs(block_q, block_k, walk)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, s: (b, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, block_k, d), lambda b, i, s: (jax.lax.div(b, group), walk.fetch(i, s), 0)
-    )
-    lane_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, s: (b, i, 0))
+    q_specs = [of_q(x.shape[-1]) for x in qs]
     with jax.named_scope("flash.dq"):
         dq = pl.pallas_call(
             functools.partial(
-                _flash_bwd_dq_kernel, scale=scale, walk=walk, **common
+                _flash_bwd_dq_kernel, parts=len(qs), scale=scale, walk=walk,
+                **common,
             ),
             grid=(bh, t_q // block_q, walk.steps),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, lane_spec, lane_spec],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((bh, t_q, d), out_dtype or q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            in_specs=q_specs + [of_kv(x, g) for x, g in zip(kvs, groups)]
+            + [of_q(d_v), of_q(128), of_q(128)],
+            out_specs=q_specs,
+            out_shape=[
+                jax.ShapeDtypeStruct(x.shape, out_dtype or x.dtype) for x in qs
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, x.shape[-1]), jnp.float32) for x in qs
+            ],
             interpret=interpret,
-        )(q, k, v, do, lse_b, delta_b)
+        )(*qs, *kvs, do, lse_b, delta_b)
 
-    # Grid (K/V head, kv block, its group's query heads x their q steps).
+    # Grid (the K/V side's coarsest head, kv block, that head's query
+    # heads x their q steps).  An array of the K/V side with more heads
+    # (``wide`` of them a coarsest head) moves to its next head every
+    # ``group * q_steps`` steps.
     num_k, q_steps = t_k // block_k, walk_dkv.steps
+    most = max(groups)
 
     def of_q(width):
         return pl.BlockSpec(
             (1, block_q, width),
             lambda b, j, s: (
-                b * group + jax.lax.div(s, q_steps),
+                b * most + jax.lax.div(s, q_steps),
                 walk_dkv.fetch(j, jax.lax.rem(s, q_steps)),
                 0,
             ),
         )
 
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, s: (b, j, 0))
+    def of_kv(x, group):
+        if group == most:
+            return pl.BlockSpec((1, block_k, x.shape[-1]), lambda b, j, s: (b, j, 0))
+        wide, span = most // group, group * q_steps
+        return pl.BlockSpec(
+            (1, block_k, x.shape[-1]),
+            lambda b, j, s: (b * wide + jax.lax.div(s, span), j, 0),
+        )
+
+    kv_specs = [of_kv(x, g) for x, g in zip(kvs, groups)]
     with jax.named_scope("flash.dkv"):
-        dk, dv = pl.pallas_call(
+        *dk, dv = pl.pallas_call(
             functools.partial(
-                _flash_bwd_dkv_kernel, scale=scale, walk=walk_dkv, **common
+                _flash_bwd_dkv_kernel, parts=len(qs), scale=scale,
+                walk=walk_dkv, spans=tuple(
+                    None if g == most else g * q_steps for g in groups
+                ), **common,
             ),
-            grid=(bkv, num_k, group * q_steps),
-            in_specs=[of_q(d), kv_spec, kv_spec, of_q(d), of_q(128), of_q(128)],
-            out_specs=[kv_spec, kv_spec],
+            grid=(bh // most, num_k, most * q_steps),
+            in_specs=[of_q(x.shape[-1]) for x in qs] + kv_specs
+            + [of_q(d_v), of_q(128), of_q(128)],
+            out_specs=kv_specs,
             out_shape=[
-                jax.ShapeDtypeStruct((bkv, t_k, d), out_dtype or k.dtype),
-                jax.ShapeDtypeStruct((bkv, t_k, d), out_dtype or v.dtype),
+                jax.ShapeDtypeStruct(x.shape, out_dtype or x.dtype) for x in kvs
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, x.shape[-1]), jnp.float32) for x in kvs
             ],
             interpret=interpret,
-        )(q, k, v, do, lse_b, delta_b)
+        )(*qs, *kvs, do, lse_b, delta_b)
 
-    return dq, dk, dv
+    if isinstance(q, (tuple, list)):
+        return tuple(dq), tuple(dk), dv
+    return dq[0], dk[0], dv
 
 
 @functools.partial(
@@ -883,10 +967,11 @@ def _flash_out_lse(
     q, k, v, scale, causal, block_q, block_k, q_offset, kv_offset, interpret,
     window,
 ):
-    b, t, h, d = q.shape
+    b, _, h, _ = jax.tree_util.tree_leaves(q)[0].shape
+    to_bht = functools.partial(jax.tree_util.tree_map, _bthd_to_bht)
     o, lse = _flash_forward(
-        _bthd_to_bht(q),
-        _bthd_to_bht(k),
+        to_bht(q),
+        to_bht(k),
         _bthd_to_bht(v),
         scale=scale,
         causal=causal,
@@ -910,7 +995,7 @@ def _flash_fwd_bthd(
     )
     # The names are on the forward RULE's residuals (outside a
     # checkpoint they are the identity): `out` after the transpose back
-    # to [B, T, H, D] and the [BH, T] float32 `lse`, never its
+    # to [B, T, H, Dv] and the [BH, T] float32 `lse`, never its
     # lane-replicated [BH, T, 128] form.
     out = checkpoint_name(out, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
@@ -922,10 +1007,11 @@ def _flash_bwd_bthd(
     res, g,
 ):
     q, k, v, out, lse = res
-    b, t, h, d = q.shape
+    b = out.shape[0]
+    to_bht = functools.partial(jax.tree_util.tree_map, _bthd_to_bht)
     dq, dk, dv = _flash_backward_pallas(
-        _bthd_to_bht(q),
-        _bthd_to_bht(k),
+        to_bht(q),
+        to_bht(k),
         _bthd_to_bht(v),
         _bthd_to_bht(out),
         lse,
@@ -939,16 +1025,19 @@ def _flash_bwd_bthd(
         interpret=interpret,
         window=window,
     )
-    kv = k.shape[2]
-    return _bht_to_bthd(dq, b, h), _bht_to_bthd(dk, b, kv), _bht_to_bthd(dv, b, kv)
+    # Every gradient back in its input's [B, T, heads, width].
+    like = lambda grads, xs: jax.tree_util.tree_map(
+        lambda d, x: _bht_to_bthd(d, b, x.shape[2]), grads, xs
+    )
+    return like(dq, q), like(dk, k), like(dv, v)
 
 
 _flash_bthd.defvjp(_flash_fwd_bthd, _flash_bwd_bthd)
 
 
 def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
+    q,  # an array, or a tuple of score parts
+    k,
     v: jax.Array,
     *,
     causal: bool = False,
@@ -979,6 +1068,17 @@ def flash_attention(
     ``h // (H // KV)`` straight from the unrepeated arrays, and dK/dV
     come back ``[B, T, KV, D]``, summed over each group in the kernel.
 
+    Widths come from the shapes.  ``v`` may be ``[B, T, KV, Dv]`` with a
+    width of its own: the output is ``[B, T, H, Dv]``, and nothing is
+    padded to the query-key width.  ``q`` and ``k`` may be tuples of
+    score PARTS, ``q_p`` ``[B, T, H, D_p]`` and ``k_p`` ``[B, T, KV_p,
+    D_p]``: the score is the sum over parts of ``q_p · k_p``, added in
+    VMEM, each ``k_p`` read by its own head count (latent attention: a
+    per-head part, and a rotary part ONE key head holds for all query
+    heads, never copied to them); the default scale is over the summed
+    width, and the gradients come back in the inputs' structure, a
+    shared part's summed over its query heads in the kernel.
+
     ``q_offset``/``kv_offset`` are *static* global positions of the first
     q/kv token (sharded-causal use).  Arbitrary dense ``mask`` is not
     supported by the tiled kernel — use ``dot_product_attention``.
@@ -1000,20 +1100,28 @@ def flash_attention(
             raise ValueError("window= requires causal=True (Mistral SWA)")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-    kv_group(q, k, v)  # K/V heads must agree and divide the query heads
+    if isinstance(q, (tuple, list)):
+        q, k = tuple(q), tuple(k)
+        check_score_parts(q, k, v)  # widths pair up, head counts divide
+    else:
+        kv_group(q, k, v)  # K/V heads must agree and divide the query heads
+    qs, ks = _parts(q), _parts(k)
     if interpret is None:
         interpret = _interpret_default()
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    qk_widths = [x.shape[-1] for x in qs]
+    scale = sm_scale if sm_scale is not None else sum(qk_widths) ** -0.5
     # Blocks must divide the sequence lengths: shrink the requested size
     # to the largest 8-aligned divisor (e.g. T=1280 with block_k=1024 →
     # 640) instead of erroring on any non-multiple length.
-    block_q = _fit_block(q.shape[1], block_q)
-    block_k = _fit_block(k.shape[1], block_k)
+    b, t_q, h, _ = qs[0].shape
+    t_k = ks[0].shape[1]
+    block_q = _fit_block(t_q, block_q)
+    block_k = _fit_block(t_k, block_k)
     if not interpret and (block_q % 8 or block_k % 8):
         # No 8-aligned divisor exists (e.g. prime T): fail here with an
         # actionable message instead of a Mosaic tiling error downstream.
         raise ValueError(
-            f"sequence lengths ({q.shape[1]}, {k.shape[1]}) admit no "
+            f"sequence lengths ({t_q}, {t_k}) admit no "
             f"8-aligned block split for the compiled TPU kernel — pad the "
             f"sequence to a multiple of 8 or use dot_product_attention"
         )
@@ -1023,19 +1131,25 @@ def flash_attention(
         # One record a call traced while the recorder is armed: what the
         # kernels of this call (forward and backward) compute.
         schedule = block_schedule(
-            q.shape[1], k.shape[1], block_q, block_k, SPLIT, causal, window,
+            t_q, t_k, block_q, block_k, SPLIT, causal, window,
             q_offset, kv_offset,
         )
         telemetry.emit(
             "attn.schedule",
             detail=dict(
-                schedule._asdict(), batch=q.shape[0], heads=q.shape[2],
-                kv_heads=k.shape[2], t_q=q.shape[1], t_k=k.shape[1],
+                schedule._asdict(), batch=b, heads=h,
+                kv_heads=ks[0].shape[2], t_q=t_q, t_k=t_k,
                 block_q=block_q, block_k=block_k, causal=causal,
                 window=window, q_offset=q_offset, kv_offset=kv_offset,
-                # what a checkpoint that saves RESIDUAL_NAMES keeps a call
-                residual_bytes=q.size * q.dtype.itemsize
-                + q.shape[0] * q.shape[2] * q.shape[1] * 4,
+                # the width of each score part (and the K heads that hold
+                # it), and of the values
+                qk_widths=qk_widths, part_kv_heads=[x.shape[2] for x in ks],
+                v_width=v.shape[-1],
+                # what a checkpoint that saves RESIDUAL_NAMES keeps a
+                # call: the output (the VALUE width) and a float32
+                # statistic a (batch, head, query)
+                residual_bytes=b * t_q * h * v.shape[-1] * qs[0].dtype.itemsize
+                + b * h * t_q * 4,
             ),
         )
     return _flash_bthd(

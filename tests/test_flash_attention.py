@@ -511,6 +511,32 @@ MAX_EQUATIONS = (290, 196, 226)
 MAX_PRODUCTS = (8, 12, 16)
 
 
+# The latent shape (64 heads, 128 + 64 wide score parts, one shared rotary
+# key head, values 128 wide) in both forms: equations / products in the
+# forward, dQ and dK/dV kernels as `tool/flash_sweep.py --lowering` read
+# them (PR 33), with a few equations of room.  The split form's tile body
+# holds one more product a score part in each kernel; the plain form
+# (k concatenated first) is the full-attention program of the other
+# shapes at another width.
+LATENT_SHAPE = (1, 8192, 64, 128, 64, 128)
+LATENT_MAX = {
+    "split": ((190, 152, 188), (9, 15, 18)),
+    "plain": ((172, 118, 144), (6, 9, 12)),
+}
+
+
+@pytest.mark.parametrize("form", list(LATENT_MAX))
+def test_latent_kernel_programs_stay_within_their_size_budget(form):
+    from tool.flash_sweep import kernel_counts, latent_attention_grad
+
+    grad, args = latent_attention_grad(fa, LATENT_SHAPE, form == "split")
+    counts = kernel_counts(jax.make_jaxpr(grad)(*args).jaxpr)
+    assert len(counts) == 3  # forward, dQ, dK/dV
+    most, most_products = LATENT_MAX[form]
+    assert [n <= m for (n, _), m in zip(counts, most)] == [True] * 3, counts
+    assert [d for _, d in counts] == list(most_products)
+
+
 @pytest.mark.parametrize("shape", list(BENCHMARK_SHAPES))
 def test_kernel_programs_stay_within_the_size_budget(shape):
     """Tracing and lowering the kernels is host work every party's thread
@@ -681,3 +707,104 @@ def test_attn_schedule_record_carries_the_residual_bytes():
     finally:
         telemetry.uninstall()
     assert record.detail["residual_bytes"] == b * t * h * d * 2 + b * h * t * 4
+
+
+# --- a score in parts, and values of a width of their own -----------------
+
+
+def _latent_parts(key, b=2, t=64, h=4, nope=16, rope=8, dv=12):
+    """The published ratios at toy widths: the rotary part half the part
+    without positions, ONE rotary key head, a value width that is
+    neither."""
+    ks = jax.random.split(key, 6)
+    shapes = [(h, nope), (h, rope), (h, nope), (1, rope), (h, dv), (h, dv)]
+    return [jax.random.normal(k, (b, t, *s)) for k, s in zip(ks, shapes)]
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_flash_split_score_matches_dense_on_the_concatenated_key(window):
+    """The kernels given the score's two parts (the rotary key read by
+    its one head, its gradient summed over the query heads in the dK/dV
+    kernel) against ``dot_product_attention`` on the concatenated key:
+    forward and all five gradients."""
+    from rayfed_tpu.ops.attention import score_parts
+
+    *parts, w = _latent_parts(jax.random.PRNGKey(0))
+    kw = dict(causal=True, window=window, sm_scale=0.3)
+
+    def split(q_nope, q_pe, k_nope, k_pe, v):
+        out = fa.flash_attention(
+            (q_nope, q_pe), (k_nope, k_pe), v, block_q=16, block_k=32, **kw
+        )
+        return jnp.sum(out * w), out
+
+    def dense(q_nope, q_pe, k_nope, k_pe, v):
+        q, k = score_parts((q_nope, q_pe), (k_nope, k_pe), v)
+        assert q.shape[-1] == k.shape[-1] == 24 and k.shape[2] == 4
+        out = dot_product_attention(q, k, v, **kw)
+        return jnp.sum(out * w), out
+
+    argnums = (0, 1, 2, 3, 4)
+    (_, got), got_grads = jax.value_and_grad(split, argnums, has_aux=True)(*parts)
+    (_, want), want_grads = jax.value_and_grad(dense, argnums, has_aux=True)(*parts)
+    assert got.shape == (2, 64, 4, 12)  # the VALUE width
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    for part, g, wg in zip(parts, got_grads, want_grads):
+        assert g.shape == part.shape  # k_pe's: one head
+        np.testing.assert_allclose(g, wg, rtol=2e-4, atol=2e-4)
+    # the dense path takes the parts as they are, too
+    np.testing.assert_allclose(
+        dot_product_attention(tuple(parts[:2]), tuple(parts[2:4]), parts[4], **kw),
+        want, rtol=1e-6, atol=1e-6,
+    )
+
+
+def test_flash_value_width_differs_from_the_query_key_width():
+    """One product over the whole query-key width, V not padded to it."""
+    from rayfed_tpu.ops.attention import score_parts
+
+    *parts, w = _latent_parts(jax.random.PRNGKey(1))
+    q, k = score_parts(tuple(parts[:2]), tuple(parts[2:4]), parts[4])
+    v = parts[4]
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, **kw) * w)
+
+    got = jax.grad(loss(fa.flash_attention, block_q=32, block_k=16), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dot_product_attention), (0, 1, 2))(q, k, v)
+    for g, wg in zip(got, want):
+        np.testing.assert_allclose(g, wg, rtol=2e-4, atol=2e-4)
+
+
+def test_score_parts_validation():
+    q_nope, q_pe, k_nope, k_pe, v, _ = _latent_parts(jax.random.PRNGKey(2))
+    with pytest.raises(ValueError, match="do not pair"):
+        fa.flash_attention((q_nope, q_pe), (k_nope, k_pe[..., :4]), v)
+    with pytest.raises(ValueError, match="query parts"):
+        fa.flash_attention((q_nope, q_pe), (k_nope,), v)
+    with pytest.raises(ValueError, match="must divide"):
+        fa.flash_attention(
+            (q_nope, q_pe), (k_nope, jnp.repeat(k_pe, 3, axis=2)), v
+        )
+
+
+def test_attn_schedule_record_carries_the_widths_of_a_latent_call():
+    """The record of a call with a two-part score: the parts' widths and
+    K heads, the value width, and ``residual_bytes`` from the OUTPUT's
+    shape (the value width; the query-key width would over-count by half
+    at 192 against 128)."""
+    b, t, h = 2, 64, 4
+    q_nope, q_pe, k_nope, k_pe, v, _ = (
+        x.astype(jnp.bfloat16) for x in _latent_parts(jax.random.PRNGKey(3))
+    )
+    rec = telemetry.install(party="alice")
+    try:
+        fa.flash_attention((q_nope, q_pe), (k_nope, k_pe), v, causal=True,
+                           block_q=32, block_k=32)
+        (record,) = [r for r in rec.records() if r.phase == "attn.schedule"]
+    finally:
+        telemetry.uninstall()
+    d = record.detail
+    assert (d["qk_widths"], d["part_kv_heads"], d["v_width"]) == ([16, 8], [4, 1], 12)
+    assert d["residual_bytes"] == b * t * h * 12 * 2 + b * h * t * 4
+    assert (d["heads"], d["kv_heads"]) == (4, 4)

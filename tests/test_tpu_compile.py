@@ -74,6 +74,28 @@ def _flash_fwd_bwd(t, d, heads=2, kv_heads=None, **kw):
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
 
 
+def _latent_flash_fwd_bwd(split, t=8192, heads=64, nope=128, rope=64, dv=128):
+    """The latent shape: a score of two parts (the rotary one on ONE key
+    head), values of their own width; ``split`` False concatenates the
+    key first (one 192-wide product)."""
+    from rayfed_tpu.ops.attention import score_parts
+    from rayfed_tpu.ops.flash_attention import flash_attention
+
+    def loss(q_nope, q_pe, k_nope, k_pe, v):
+        q, k = (q_nope, q_pe), (k_nope, k_pe)
+        if not split:
+            q, k = score_parts(q, k, v)
+        return jnp.sum(
+            flash_attention(q, k, v, causal=True, interpret=False)
+            .astype(jnp.float32) ** 2
+        )
+
+    shape = lambda h, d: jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16)
+    args = _on_chip((shape(heads, nope), shape(heads, rope), shape(heads, nope),
+                     shape(1, rope), shape(heads, dv)))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args)
+
+
 def _resnet18_bundle():
     from rayfed_tpu.fl import compress
     from rayfed_tpu.models import resnet
@@ -176,6 +198,15 @@ CASES = {
     "flash_t8192_d128_h32_kv8_window4096": (
         lambda: _flash_fwd_bwd(8192, 128, heads=32, kv_heads=8, window=4096),
         True,
+    ),
+    # Latent attention at the published widths, 1024 x 1024 blocks: the
+    # split score (128 + 64 wide parts, one shared rotary key head, the
+    # form the decoder runs) and the concatenated key, values 128 wide.
+    "flash_t8192_latent_128+64_v128_split": (
+        lambda: _latent_flash_fwd_bwd(True), True,
+    ),
+    "flash_t8192_latent_192_v128_plain": (
+        lambda: _latent_flash_fwd_bwd(False), True,
     ),
     "resnet18_fed_train_step": (_resnet18_fed_step, False),
     "quantized_accum_kernel_resnet18": (_quantized_accum, False),

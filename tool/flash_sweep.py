@@ -64,6 +64,13 @@ TINY = {
     "tiny window": (1, 256, 4, 2, 64, 96),
     "tiny full": (2, 128, 4, 1, 64, None),
 }
+# name -> (batch, tokens, heads, width without position, rotary width,
+# value width): latent attention, whose key is a part a head of its own
+# and ONE rotary head all query heads share.
+LATENT_SHAPES = {
+    "kimi latent 128+64|128": (1, 8192, 64, 128, 64, 128),
+}
+TINY_LATENT = {"tiny latent 16+8|16": (1, 256, 4, 16, 8, 16)}
 KERNELS = ("fwd", "dq", "dkv")
 
 
@@ -211,6 +218,82 @@ def run_shape(name, shape, fa, parent, splits, splash, iters, repeats,
             "rel_rms_vs_first": agree}
 
 
+def run_latent(name, shape, fa, iters, repeats, interpret):
+    """Both forms of the latent attention's kernels at one shape.
+
+    ``split``: the kernels are given the score's two parts and add the
+    two products in VMEM; the shared rotary key is read by its one head
+    and its gradient summed over the query heads in the dK/dV kernel.
+    ``plain``: k concatenated to ``[B, T, H, nope + rope]`` first (the
+    rotary head copied to every head), one product over the whole
+    width.  ``grad`` is a whole forward + backward of ``flash_attention``
+    from the five arrays a latent layer makes, so the plain form pays
+    for its concatenation and for summing the rotary key's gradient
+    over the heads outside the kernels."""
+    from rayfed_tpu.ops.attention import score_parts
+
+    b, t, h, nope, rope, dv = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    bf = jnp.bfloat16
+    q_nope = jax.random.normal(keys[0], (b, t, h, nope), bf)
+    q_pe = jax.random.normal(keys[1], (b, t, h, rope), bf)
+    k_nope = jax.random.normal(keys[2], (b, t, h, nope), bf)
+    k_pe = jax.random.normal(keys[3], (b, t, 1, rope), bf)
+    v = jax.random.normal(keys[4], (b, t, h, dv), bf)
+    do = jax.random.normal(keys[5], (b, t, h, dv), bf)
+    parts = (q_nope, q_pe, k_nope, k_pe, v)
+    block = fa._fit_block(t, 1024)
+    scale = (nope + rope) ** -0.5
+    kw = dict(scale=scale, causal=True, block_q=block, block_k=block,
+              q_offset=0, kv_offset=0, interpret=interpret, window=None)
+    bht = lambda x: jax.tree_util.tree_map(fa._bthd_to_bht, x)
+
+    def qk(form, q_nope, q_pe, k_nope, k_pe, v):
+        if form == "split":
+            return (q_nope, q_pe), (k_nope, k_pe)
+        return score_parts((q_nope, q_pe), (k_nope, k_pe), v)
+
+    rows, outputs = {}, {}
+    for form in ("split", "plain"):
+        q, k = bht(qk(form, *parts))
+        fns = {
+            "fwd": jax.jit(lambda q, k, v, *_: fa._flash_forward(q, k, v, **kw)),
+            "dq": jax.jit(lambda q, k, v, o, lse, do, ld: fa._flash_backward_pallas(
+                q, k, v, o, lse, do, lse_delta_b=ld, **kw)[0]),
+            "dkv": jax.jit(lambda q, k, v, o, lse, do, ld: fa._flash_backward_pallas(
+                q, k, v, o, lse, do, lse_delta_b=ld, **kw)[1:]),
+        }
+        v_, do_ = bht(v), bht(do)
+        o, lse = fns["fwd"](q, k, v_)
+        args = (q, k, v_, o, lse, do_, fa._lse_delta_lanes(o, lse, do_))
+        rows[form] = {
+            kern: best_ms(fns[kern], args, iters, repeats) for kern in KERNELS
+        }
+
+        def loss(*parts, form=form):
+            q, k = qk(form, *parts)
+            out = fa.flash_attention(
+                q, k, parts[4], causal=True, sm_scale=scale,
+                interpret=interpret,
+            )
+            return jnp.sum(out.astype(jnp.float32) * do)
+
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+        rows[form]["grad"] = best_ms(grad, parts, iters, repeats)
+        outputs[form] = [np.asarray(x, np.float32) for x in grad(*parts)]
+    first = outputs["split"]
+    agree = {
+        form: max(
+            float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+            for a, b in zip(outs, first)
+        )
+        for form, outs in outputs.items()
+    }
+    sched = fa.block_schedule(t, t, block, block, fa.SPLIT, True, None, 0, 0)
+    return {"shape": name, "ms": rows, "rel_rms_vs_first": agree,
+            "schedule": {form: sched._asdict() for form in rows}}
+
+
 def table(results):
     lines = [
         "| shape | kernels | fwd ms | dQ ms | dK/dV ms | grid steps a head "
@@ -230,6 +313,8 @@ def table(results):
                 [f"{ms[k]:.3f}" for k in KERNELS] if "error" not in ms
                 else [ms["error"], "", ""]
             )
+            if "grad" in ms:  # a latent form: the whole forward + backward
+                cells[-1] += f" (fwd + bwd from the parts {ms['grad']:.3f})"
             idle = s and steps - s["grid"][0] * s["grid"][1] + s["steps_skipped"]
             sched = (
                 [f"{steps} ({idle})",
@@ -249,6 +334,7 @@ STEP_CELLS = (
     "mistral-7b-v0.1-d6.lora-2p",
     "mistral-7b-v0.1-d6.qlora-wire-uint8",
     "trinity-mini-ep8.lora-all-linear-2p",
+    "kimi-k2.7-code-ep32.lora-all-linear-2p",
 )
 TRACE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -307,6 +393,24 @@ def attention_grad(fa, shape, grouped=True):
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, t, kv, d), jnp.bfloat16)
     return jax.grad(loss, argnums=(0, 1, 2)), (q, k, k)
+
+
+def latent_attention_grad(fa, shape, split=True):
+    """As :func:`attention_grad`, for a latent shape in either form."""
+    from rayfed_tpu.ops.attention import score_parts
+
+    b, t, h, nope, rope, dv = shape
+
+    def loss(q_nope, q_pe, k_nope, k_pe, v):
+        q, k = (q_nope, q_pe), (k_nope, k_pe)
+        if not split:
+            q, k = score_parts(q, k, v)
+        out = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    S = lambda heads, d: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16)
+    args = (S(h, nope), S(h, rope), S(h, nope), S(1, rope), S(h, dv))
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4)), args
 
 
 def _timed(lower, events):
@@ -384,12 +488,24 @@ def lowering_child(root, repeats):
         work["attention " + name] = lambda shape=shape: (
             lambda grad, args: jax.jit(grad).lower(*on_chip(args))
         )(*attention_grad(fa, shape, grouped))
+    latent = hasattr(attention, "score_parts")  # a tree that has the kind
+    for name, shape in LATENT_SHAPES.items() if latent else ():
+        work["attention " + name] = lambda shape=shape: (
+            lambda grad, args: jax.jit(grad).lower(*on_chip(args))
+        )(*latent_attention_grad(fa, shape))
     for name in STEP_CELLS:
-        work["step " + name] = _step_lowering(name, on_chip)
+        if os.path.exists(os.path.join(root, "benchmark", "workloads", name + ".json")):
+            work["step " + name] = _step_lowering(name, on_chip)
     report = {"kernels": {}, "first": {}, "again": {}}
     for name, shape in SHAPES.items():
         grad, args = attention_grad(fa, shape, grouped)
         report["kernels"][name] = kernel_counts(jax.make_jaxpr(grad)(*args).jaxpr)
+    for name, shape in LATENT_SHAPES.items() if latent else ():
+        for form in ("split", "plain"):
+            grad, args = latent_attention_grad(fa, shape, form == "split")
+            report["kernels"][f"{name} {form}"] = kernel_counts(
+                jax.make_jaxpr(grad)(*args).jaxpr
+            )
     for name, lower in work.items():
         lower()  # pays the imports
         first, again = [], []
@@ -446,20 +562,21 @@ def lowering(args):
         cells = []
         for label in labels:
             for key in ("first", "again"):
-                s = reports[label][key][name]
+                s = reports[label][key].get(name)
                 cells.append(
                     f"{s['wall_s'][0]:.2f}-{s['wall_s'][1]:.2f} "
-                    f"({s['lower_s'][0]:.2f})"
+                    f"({s['lower_s'][0]:.2f})" if s else "-"
                 )
         print(f"| {name} | " + " | ".join(cells) + " |")
     print()
     print("Equations (matrix products) in the kernels' jaxprs: fwd / dQ / dK/dV")
     print("| shape | " + " | ".join(labels) + " |")
     print("| --- |" + " --- |" * len(labels))
-    for name in SHAPES:
+    for name in reports[labels[-1]]["kernels"]:
         cells = [
             " / ".join(f"{n} ({dots})" for n, dots in reports[label]["kernels"][name])
             + f" = {sum(n for n, _ in reports[label]['kernels'][name])}"
+            if name in reports[label]["kernels"] else "-"
             for label in labels
         ]
         print(f"| {name} | " + " | ".join(cells) + " |")
@@ -502,6 +619,12 @@ def main():
             interpret=device.platform != "tpu",
         )
         for name, shape in shapes.items() if args.shapes in name
+    ]
+    latent = TINY_LATENT if args.tiny else LATENT_SHAPES
+    results += [
+        run_latent(name, shape, fa, args.iters, args.repeats,
+                   interpret=device.platform != "tpu")
+        for name, shape in latent.items() if args.shapes in name
     ]
     report = {
         "device": {"platform": device.platform, "kind": device.device_kind},
