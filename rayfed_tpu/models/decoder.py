@@ -21,10 +21,14 @@ group (one compiled body however many layers; ``remat`` is
 ``jax.checkpoint`` of that body with ``llama.py``'s policy
 ``REMAT_SAVED``, which saves beside the layer's input the experts
 selected: a selection made again from recomputed scores need not be the
-one the forward pass used; and the flash kernel's output and row
+one the forward pass used; the flash kernel's output and row
 statistics, ``B*T*H*Dh`` elements of ``dtype`` + ``B*H*T*4`` bytes a
-layer, 68.2 MB a sequence of 8,192 at 32 x 128 heads: the backward pass
-runs the rest of the layer's forward again, not the attention kernel).
+layer, 68.2 MB a sequence of 8,192 at 32 x 128 heads; and the up
+product of the dense FFN or the shared expert, ``B*T*F`` elements of
+``dtype``, 100.7 MB at F = 6,144: the backward pass runs the rest of
+the layer's forward again, the FFN's gate product too, but not the
+attention kernel nor the up product; the routed experts keep what
+their hand-written backward keeps).
 Where a group mixes attention kinds the body picks the layer's kernel
 with a ``cond`` on a scanned flag (the flash kernel's ``window`` is
 static); the branches' residuals are of one shape and share the
@@ -39,8 +43,8 @@ Two configurations run through it: the AFMoE family's
 block (``benchmark/families/kimi_k2_lm.py``, ``reference/kimi_k2.py``),
 all of whose layers are latent.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
-``_linear``, ``lm_loss``, ``_adam_update``); ``llama.py``'s own programs
-do not pass through this module.
+``_linear``, ``lm_loss``, ``frozen_head_loss``, ``_adam_update``);
+``llama.py``'s own programs do not pass through this module.
 """
 
 from __future__ import annotations
@@ -64,7 +68,10 @@ from rayfed_tpu.models.llama import (
     _linear,
     _rms_norm,
     apply_rope,
-    lm_loss,
+    checkpoint_layer,
+    emit_remat_saved,
+    frozen_head_loss,
+    lm_loss,  # noqa: F401  (benchmark/families read `decoder.lm_loss`)
     rope_tables,
 )
 from rayfed_tpu.ops.attention import dot_product_attention
@@ -396,6 +403,18 @@ def apply_decoder(
     them: ``layers`` keyed by the group's index as a string).
     """
     c = config
+    x, aux = _hidden_states(params, input_ids, c, lora, attn_fn)
+    logits = jax.lax.dot_general(
+        x, params["lm_head"].astype(c.dtype),
+        (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    return logits, aux
+
+
+def _hidden_states(params, input_ids, config, lora, attn_fn):
+    """The final-normed residual stream [B, T, D] in the compute dtype,
+    and ``apply_decoder``'s ``aux``."""
+    c = config
     x = embed(params, input_ids, c)
     lora_groups = (lora or {}).get("layers", {})
     aux = {}
@@ -413,7 +432,7 @@ def apply_decoder(
             )
 
         if c.remat:
-            body = jax.checkpoint(body, policy=REMAT_SAVED)
+            body = checkpoint_layer(body, REMAT_SAVED, stop - start)
         windowed = jnp.asarray([s.attention == "window" for s in specs])
         with jax.named_scope(f"layers{start}-{stop - 1}"):
             x, stacked = jax.lax.scan(
@@ -423,11 +442,7 @@ def apply_decoder(
             for i in range(start, stop):
                 aux[i] = jax.tree_util.tree_map(lambda a: a[i - start], stacked)
     x = _rms_norm(x, params["final_norm"], c.rms_eps)
-    logits = jax.lax.dot_general(
-        x.astype(c.dtype), params["lm_head"].astype(c.dtype),
-        (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    return logits, aux
+    return x.astype(c.dtype), aux
 
 
 def routing_counts(aux) -> Optional[jax.Array]:
@@ -441,13 +456,37 @@ def routing_counts(aux) -> Optional[jax.Array]:
     ])
 
 
+def _kept_by_group(config: DecoderConfig, tokens: int):
+    """``{scanned group: (layers, {name: bytes a layer keeps})}`` for the
+    names of ``REMAT_SAVED`` given in ``moe.py``: the up product of the
+    dense FFN or the shared expert, and an expert layer's selection;
+    empty without ``remat``."""
+    c, itemsize = config, jnp.dtype(config.dtype).itemsize
+    kept = {}
+    for start, stop in c.groups() if c.remat else ():
+        if c.layers[start].ffn == "dense":
+            sizes = {moe.FFN_UP_NAME: tokens * c.intermediate_size * itemsize}
+        else:
+            sizes = {
+                moe.FFN_UP_NAME: tokens * c.experts.d_ff * itemsize,
+                moe.SELECTED_NAME: tokens * c.experts.top_k * 4,
+            }
+        kept[f"layers{start}-{stop - 1}"] = (stop - start, sizes)
+    return kept
+
+
 def lora_loss(lora, base, ids, config: DecoderConfig, *,
               attn_fn: Callable = dot_product_attention):
     """Next-token loss of ``ids`` [B, T] with adapters ``lora`` on the
     frozen ``base``, and ``apply_decoder``'s ``aux``: what the LoRA step
-    differentiates."""
-    logits, aux = apply_decoder(base, ids, config, lora=lora, attn_fn=attn_fn)
-    return lm_loss(logits[:, :-1], ids[:, 1:]), aux
+    differentiates.  The head is frozen too, so head and loss are fused
+    (:func:`llama.frozen_head_loss`: no ``[B, T, V]`` array)."""
+    x, aux = _hidden_states(base, ids, config, lora, attn_fn)
+    emit_remat_saved(
+        _kept_by_group(config, ids.size), ids.size, config.vocab_size
+    )
+    head = base["lm_head"].astype(config.dtype)
+    return frozen_head_loss(x, head, ids), aux
 
 
 def make_lora_train_step(

@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from rayfed_tpu.models.moe import SELECTED_NAME
+from rayfed_tpu import telemetry
+from rayfed_tpu.models.moe import FFN_UP_NAME, SELECTED_NAME, swiglu
 from rayfed_tpu.ops.attention import NEG_INF, dot_product_attention
 from rayfed_tpu.ops.flash_attention import RESIDUAL_NAMES
 
@@ -38,12 +39,30 @@ Params = Dict[str, Any]
 
 # What a rematerialized layer body keeps besides its input, here and in
 # ``decoder.py``: the experts a routed layer selected (selected again
-# from recomputed scores they need not be the same) and the flash
-# kernel's output and row statistics, which only the kernel can make
-# again.  Everything else of the layer's forward is recomputed.
-REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(
-    SELECTED_NAME, *RESIDUAL_NAMES
-)
+# from recomputed scores they need not be the same), the flash kernel's
+# output and row statistics, which only the kernel can make again, and
+# the gated FFN's up product (``B*T*F`` elements of the compute dtype a
+# dense FFN or shared expert): the second forward makes ``silu(gate) *
+# up`` from a recomputed gate and the saved up, one FFN-width product
+# less a layer.  Everything else of the layer's forward is recomputed.
+# The gate product (``moe.FFN_GATE_NAME``) is NOT kept: kept in bf16 it
+# moved the losses of the cells that adapt the FFN by 4e-4 (PERF.md
+# section 6, PR 34).
+REMAT_SAVED_NAMES = (SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME)
+REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+
+
+def checkpoint_layer(body, policy, layers: int):
+    """``jax.checkpoint`` of a layer body that ``lax.scan`` runs
+    ``layers`` times.  Inside a loop the barrier ``prevent_cse`` puts on
+    every residual guards nothing, and it makes a saved residual cost
+    more than its bytes (0.3-0.8 GB of a benchmark step's temporaries):
+    off.  A scan of ONE layer XLA unrolls, and there the barrier is
+    what keeps it from merging the second forward into the first, that
+    is from keeping the whole layer (+1.3 GB in the step of the
+    benchmark's Kimi cell compiled for the TPU, +0.85 in Trinity's,
+    each for its dense layer 0): on."""
+    return jax.checkpoint(body, policy=policy, prevent_cse=layers == 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,13 +86,16 @@ class LlamaConfig:
     # float32 (see init_adam for why).
     param_dtype: Any = jnp.float32
     # ``jax.checkpoint`` of the scanned layer body.  A layer keeps its
-    # input and, where ``attn_fn`` is the flash kernel, the kernel's
-    # output and row statistics (``REMAT_SAVED``), so the backward pass
-    # runs the layer's projections, norms, rotary embedding and FFN
-    # again but not the attention kernel: ``B*T*H*Dh`` elements of
-    # ``dtype`` + ``B*H*T*4`` bytes a layer, 68.2 MB a sequence of 8,192
-    # at 32 x 128 heads in bf16 (2.2 GB at 32 layers), about a
-    # fourteenth of what ``remat=False`` would keep.
+    # input and (``REMAT_SAVED``) the FFN's up product and, where
+    # ``attn_fn`` is the flash kernel, the kernel's output and row
+    # statistics, so the backward pass runs the layer's projections,
+    # norms, rotary embedding and the FFN's gate product again, but not
+    # the attention kernel nor the up product: ``B*T*H*Dh`` elements of
+    # ``dtype`` + ``B*H*T*4`` bytes a layer for the kernel's (68.2 MB a
+    # sequence of 8,192 at 32 x 128 heads in bf16) and ``B*T*F``
+    # elements for the FFN's (234.9 MB at F = 14,336): 303 MB a layer
+    # beside its input (9.7 GB at 32 layers), about a third of what
+    # ``remat=False`` would keep.
     remat: bool = False
     # Rematerialization policy for the scanned layer body: None =
     # recompute everything but the above (lowest memory); "dots" = also
@@ -397,11 +419,8 @@ def _attn_out(x, attn, lp, config, b, t, lget=_no_lora):
 
 def _mlp_block(x, lp, config, lget=_no_lora):
     """RMSNorm + SwiGLU MLP residual — shared by training and decode."""
-    dtype = config.dtype
     y = _rms_norm(x, lp["mlp_norm"], config.rms_eps)
-    gate = jax.nn.silu(_linear(y, lp["w_gate"], lget("w_gate"), dtype))
-    up = _linear(y, lp["w_up"], lget("w_up"), dtype)
-    return x + _linear(gate * up, lp["w_down"], lget("w_down"), dtype)
+    return x + swiglu(y, lp, lget, config.dtype)
 
 
 def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
@@ -429,6 +448,20 @@ def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
     return (x, (k, v)) if emit_kv else (x, None)
 
 
+def _head_matrix(params, config):
+    """``(head [D, V] in the compute dtype, out_scale or None)``: the
+    vocabulary projection's operand, and what multiplies its OUTPUT."""
+    from rayfed_tpu.models.quant import split_output_scale
+
+    head = params.get("lm_head")
+    if head is None:
+        return params["embed"].astype(config.dtype).T, None
+    # Output-side scale keeps the weight feed a pure int8->bf16
+    # convert (see quant.split_output_scale) — the lm_head is the
+    # single largest weight read of a decode step.
+    return split_output_scale(head, config.dtype)
+
+
 def _lm_head(x, params, config):
     """Final norm + vocabulary projection ([..., D] → [..., V] f32).
 
@@ -436,18 +469,8 @@ def _lm_head(x, params, config):
     at a fraction of bf16 throughput and the f32 accumulator already
     carries the precision the loss needs.
     """
-    from rayfed_tpu.models.quant import split_output_scale
-
     x = _rms_norm(x, params["final_norm"], config.rms_eps)
-    head = params.get("lm_head")
-    out_scale = None
-    if head is None:
-        head = params["embed"].astype(config.dtype).T
-    else:
-        # Output-side scale keeps the weight feed a pure int8->bf16
-        # convert (see quant.split_output_scale) — the lm_head is the
-        # single largest weight read of a decode step.
-        head, out_scale = split_output_scale(head, config.dtype)
+    head, out_scale = _head_matrix(params, config)
     logits = jax.lax.dot_general(
         x.astype(config.dtype),
         head,
@@ -469,6 +492,13 @@ def apply_llama(
     positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Forward: [B, T] ids → [B, T, V] float32 logits (causal LM)."""
+    x = _hidden_states(params, input_ids, config, lora, attn_fn, positions)
+    return _lm_head(x, params, config)
+
+
+def _hidden_states(params, input_ids, config, lora, attn_fn, positions=None):
+    """The residual stream after the last layer, [B, T, D] (before the
+    final norm)."""
     b, t = input_ids.shape
     dtype = config.dtype
     h, kv, dh = config.num_heads, config.num_kv_heads, config.head_dim
@@ -504,14 +534,17 @@ def apply_llama(
         # dispatch stays exhaustive so a future policy added to the
         # whitelist cannot silently fall through to the wrong one.
         if config.remat_policy is None:
-            layer_body = jax.checkpoint(layer_body, policy=REMAT_SAVED)
+            layer_body = checkpoint_layer(
+                layer_body, REMAT_SAVED, config.num_layers
+            )
         elif config.remat_policy == "dots":
-            layer_body = jax.checkpoint(
+            layer_body = checkpoint_layer(
                 layer_body,
-                policy=jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.save_from_both_policies(
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                     REMAT_SAVED,
                 ),
+                config.num_layers,
             )
         else:  # pragma: no cover — unreachable past __post_init__
             raise AssertionError(config.remat_policy)
@@ -520,8 +553,7 @@ def apply_llama(
     if lora_layers is not None:
         scanned["lora"] = lora_layers
     x, _ = jax.lax.scan(layer_body, x, scanned)
-
-    return _lm_head(x, params, config)
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -879,6 +911,155 @@ def lm_loss(logits: jax.Array, targets: jax.Array, mask=None) -> jax.Array:
     return jnp.mean(nll)
 
 
+# The float32 logits one chunk of the fused head-and-loss may hold.
+HEAD_CHUNK_BYTES = 128 << 20
+
+
+def head_chunk_rows(rows: int, vocab: int) -> int:
+    """Rows a chunk of :func:`frozen_head_loss`: the largest power of two
+    whose float32 logits fit ``HEAD_CHUNK_BYTES`` (1,024 at a vocabulary
+    of 32,000), and no more than there are."""
+    fit = max(HEAD_CHUNK_BYTES // (4 * vocab), 1)
+    return min(1 << (fit.bit_length() - 1), rows)
+
+
+def _head_loss_chunks(xs, head, out_scale, targets, weights, with_dx):
+    """Scan the chunks: ``sum(weights * nll)``, and with ``with_dx`` its
+    gradient by ``xs``, chunk by chunk.  ``xs`` [n, rows, D], ``targets``
+    and ``weights`` [n, rows]."""
+
+    def body(total, chunk):
+        x, target, weight = chunk
+        logits = jax.lax.dot_general(
+            x, head, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if out_scale is not None:
+            logits = logits * out_scale.astype(jnp.float32)
+        # lm_loss's arithmetic, row by row: log_softmax, then the target's.
+        shifted = logits - jnp.max(logits, axis=-1, keepdims=True)
+        lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+        columns = jax.lax.broadcasted_iota(jnp.int32, shifted.shape, 1)
+        hit = columns == target[:, None]
+        nll = lse - jnp.sum(jnp.where(hit, shifted, 0.0), axis=-1)
+        total = total + jnp.sum(nll * weight)
+        if not with_dx:
+            return total, None
+        # d/d logits = (softmax - onehot) * weight, rounded to the head's
+        # type as the transposed product of `lm_loss(_lm_head(x))` rounds
+        # it on the chip (its compiled step: bf16 x bf16, bf16 out).
+        d_logits = (jnp.exp(shifted - lse[:, None]) - hit) * weight[:, None]
+        if out_scale is not None:
+            d_logits = d_logits * out_scale.astype(jnp.float32)
+        dx = jax.lax.dot_general(
+            d_logits.astype(head.dtype), head, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return total, dx.astype(x.dtype)
+
+    return jax.lax.scan(
+        body, jnp.zeros((), jnp.float32), (xs, targets, weights)
+    )
+
+
+@jax.custom_vjp
+def _head_loss(xs, head, out_scale, targets, weights):
+    return _head_loss_chunks(xs, head, out_scale, targets, weights, False)[0]
+
+
+def _head_loss_fwd(xs, head, out_scale, targets, weights):
+    return _head_loss_chunks(xs, head, out_scale, targets, weights, True)
+
+
+def _head_loss_bwd(dxs, g):
+    # The head is frozen: no cotangent but the hidden states'.
+    return g.astype(dxs.dtype) * dxs, None, None, None, None
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def frozen_head_loss(x, head, ids, out_scale=None) -> jax.Array:
+    """``lm_loss(logits[:, :-1], ids[:, 1:])`` of the final-normed hidden
+    states ``x`` [B, T, D] through a FROZEN head [D, V] (``out_scale``
+    [V] on its output, a quantized head's), with no ``[B, T, V]`` array.
+
+    A ``lax.scan`` over chunks of :func:`head_chunk_rows` rows of the
+    flattened ``[B*T, D]``: per chunk the logits (``x``'s and the head's
+    type, float32 accumulation), ``lm_loss``'s arithmetic on them, and
+    in the forward pass of a gradient already ``d loss / d x``, so that
+    the only residual is ``[B*T, D]`` of ``x``'s type, the backward rule
+    scales it, and the head product runs twice a step (logits, input
+    gradient), not a third time.  Per-row results are ``lm_loss``'s; the
+    mean sums in another order.  The head takes NO gradient: a step that
+    trains it stays on :func:`lm_loss`.
+    """
+    b, t, _ = x.shape
+    rows = b * t
+    chunk = head_chunk_rows(rows, head.shape[1])
+    n = -(-rows // chunk)
+    # The last position of each sequence has no target: weight 0, as the
+    # rows that pad the last chunk.
+    targets = jnp.roll(ids, -1, axis=1)
+    weights = jnp.broadcast_to(
+        (jnp.arange(t) < t - 1) / jnp.float32(max(b * (t - 1), 1)), (b, t)
+    )
+
+    def chunks(a):
+        a = a.reshape(rows, *a.shape[2:])
+        a = jnp.pad(a, [(0, n * chunk - rows)] + [(0, 0)] * (a.ndim - 1))
+        return a.reshape(n, chunk, *a.shape[1:])
+
+    return _head_loss(
+        chunks(x), head, out_scale, chunks(targets), chunks(weights)
+    )
+
+
+def lora_loss(lora, base_params, ids, config: LlamaConfig, *,
+              attn_fn: Callable = dot_product_attention) -> jax.Array:
+    """Next-token loss of ``ids`` [B, T] with adapters ``lora`` on the
+    frozen ``base_params``: what the LoRA steps differentiate.  The head
+    is frozen too, so head and loss are fused (:func:`frozen_head_loss`).
+    """
+    c = config
+    x = _hidden_states(base_params, ids, c, lora, attn_fn)
+    kept = {}
+    if c.remat:
+        up = ids.size * c.intermediate_size * jnp.dtype(c.dtype).itemsize
+        kept[f"layers0-{c.num_layers - 1}"] = (c.num_layers, {FFN_UP_NAME: up})
+    emit_remat_saved(kept, ids.size, c.vocab_size)
+    x = _rms_norm(x, base_params["final_norm"], c.rms_eps)
+    head, out_scale = _head_matrix(base_params, c)
+    return frozen_head_loss(x.astype(c.dtype), head, ids, out_scale)
+
+
+def emit_remat_saved(groups, rows: int, vocab: int) -> None:
+    """While the flight recorder is armed, the ``remat.saved`` record of
+    a LoRA step being traced: what its checkpointed layers keep and how
+    its head-and-loss is chunked, from static shapes.  ``groups``:
+    ``{scanned group: (layers, {name: bytes a layer keeps under it})}``
+    for the names the model's own code gives.
+    """
+    if not telemetry.armed():
+        return
+    chunk = head_chunk_rows(rows, vocab)
+    telemetry.emit(
+        "remat.saved",
+        detail={
+            # the policy's names; the flash kernel's two are tagged in
+            # ops.flash_attention (`attn.schedule`'s residual_bytes)
+            "names": list(REMAT_SAVED_NAMES),
+            "layers": {group: n for group, (n, _) in groups.items()},
+            "bytes_per_layer": {
+                group: sizes for group, (_, sizes) in groups.items()
+            },
+            "head_chunk_rows": chunk,
+            "head_chunks": -(-rows // chunk),
+            "logits_bytes_avoided": rows * vocab * 4,
+        },
+    )
+
+
 # Partition rules (stacked layout: dim 0 is the layer axis — never shard).
 PARTITION_RULES = (
     (r"layers/w[qkv]$", P(None, "fsdp", "tp")),
@@ -947,9 +1128,7 @@ def make_lora_train_step(
     delete those buffers out from under the transport.
     """
 
-    def loss_fn(lora, base_params, ids):
-        logits = apply_llama(base_params, ids, config, lora=lora, attn_fn=attn_fn)
-        return lm_loss(logits[:, :-1], ids[:, 1:])
+    loss_fn = functools.partial(lora_loss, config=config, attn_fn=attn_fn)
 
     def step_fn(lora, opt, base_params, ids):
         loss, grads = jax.value_and_grad(loss_fn)(lora, base_params, ids)
@@ -1040,9 +1219,7 @@ def make_lora_train_loop(
     Same one-dispatch rationale as :func:`make_train_loop`.
     """
 
-    def loss_fn(lora, base_params, ids):
-        logits = apply_llama(base_params, ids, config, lora=lora, attn_fn=attn_fn)
-        return lm_loss(logits[:, :-1], ids[:, 1:])
+    loss_fn = functools.partial(lora_loss, config=config, attn_fn=attn_fn)
 
     def run(lora, opt, base_params, ids):
         def body(carry, _):

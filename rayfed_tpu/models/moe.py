@@ -37,6 +37,9 @@ Params = Dict[str, Any]
 
 # The checkpoint name of `route_tokens`'s selection.
 SELECTED_NAME = "moe.selected"
+# The checkpoint names of the gated FFN's two products (`swiglu`): the
+# gate product BEFORE `silu`, and the up product, each with its LoRA term.
+FFN_GATE_NAME, FFN_UP_NAME = "ffn.gate", "ffn.up"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -438,12 +441,18 @@ def _expert_linear(xs, w, group_sizes, row_expert, lora_entry):
 
 def swiglu(x, p, lget, dtype):
     """``(silu(x Wg) * (x Wu)) Wd`` with each matrix's optional LoRA
-    entry from ``lget(name)``."""
+    entry from ``lget(name)``: every model's dense FFN and shared
+    expert.  The two FFN-width products carry checkpoint names
+    (``FFN_GATE_NAME`` before ``silu``, ``FFN_UP_NAME``): what a
+    rematerialized layer's policy names it keeps and does not run again
+    (``llama.REMAT_SAVED``: the up product)."""
     from rayfed_tpu.models.llama import _linear
 
-    gate = jax.nn.silu(_linear(x, p["w_gate"], lget("w_gate"), dtype))
+    gate = _linear(x, p["w_gate"], lget("w_gate"), dtype)
     up = _linear(x, p["w_up"], lget("w_up"), dtype)
-    return _linear(gate * up, p["w_down"], lget("w_down"), dtype)
+    gate = checkpoint_name(gate, FFN_GATE_NAME)
+    up = checkpoint_name(up, FFN_UP_NAME)
+    return _linear(jax.nn.silu(gate) * up, p["w_down"], lget("w_down"), dtype)
 
 
 def _chunk_sizes(static, c, group_sizes):

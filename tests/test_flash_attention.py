@@ -709,6 +709,85 @@ def test_attn_schedule_record_carries_the_residual_bytes():
     assert record.detail["residual_bytes"] == b * t * h * d * 2 + b * h * t * 4
 
 
+def _llama_lora_step():
+    from rayfed_tpu.models import llama, lora
+
+    cfg = llama.llama_tiny(remat=True, dtype=jnp.bfloat16)
+    base = llama.init_llama(jax.random.PRNGKey(0), cfg)
+    adapters = lora.init_lora(jax.random.PRNGKey(1), base, lora.LoraConfig(rank=2))
+    step = llama.make_lora_train_step(cfg, attn_fn=flash_attention)
+    b, t, f = 2, 24, cfg.intermediate_size
+    shape = (b, t, cfg.vocab_size)
+    return step, (adapters, llama.init_adam(adapters), base), shape, {
+        "layers": {"layers0-1": 2},
+        "bytes_per_layer": {"layers0-1": {"ffn.up": b * t * f * 2}},
+    }
+
+
+def _decoder_lora_step():
+    from rayfed_tpu.models import decoder, llama, lora, moe
+
+    experts = moe.ExpertShareConfig(
+        num_experts=8, held=(0, 1, 2, 3), top_k=3, d_model=32, d_ff=16,
+    )
+    cfg = decoder.DecoderConfig(
+        layers=(decoder.LayerSpec("window", "dense"),
+                decoder.LayerSpec("window", "moe"),
+                decoder.LayerSpec("full", "moe")),
+        vocab_size=64, hidden_size=32, num_heads=4, num_kv_heads=2,
+        head_dim=8, intermediate_size=48, sliding_window=8, experts=experts,
+        dtype=jnp.float32, remat=True,
+    )
+    base = decoder.init_decoder(jax.random.PRNGKey(0), cfg)
+    adapters = lora.init_lora(
+        jax.random.PRNGKey(1), base,
+        lora.LoraConfig(rank=2, targets=decoder.ALL_LINEAR),
+    )
+    step = decoder.make_lora_train_step(cfg, attn_fn=flash_attention).jitted
+    b, t = 1, 24
+    return step, (adapters, llama.init_adam(adapters), base), (b, t, 64), {
+        # a dense FFN's up product; a shared expert's, and the selection
+        "layers": {"layers0-0": 1, "layers1-2": 2},
+        "bytes_per_layer": {
+            "layers0-0": {"ffn.up": b * t * 48 * 4},
+            "layers1-2": {
+                "ffn.up": b * t * 16 * 4, "moe.selected": b * t * 3 * 4,
+            },
+        },
+    }
+
+
+@pytest.mark.parametrize("model", ["llama", "decoder"])
+def test_remat_saved_record_when_armed(model, monkeypatch):
+    """Armed while a LoRA step is traced, the flight recorder gets one
+    ``remat.saved`` record: the policy's names, what a layer of each
+    scanned group keeps under those the models give, and how the fused
+    head-and-loss is chunked; disarmed, none."""
+    from rayfed_tpu.models import llama
+
+    make = {"llama": _llama_lora_step, "decoder": _decoder_lora_step}[model]
+    step, args, (b, t, vocab), want = make()
+    # chunks of 16 rows: 48 rows are three, 24 rows two (the last padded)
+    monkeypatch.setattr(llama, "HEAD_CHUNK_BYTES", 4 * 16 * vocab)
+    ids = jnp.zeros((b, t), jnp.int32)
+    step.lower(*args, ids)  # disarmed: nothing recorded, nothing raised
+    rec = telemetry.install(party="alice")
+    try:
+        make()[0].lower(*args, ids)  # a step built anew is traced anew
+        (record,) = [r for r in rec.records() if r.phase == "remat.saved"]
+    finally:
+        telemetry.uninstall()
+    detail = record.detail
+    assert detail["names"] == list(llama.REMAT_SAVED_NAMES)
+    assert {"ffn.up", "flash.out"} <= set(detail["names"])
+    assert "ffn.gate" not in detail["names"]
+    assert detail["head_chunk_rows"] == 16
+    assert detail["head_chunks"] == -(-b * t // 16)
+    assert detail["logits_bytes_avoided"] == b * t * vocab * 4
+    for key, value in want.items():
+        assert detail[key] == value, key
+
+
 # --- a score in parts, and values of a width of their own -----------------
 
 

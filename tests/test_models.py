@@ -255,6 +255,86 @@ def test_llama_lora_train_decreases_loss():
     )
 
 
+# (B, T, rows a chunk): one sequence and several; a row count the chunk
+# divides, one it does not (the last chunk is padded), one chunk for all.
+HEAD_LOSS_CASES = [(1, 32, 8), (3, 16, 8), (3, 20, 16), (2, 9, 64)]
+
+
+@pytest.mark.parametrize("head", ["float", "int8"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("batch,seq,chunk", HEAD_LOSS_CASES)
+def test_frozen_head_loss_is_lm_loss_of_the_head(
+    batch, seq, chunk, dtype, head, monkeypatch
+):
+    """The LoRA steps' fused, chunked head-and-loss against
+    ``lm_loss(_lm_head(x)[:, :-1], ids[:, 1:])``: the value (the mean
+    sums in another order) and ``d/dx`` (its product's operand rounded
+    to the compute dtype, as the chip's transposed product rounds it)."""
+    cfg = llama.llama_tiny(dtype=jnp.dtype(dtype))
+    params = llama.init_llama(jax.random.PRNGKey(0), cfg)
+    if head == "int8":
+        params = llama.quantize_llama_base(params)
+    monkeypatch.setattr(llama, "HEAD_CHUNK_BYTES", 4 * cfg.vocab_size * chunk)
+    rows = batch * seq
+    assert llama.head_chunk_rows(rows, cfg.vocab_size) == min(chunk, rows)
+    ids = jax.random.randint(
+        jax.random.PRNGKey(1), (batch, seq), 0, cfg.vocab_size
+    )
+    x = jax.random.normal(
+        jax.random.PRNGKey(2), (batch, seq, cfg.hidden_size), cfg.dtype
+    )
+
+    def plain(x):
+        logits = llama._lm_head(x, params, cfg)
+        return llama.lm_loss(logits[:, :-1], ids[:, 1:])
+
+    def fused(x):
+        y = llama._rms_norm(x, params["final_norm"], cfg.rms_eps)
+        head, out_scale = llama._head_matrix(params, cfg)
+        return llama.frozen_head_loss(y, head, ids, out_scale)
+
+    want, d_want = jax.value_and_grad(plain)(x)
+    got, d_got = jax.value_and_grad(fused)(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # without a gradient (no d/dx in the chunks) the same number
+    np.testing.assert_allclose(fused(x), got, rtol=1e-6)
+    assert d_got.dtype == d_want.dtype == cfg.dtype
+    d_want, d_got = (np.asarray(d, np.float32) for d in (d_want, d_got))
+    # float32: the order of sums alone; bf16: one rounding (2^-8) of an
+    # operand and one of the result, against the largest entry.
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -6
+    assert np.abs(d_got - d_want).max() <= tol * np.abs(d_want).max()
+
+
+def test_lora_step_runs_the_head_product_twice():
+    """A LoRA step's gradient holds the vocabulary product twice (the
+    logits, and the input gradient the fused loss makes beside them):
+    chunking it under a plain ``jax.checkpoint`` would make it three."""
+    from tool.flash_sweep import _sub_jaxprs
+
+    cfg = llama.llama_tiny(remat=True)
+    params = llama.init_llama(jax.random.PRNGKey(0), cfg)
+    adapters = lora.init_lora(
+        jax.random.PRNGKey(1), params, lora.LoraConfig(rank=2)
+    )
+    ids = jnp.zeros((2, 12), jnp.int32)
+    v = cfg.vocab_size
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in _sub_jaxprs(eqn):
+                yield from walk(sub)
+
+    grad = jax.grad(lambda a: llama.lora_loss(a, params, ids, cfg))
+    eqns = list(walk(jax.make_jaxpr(grad)(adapters).jaxpr))
+    head_products = [
+        e for e in eqns if e.primitive.name == "dot_general"
+        and any(v in x.aval.shape for x in e.invars)
+    ]
+    assert len(head_products) == 2
+
+
 def test_lora_merge_matches_bypass():
     cfg = llama.llama_tiny()
     params = llama.init_llama(jax.random.PRNGKey(0), cfg)

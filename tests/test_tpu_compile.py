@@ -244,15 +244,30 @@ STEP_FLASH_FORWARDS = {
     "mistral-7b-v0.1-d6.lora-2p": 1,
     "trinity-mini-ep8.lora-all-linear-2p": 3,
 }
+# cell -> (the output of a product as wide as its dense FFN, how many
+# the compiled step holds, the vocabulary, XLA's GB a party at the
+# parent of PR 34).  Mistral's scanned layer: gate and up forward, the
+# gate again in the second forward, and `dh` (the up product is saved:
+# five when the second forward ran it too).  Trinity's dense layer 0
+# adapts its FFN, so each is a pair (the base's and the adapter's
+# ``(x a) b``): ten before.
+STEP_FFN_PRODUCTS = {
+    "mistral-7b-v0.1-d6.lora-2p": ("bf16[8192,14336]", 4, 32000, 7.824),
+    "trinity-mini-ep8.lora-all-linear-2p": ("bf16[8192,6144]", 8, 25024, 7.266),
+}
 
 
 @pytest.mark.parametrize("cell", list(STEP_FLASH_FORWARDS))
 def test_step_runs_the_flash_forward_once_and_two_parties_fit(cell, monkeypatch):
     """The benchmark's LoRA step at the cell's shapes: the checkpointed
-    layers save the flash kernel's output and row statistics
-    (``llama.REMAT_SAVED``), so the backward pass' recompute holds no
-    forward kernel, and two parties' steps still fit one chip."""
+    layers save the flash kernel's output and row statistics and the
+    FFN's up product (``llama.REMAT_SAVED``), so the backward pass'
+    recompute holds no forward kernel and one FFN-width product less;
+    the fused head-and-loss leaves no float32 array of tokens x
+    vocabulary; and two parties' steps still fit one chip, in no more
+    memory than before the product was kept."""
     import importlib
+    import re
 
     from rayfed_tpu.models import moe
     from tool.flash_sweep import _step_lowering
@@ -264,13 +279,26 @@ def test_step_runs_the_flash_forward_once_and_two_parties_fit(cell, monkeypatch)
     monkeypatch.setattr(moe, "_grouped_impl", lambda: "megablox")
     _topology()
     compiled = _step_lowering(cell, _on_chip)().compile()
+    text = compiled.as_text()
     forwards = [
-        line for line in compiled.as_text().splitlines()
+        line for line in text.splitlines()
         if "custom-call(" in line and "flash.fwd" in line
     ]
     assert len(forwards) == STEP_FLASH_FORWARDS[cell]
     for line in forwards:  # none in the backward pass or its recompute
         assert "transpose(" not in line and "rematted_computation" not in line
+    ffn_out, ffn_products, vocab, parent_gb = STEP_FFN_PRODUCTS[cell]
+    products = re.findall(
+        r"= (\w+\[[\d,]+\])\S* (?:convolution|dot)\(", text
+    )
+    assert products.count(ffn_out) == ffn_products
+    # the logits are made once, a chunk of 1,024 rows at a time (their
+    # gradient's product is the head's only other)
+    assert products.count(f"f32[1024,{vocab}]") == 1
+    for shape in set(re.findall(r"f32\[([\d,]+)\]", text)):
+        dims = {int(d) for d in shape.split(",")}
+        # the 8,192 tokens (or the 8,191 that have a target) x vocabulary
+        assert not (vocab in dims and dims & {8191, 8192}), shape
     memory = compiled.memory_analysis()
     party = (
         memory.argument_size_in_bytes + memory.temp_size_in_bytes
@@ -281,3 +309,5 @@ def test_step_runs_the_flash_forward_once_and_two_parties_fit(cell, monkeypatch)
     # read 12.59 and 6.62 GB with both parties' steps in flight (PR 32).
     print(f"{cell}: {party / 1e9:.3f} GB a party, {2 * party / 1e9:.3f} two")
     assert 2 * party / 1e9 < 16.9
+    # the logits' memory pays for the saved product
+    assert party / 1e9 <= parent_gb
