@@ -2,20 +2,29 @@
 
 :mod:`rayfed_tpu.models.llama` is one stacked scan of identical layers
 (one attention kind, one FFN, model-wide).  Here every layer has a
-:class:`LayerSpec`: its attention kind (a sliding window with rotary
-positions, full causal attention with no position embedding, or LATENT
-attention: queries and keys/values through low-rank latents with a norm
-of their own, a score of two parts, ``q_nope · k_nope`` a head plus
-``q_pe · k_pe`` against ONE rotary key head all query heads share, and
-values of their own width) and its FFN kind (dense SwiGLU, or routed +
-shared experts through :func:`rayfed_tpu.models.moe.apply_expert_share`).
+:class:`LayerSpec`: its MIXER (a sliding window with rotary positions,
+full causal attention with no position embedding, LATENT attention:
+queries and keys/values through low-rank latents with a norm of their
+own, a score of two parts, ``q_nope · k_nope`` a head plus ``q_pe ·
+k_pe`` against ONE rotary key head all query heads share, and values of
+their own width; or no attention at all but a state-space layer, the
+Mamba-2 mixer of :mod:`rayfed_tpu.models.mamba2`: one input projection
+split three ways, a causal depthwise convolution, the chunked scan of
+:mod:`rayfed_tpu.ops.ssd`, a gated norm, an output projection) and its
+FFN kind (dense SwiGLU, or routed + shared experts through
+:func:`rayfed_tpu.models.moe.apply_expert_share`).
 What the sparse-expert decoders published since 2025 add to the block
 is the configuration's to switch (:class:`DecoderConfig`, on by default
 as the first configuration has them all): an RMS norm of q and k over
 the head width, a sigmoid gate on the attention output, norms after
 each sub-block, a scaled embedding; rotary frequencies may be YaRN's.
+Off by default, the multipliers of the ``granitemoehybrid`` block: one
+on each residual branch, a score scale that is not ``head_dim ** -0.5``,
+one on the logits, and a head tied to the embedding.
 
-Consecutive layers with one FFN kind are a GROUP: their parameters are
+Consecutive layers with one FFN kind and one kind of mixer PARAMETERS
+(window and full attention share theirs; latent attention and a
+state-space layer each have their own) are a GROUP: their parameters are
 stacked on a leading dim and the forward pass is one ``lax.scan`` a
 group (one compiled body however many layers; ``remat`` is
 ``jax.checkpoint`` of that body with ``llama.py``'s policy
@@ -37,11 +46,14 @@ static); the branches' residuals are of one shape and share the
 :func:`rayfed_tpu.models.lora.init_lora` mirrors it with the group's
 index as a string.  :func:`unstack` gives either tree layer by layer.
 
-Two configurations run through it: the AFMoE family's
+Three configurations run through it: the AFMoE family's
 (``benchmark/families/afmoe_lm.py``, reference in
-``benchmark/reference/afmoe.py``) and the ``kimi_k2`` / DeepSeek-V3
+``benchmark/reference/afmoe.py``), the ``kimi_k2`` / DeepSeek-V3
 block (``benchmark/families/kimi_k2_lm.py``, ``reference/kimi_k2.py``),
-all of whose layers are latent.  Helpers are shared with
+all of whose layers are latent, and the ``granitemoehybrid`` block
+(``benchmark/families/granite_hybrid_lm.py``,
+``reference/granite_hybrid.py``): nine state-space layers to one of
+full attention without positions, a dense FFN after each.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
 ``_linear``, ``lm_loss``, ``frozen_head_loss``, ``_adam_update``);
 ``llama.py``'s own programs do not pass through this module.
@@ -60,7 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from rayfed_tpu import telemetry
-from rayfed_tpu.models import moe
+from rayfed_tpu.models import mamba2, moe
 from rayfed_tpu.models.llama import (
     REMAT_SAVED,
     YarnScaling,
@@ -74,29 +86,50 @@ from rayfed_tpu.models.llama import (
     lm_loss,  # noqa: F401  (benchmark/families read `decoder.lm_loss`)
     rope_tables,
 )
+from rayfed_tpu.models.mamba2 import SsmConfig
 from rayfed_tpu.ops.attention import dot_product_attention
 
 Params = Dict[str, Any]
 
 # Every linear matrix of the block but the router (LoraConfig.targets):
-# wq wk wv wo and the output gate wz, or a latent layer's five (wq_a wq_b
-# wkv_a wkv_b wo); the dense FFN's, the shared expert's and each held
-# expert's three.
-ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$", r"/w_(gate|up|down)$")
+# wq wk wv wo and the output gate wz, a latent layer's five (wq_a wq_b
+# wkv_a wkv_b wo), or a state-space layer's two (w_in w_out); the dense
+# FFN's, the shared expert's and each held expert's three.
+ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$", r"/w_(gate|up|down|in|out)$")
+
+# A mixer's kind -> the kind of its PARAMETERS: layers stack into one
+# scanned group only where these agree.
+MIXER_PARAMS = {
+    "window": "attention", "full": "attention", "latent": "latent",
+    "ssm": "ssm",
+}
+FFN_KINDS = ("dense", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     # "window" (with RoPE) | "full" (no positions) | "latent" (RoPE on a
-    # part of the head, DecoderConfig.latent)
-    attention: str = "window"
+    # part of the head, DecoderConfig.latent) | "ssm" (no attention: the
+    # Mamba-2 mixer, DecoderConfig.ssm)
+    mixer: str = "window"
     ffn: str = "dense"  # "dense" | "moe"
 
     def __post_init__(self):
-        if self.attention not in ("window", "full", "latent"):
-            raise ValueError(f"unknown attention kind {self.attention!r}")
-        if self.ffn not in ("dense", "moe"):
-            raise ValueError(f"unknown ffn kind {self.ffn!r}")
+        if self.mixer not in MIXER_PARAMS:
+            raise ValueError(
+                f"unknown mixer kind {self.mixer!r}: one of "
+                f"{', '.join(MIXER_PARAMS)}"
+            )
+        if self.ffn not in FFN_KINDS:
+            raise ValueError(
+                f"unknown ffn kind {self.ffn!r}: one of {', '.join(FFN_KINDS)}"
+            )
+
+    @property
+    def attention(self) -> str:
+        """The mixer under the name the field had while every mixer was
+        attention: read-only, for ``benchmark/families/afmoe_lm.py``."""
+        return self.mixer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,11 +160,17 @@ class DecoderConfig:
     rms_eps: float = 1e-5
     rope_scaling: Optional[YarnScaling] = None
     latent: Optional[LatentConfig] = None  # a "latent" layer's widths
+    ssm: Optional[SsmConfig] = None  # an "ssm" layer's widths
     # The block's optional parts.
     qk_norm: bool = True  # RMS norm of q and k over the head width
     output_gate: bool = True  # attention output * sigmoid(x wz)
     post_norms: bool = True  # a norm on each sub-block's output
     embed_scale: float = 1.0  # the residual stream starts at embed * this
+    residual_scale: float = 1.0  # x + this * sub_block(norm(x)), both
+    # scores times this; None: the score width ** -0.5 (the kernels' own)
+    attn_scale: Optional[float] = None
+    logit_scale: float = 1.0  # logits times this
+    tie_embeddings: bool = False  # the head is the embedding, transposed
     experts: Optional[moe.ExpertShareConfig] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -142,19 +181,24 @@ class DecoderConfig:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if any(s.ffn == "moe" for s in self.layers) and self.experts is None:
             raise ValueError("a layer with ffn='moe' needs config.experts")
-        latent = [s.attention == "latent" for s in self.layers]
-        if any(latent) and self.latent is None:
-            raise ValueError("a layer with attention='latent' needs config.latent")
-        if any(latent) != all(latent):
-            # their parameters differ, so no group could stack them
-            raise ValueError("latent layers do not mix with other kinds")
+        for kind in ("latent", "ssm"):
+            if getattr(self, kind) is None and any(
+                s.mixer == kind for s in self.layers
+            ):
+                raise ValueError(
+                    f"a layer with mixer={kind!r} needs config.{kind}"
+                )
 
     def groups(self) -> Tuple[Tuple[int, int], ...]:
         """``(first layer, one past the last)`` of every run of
-        consecutive layers with one FFN kind."""
+        consecutive layers with one FFN kind and one kind of mixer
+        parameters (``MIXER_PARAMS``): what one scan can stack."""
+        stacks = lambda s: (s.ffn, MIXER_PARAMS[s.mixer])
         out, start = [], 0
         for i in range(1, len(self.layers) + 1):
-            if i == len(self.layers) or self.layers[i].ffn != self.layers[start].ffn:
+            if i == len(self.layers) or stacks(self.layers[i]) != stacks(
+                self.layers[start]
+            ):
                 out.append((start, i))
                 start = i
         return tuple(out)
@@ -163,8 +207,10 @@ class DecoderConfig:
 def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     """Random weights: normal ``fan_in ** -0.5`` (the embedding 0.02, the
     published initializer range, so that the scaled embedding is of the
-    size the sub-blocks' normed outputs add to), norms at one.  Every
-    layer draws from a key of its own; a group's layers are stacked."""
+    size the sub-blocks' normed outputs add to), norms at one; a
+    state-space layer's buffers as :func:`mamba2.init_mixer` draws them.
+    Every layer draws from a key of its own; a group's layers are
+    stacked.  No ``lm_head`` where the head is tied to the embedding."""
     c = config
     d, dh, h, kv = c.hidden_size, c.head_dim, c.num_heads, c.num_kv_heads
     pdt = c.param_dtype
@@ -176,8 +222,11 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     def layer(key, spec: LayerSpec) -> Params:
         ks = jax.random.split(key, 9)
         ones = lambda n: jnp.ones((n,), pdt)
+        # `attn_norm` is the norm before the mixer, whatever the mixer
         lp = {"attn_norm": ones(d), "mlp_norm": ones(d)}
-        if spec.attention == "latent":
+        if spec.mixer == "ssm":
+            lp.update(mamba2.init_mixer(ks[0], d, c.ssm, pdt))
+        elif spec.mixer == "latent":
             m = c.latent
             lp.update(
                 wq_a=dense(ks[0], d, m.q_rank, fan_in=d),
@@ -202,7 +251,7 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
             )
             if c.qk_norm:
                 lp.update(q_norm=ones(dh), k_norm=ones(dh))
-        if c.output_gate:
+        if c.output_gate and spec.mixer != "ssm":
             lp["wz"] = dense(ks[3], d, lp["wo"].shape[0], fan_in=d)
         if c.post_norms:
             lp.update(post_attn_norm=ones(d), post_mlp_norm=ones(d))
@@ -217,7 +266,7 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
 
     keys = jax.random.split(k_layers, len(c.layers))
     stack = lambda *leaves: jnp.stack(leaves)
-    return {
+    params = {
         "embed": (jax.random.normal(k_embed, (c.vocab_size, d)) * 0.02
                   ).astype(pdt),
         "layers": [
@@ -227,8 +276,10 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
             for start, stop in c.groups()
         ],
         "final_norm": jnp.ones((d,), pdt),
-        "lm_head": dense(k_head, d, c.vocab_size, fan_in=d),
     }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense(k_head, d, c.vocab_size, fan_in=d)
+    return params
 
 
 def unstack(tree: Params, config: DecoderConfig) -> Params:
@@ -262,9 +313,14 @@ def embed(params: Params, input_ids: jax.Array, config: DecoderConfig):
 
 
 def _attend(q, k, v, rope, *, kind, config, attn_fn):
+    # a score scale of the configuration's own goes to the kernel; None
+    # leaves it the kernel's default, as every call before there was one
+    scaled = {} if config.attn_scale is None else {
+        "sm_scale": config.attn_scale
+    }
     with jax.named_scope(f"attn.{kind}"):
         if kind == "full":
-            return attn_fn(q, k, v, causal=True)
+            return attn_fn(q, k, v, causal=True, **scaled)
         if kind == "latent":
             # Rotary positions on the rotary part alone; the score's two
             # parts go to the kernel as they are (the shared rotary key
@@ -281,7 +337,8 @@ def _attend(q, k, v, rope, *, kind, config, attn_fn):
                 v, causal=True, sm_scale=scale,
             )
         q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-        return attn_fn(q, k, v, causal=True, window=config.sliding_window)
+        return attn_fn(q, k, v, causal=True, window=config.sliding_window,
+                       **scaled)
 
 
 def _latent_qkv(y, lp, config: DecoderConfig, lget):
@@ -308,18 +365,61 @@ def _latent_qkv(y, lp, config: DecoderConfig, lget):
     )
 
 
-def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
-                attn_fn: Callable = dot_product_attention,
+def _add(x, branch, scale: float):
+    """``x + scale * branch``; a scale that is not 1 in float32 (0.22
+    has no bf16: rounded first it would bias every layer alike)."""
+    if scale == 1.0:
+        return x + branch
+    f32 = jnp.float32
+    return (x.astype(f32) + branch.astype(f32) * scale).astype(x.dtype)
+
+
+def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
+                attention=None, attn_fn: Callable = dot_product_attention,
                 lora: Optional[Params] = None):
     """One layer on the stream ``x`` [B, T, D] -> (``x``, ``aux``).
-    ``lp`` (and ``lora``) are ONE layer's entries; ``attention`` is the
-    layer's kind, or a traced boolean "is windowed" (both kernels are
-    then in the program, under a ``cond``); ``aux`` is what
+    ``lp`` (and ``lora``) are ONE layer's entries; ``mixer``
+    (or ``attention=``, its older name: one of the two) is the layer's kind,
+    or a traced boolean "is windowed" (both kernels are then in the
+    program, under a ``cond``); ``aux`` is what
     :func:`moe.apply_expert_share` reports, None for a dense FFN."""
+    c = config
+    lget = (lora or {}).get
+    if (mixer is None) == (attention is None):
+        raise TypeError("apply_block takes mixer= (or attention=, not both)")
+    kind = attention if mixer is None else mixer
+    if isinstance(kind, str) and kind == "ssm":
+        with jax.named_scope("ssm.proj"):
+            y = _rms_norm(x, lp["attn_norm"], c.rms_eps)
+        o = mamba2.apply_mixer(y, lp, c.ssm, lget, c.dtype, c.rms_eps)
+        with jax.named_scope("ssm.proj"):
+            if c.post_norms:
+                o = _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
+            x = _add(x, o, c.residual_scale)
+    else:
+        x = _attention_block(x, lp, c, kind, attn_fn, lget)
+    b, t, _ = x.shape
+    y = _rms_norm(x, lp["mlp_norm"], c.rms_eps)
+    aux = None
+    if ffn == "dense":
+        with jax.named_scope("ffn.dense"):
+            f = moe.swiglu(y, lp, lget, c.dtype)
+    else:
+        f, aux = moe.apply_expert_share(
+            lp["moe"], y.reshape(b * t, -1), c.experts, lora=lget("moe"),
+        )
+        f = f.reshape(b, t, -1)
+    if c.post_norms:
+        f = _rms_norm(f, lp["post_mlp_norm"], c.rms_eps)
+    return _add(x, f, c.residual_scale), aux
+
+
+def _attention_block(x, lp, config: DecoderConfig, attention, attn_fn, lget):
+    """``x`` plus an attention layer's first sub-block: norm,
+    projections, the kernel of its kind, gate, output projection."""
     c = config
     b, t, _ = x.shape
     h, kv, dh, dtype = c.num_heads, c.num_kv_heads, c.head_dim, c.dtype
-    lget = (lora or {}).get
     latent = isinstance(attention, str) and attention == "latent"
     rope = rope_tables(
         jnp.arange(t), c.latent.rope_dim if latent else dh, c.rope_theta,
@@ -352,21 +452,7 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, attention,
         o = _linear(o, lp["wo"], lget("wo"), dtype)
         if c.post_norms:
             o = _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
-        x = x + o
-
-    y = _rms_norm(x, lp["mlp_norm"], c.rms_eps)
-    aux = None
-    if ffn == "dense":
-        with jax.named_scope("ffn.dense"):
-            f = moe.swiglu(y, lp, lget, dtype)
-    else:
-        f, aux = moe.apply_expert_share(
-            lp["moe"], y.reshape(b * t, -1), c.experts, lora=lget("moe"),
-        )
-        f = f.reshape(b, t, -1)
-    if c.post_norms:
-        f = _rms_norm(f, lp["post_mlp_norm"], c.rms_eps)
-    return x + f, aux
+        return _add(x, o, c.residual_scale)
 
 
 def _split_scalars(tree):
@@ -393,8 +479,11 @@ def apply_decoder(
     *,
     lora: Optional[Params] = None,
     attn_fn: Callable = dot_product_attention,
+    last: Optional[int] = None,
 ):
-    """``[B, T]`` ids -> (``[B, T, V]`` float32 logits, ``aux``).
+    """``[B, T]`` ids -> (``[B, T, V]`` float32 logits, ``aux``); with
+    ``last`` the logits of the last ``last`` positions alone (a
+    vocabulary of 100,352 makes all 8,192 positions' 3.3 GB).
 
     ``aux`` maps each expert layer's index to what
     :func:`moe.apply_expert_share` reports (``counts``,
@@ -404,11 +493,25 @@ def apply_decoder(
     """
     c = config
     x, aux = _hidden_states(params, input_ids, c, lora, attn_fn)
+    if last is not None:
+        x = x[:, -last:]
+    head, rows = _head(params, c)
     logits = jax.lax.dot_general(
-        x, params["lm_head"].astype(c.dtype),
-        (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        x, head, (((2,), (rows,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
+    if c.logit_scale != 1.0:
+        logits = logits * c.logit_scale
     return logits, aux
+
+
+def _head(params, config: DecoderConfig):
+    """``(matrix, the dim of it that is the model width)``: ``lm_head``
+    [D, V], or the tied embedding [V, D] as it lies (no transposed
+    copy)."""
+    if config.tie_embeddings:
+        return params["embed"].astype(config.dtype), 1
+    return params["lm_head"].astype(config.dtype), 0
 
 
 def _hidden_states(params, input_ids, config, lora, attn_fn):
@@ -420,20 +523,20 @@ def _hidden_states(params, input_ids, config, lora, attn_fn):
     aux = {}
     for g, (start, stop) in enumerate(c.groups()):
         specs = c.layers[start:stop]
-        kinds = {s.attention for s in specs}
+        kinds = {s.mixer for s in specs}
         adapters, rebuild = _split_scalars(lora_groups.get(str(g)))
 
         def body(x, scanned, specs=specs, kinds=kinds, rebuild=rebuild):
             lp, adapters, windowed = scanned
             return apply_block(
                 x, lp, c, ffn=specs[0].ffn, attn_fn=attn_fn,
-                attention=specs[0].attention if len(kinds) == 1 else windowed,
+                mixer=specs[0].mixer if len(kinds) == 1 else windowed,
                 lora=rebuild(adapters),
             )
 
         if c.remat:
             body = checkpoint_layer(body, REMAT_SAVED, stop - start)
-        windowed = jnp.asarray([s.attention == "window" for s in specs])
+        windowed = jnp.asarray([s.mixer == "window" for s in specs])
         with jax.named_scope(f"layers{start}-{stop - 1}"):
             x, stacked = jax.lax.scan(
                 body, x, (params["layers"][g], adapters, windowed),
@@ -485,8 +588,11 @@ def lora_loss(lora, base, ids, config: DecoderConfig, *,
     emit_remat_saved(
         _kept_by_group(config, ids.size), ids.size, config.vocab_size
     )
-    head = base["lm_head"].astype(config.dtype)
-    return frozen_head_loss(x, head, ids), aux
+    head, rows = _head(base, config)
+    scale = None if config.logit_scale == 1.0 else jnp.float32(
+        config.logit_scale
+    )
+    return frozen_head_loss(x, head, ids, scale, head_rows=bool(rows)), aux
 
 
 def make_lora_train_step(

@@ -923,15 +923,18 @@ def head_chunk_rows(rows: int, vocab: int) -> int:
     return min(1 << (fit.bit_length() - 1), rows)
 
 
-def _head_loss_chunks(xs, head, out_scale, targets, weights, with_dx):
+def _head_loss_chunks(head_rows, xs, head, out_scale, targets, weights,
+                      with_dx):
     """Scan the chunks: ``sum(weights * nll)``, and with ``with_dx`` its
     gradient by ``xs``, chunk by chunk.  ``xs`` [n, rows, D], ``targets``
-    and ``weights`` [n, rows]."""
+    and ``weights`` [n, rows]; ``head`` [D, V], or with ``head_rows``
+    [V, D] (a tied embedding as it lies)."""
+    wide, tall = (1, 0) if head_rows else (0, 1)
 
     def body(total, chunk):
         x, target, weight = chunk
         logits = jax.lax.dot_general(
-            x, head, (((1,), (0,)), ((), ())),
+            x, head, (((1,), (wide,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if out_scale is not None:
@@ -952,7 +955,7 @@ def _head_loss_chunks(xs, head, out_scale, targets, weights, with_dx):
         if out_scale is not None:
             d_logits = d_logits * out_scale.astype(jnp.float32)
         dx = jax.lax.dot_general(
-            d_logits.astype(head.dtype), head, (((1,), (1,)), ((), ())),
+            d_logits.astype(head.dtype), head, (((1,), (tall,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return total, dx.astype(x.dtype)
@@ -962,16 +965,20 @@ def _head_loss_chunks(xs, head, out_scale, targets, weights, with_dx):
     )
 
 
-@jax.custom_vjp
-def _head_loss(xs, head, out_scale, targets, weights):
-    return _head_loss_chunks(xs, head, out_scale, targets, weights, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _head_loss(head_rows, xs, head, out_scale, targets, weights):
+    return _head_loss_chunks(
+        head_rows, xs, head, out_scale, targets, weights, False
+    )[0]
 
 
-def _head_loss_fwd(xs, head, out_scale, targets, weights):
-    return _head_loss_chunks(xs, head, out_scale, targets, weights, True)
+def _head_loss_fwd(head_rows, xs, head, out_scale, targets, weights):
+    return _head_loss_chunks(
+        head_rows, xs, head, out_scale, targets, weights, True
+    )
 
 
-def _head_loss_bwd(dxs, g):
+def _head_loss_bwd(head_rows, dxs, g):
     # The head is frozen: no cotangent but the hidden states'.
     return g.astype(dxs.dtype) * dxs, None, None, None, None
 
@@ -979,10 +986,13 @@ def _head_loss_bwd(dxs, g):
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
-def frozen_head_loss(x, head, ids, out_scale=None) -> jax.Array:
+def frozen_head_loss(x, head, ids, out_scale=None, *,
+                     head_rows: bool = False) -> jax.Array:
     """``lm_loss(logits[:, :-1], ids[:, 1:])`` of the final-normed hidden
     states ``x`` [B, T, D] through a FROZEN head [D, V] (``out_scale``
-    [V] on its output, a quantized head's), with no ``[B, T, V]`` array.
+    [V] or a scalar on its output: a quantized head's, a model's logit
+    multiplier), with no ``[B, T, V]`` array.  ``head_rows``: the head
+    is [V, D], an embedding the model ties its head to, read as it lies.
 
     A ``lax.scan`` over chunks of :func:`head_chunk_rows` rows of the
     flattened ``[B*T, D]``: per chunk the logits (``x``'s and the head's
@@ -996,7 +1006,7 @@ def frozen_head_loss(x, head, ids, out_scale=None) -> jax.Array:
     """
     b, t, _ = x.shape
     rows = b * t
-    chunk = head_chunk_rows(rows, head.shape[1])
+    chunk = head_chunk_rows(rows, head.shape[0 if head_rows else 1])
     n = -(-rows // chunk)
     # The last position of each sequence has no target: weight 0, as the
     # rows that pad the last chunk.
@@ -1011,7 +1021,8 @@ def frozen_head_loss(x, head, ids, out_scale=None) -> jax.Array:
         return a.reshape(n, chunk, *a.shape[1:])
 
     return _head_loss(
-        chunks(x), head, out_scale, chunks(targets), chunks(weights)
+        head_rows, chunks(x), head, out_scale, chunks(targets),
+        chunks(weights),
     )
 
 

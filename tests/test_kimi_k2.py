@@ -113,10 +113,14 @@ def test_a_latent_layer_has_its_five_matrices_and_two_latent_norms():
     assert not {"wq", "wz", "q_norm", "post_attn_norm", "post_mlp_norm"} & set(lp)
     got = sorted(adapters["layers"]["1"])
     assert got == ["moe", "wkv_a", "wkv_b", "wo", "wq_a", "wq_b"]
-    with pytest.raises(ValueError, match="do not mix"):
-        dataclasses.replace(
-            cfg, layers=SPECS + (decoder.LayerSpec("full", "moe"),)
-        )
+    # a layer of another kind has parameters of its own: a group of its own
+    # (the refusal to mix latent layers with others became this rule, PR 35)
+    mixed = dataclasses.replace(
+        cfg, layers=SPECS + (decoder.LayerSpec("full", "moe"),)
+    )
+    assert mixed.groups() == ((0, 1), (1, 3), (3, 4))
+    with pytest.raises(ValueError, match="needs config.latent"):
+        dataclasses.replace(cfg, latent=None)
 
 
 def test_adapters_at_the_published_shapes_count_what_the_cell_states():

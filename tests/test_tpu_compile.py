@@ -311,3 +311,60 @@ def test_step_runs_the_flash_forward_once_and_two_parties_fit(cell, monkeypatch)
     assert 2 * party / 1e9 < 16.9
     # the logits' memory pays for the saved product
     assert party / 1e9 <= parent_gb
+
+
+def test_state_space_hybrid_step_compiles_and_one_step_fits_beside_two_parties(
+    monkeypatch,
+):
+    """The fourth configuration's cell (18 Mamba-2 layers, 2 attention
+    layers without positions, a head tied to 100,352 rows of embedding)
+    at its shapes: the chunked scan (``ops/ssd.py``), the convolution
+    and both projections are in the program under their scopes, the two
+    attention groups run ONE forward kernel each at 32 x 64-wide heads
+    on 8 K/V heads, nothing of the embedding's size is copied for the
+    tied head, and what is resident (ONE copy of the base, two parties'
+    adapters, Adam state and ids) plus ONE running step's temporaries
+    fit the chip by XLA's count.  Whether two steps' temporaries are
+    ever whole at once the compiler cannot say: their sum is printed,
+    and the chip's runs are PERF.md's."""
+    import importlib
+    import re
+
+    from tool.flash_sweep import _step_lowering
+
+    flash = importlib.import_module("rayfed_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)
+    _topology()
+    cell = "granite-4.0-h-micro-d20.lora-all-linear-2p"
+    compiled = _step_lowering(cell, _on_chip)().compile()
+    text = compiled.as_text()
+    for scope in ("ssm.proj", "ssm.conv", "ssm.scan", "attn.full",
+                  "attn.proj", "ffn.dense"):
+        assert scope in text, scope
+    forwards = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "flash.fwd" in line
+    ]
+    assert len(forwards) == 2
+    assert not re.search(
+        r"= bf16\[(100352,2048|2048,100352)\]\S* (copy|transpose)\(", text
+    )
+    products = re.findall(r"= (\w+\[[\d,]+\])\S* (?:convolution|dot)\(", text)
+    # the FFN's width: gate and up forward, the gate again in the second
+    # forward, `dh`, each with its adapter's, in each of five scanned
+    # bodies; the up product is kept and not run again
+    assert products.count("bf16[8192,8192]") == 5 * 8
+    assert products.count("f32[256,100352]") == 1  # the logits, a chunk
+    memory = compiled.memory_analysis()
+    base = 1_698_459_520 * 2 - 18 * 3 * 64 * 2  # bf16, the scan's buffers float32
+    assert abs(base / 1e9 - 3.397) < 0.001
+    # a party's own arguments (adapters, Adam state, ids) and outputs
+    held = memory.argument_size_in_bytes - base + memory.output_size_in_bytes
+    temp = memory.temp_size_in_bytes
+    print(f"{cell}: base {base / 1e9:.3f} GB, a party holds {held / 1e9:.3f} "
+          f"GB, a running step's temporaries {temp / 1e9:.3f} GB; resident "
+          f"and ONE step {(base + 2 * held + temp) / 1e9:.3f} GB, and TWO "
+          f"{(base + 2 * held + 2 * temp) / 1e9:.3f} GB")
+    assert (base + 2 * held + temp) / 1e9 < 16.9
+    # all heads of a scan at once (no loop over blocks of them): 7.35 GB
+    assert temp / 1e9 < 7.5

@@ -335,6 +335,7 @@ STEP_CELLS = (
     "mistral-7b-v0.1-d6.qlora-wire-uint8",
     "trinity-mini-ep8.lora-all-linear-2p",
     "kimi-k2.7-code-ep32.lora-all-linear-2p",
+    "granite-4.0-h-micro-d20.lora-all-linear-2p",
 )
 TRACE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
