@@ -26,6 +26,7 @@ from rayfed_tpu import telemetry
 from rayfed_tpu.models import decoder, llama, lora, mamba2
 from rayfed_tpu.ops.attention import dot_product_attention
 from rayfed_tpu.ops.flash_attention import flash_attention
+from rayfed_tpu.ops import ssd
 from rayfed_tpu.ops.ssd import scan_bytes, scan_flops, ssd_scan
 from tests.test_kimi_k2 import _trained, rel_rms
 
@@ -255,9 +256,193 @@ def test_scan_refuses_shapes_that_do_not_pair_up():
         ssd_scan(x, dt[:, :-1], a, b, c, d, chunk=8)
 
 
+def _gradients(scan, args, w):
+    return jax.grad(
+        lambda *a: jnp.sum(scan(*a).astype(jnp.float32) * w), argnums=range(6)
+    )(*args)
+
+
+SCAN_CASES = {
+    # (inputs, chunk, operand type, limit against the float32 recurrence)
+    "two_groups_four_blocks_each": (
+        dict(b=1, t=24, h=8, p=8, g=2, n=16), 8, jnp.float32, 1e-5),
+    "two_groups_two_heads_a_block": (
+        dict(b=1, t=24, h=8, p=8, g=2, n=16), 8, jnp.float32, 1e-5),
+    "ragged_length_bf16_operands": (
+        dict(b=1, t=37, h=4, p=8, g=1, n=16), 8, jnp.bfloat16, 0.02),
+    "batch_of_three": (dict(b=3, t=16, h=4, p=8, g=2, n=16), 8, jnp.float32, 1e-5),
+}
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_the_kernels_take_groups_blocks_batches_and_ragged_lengths(
+    case, monkeypatch
+):
+    """Forward and all six gradients against the token-by-token
+    recurrence in float32: a group's heads over several grid steps (``dB``
+    and ``dC`` summed over them in VMEM), two heads sharing a block's
+    lanes, a length that is no multiple of the chunk with bf16 operands
+    (the chip test's limit), a batch."""
+    shape, chunk, dtype, tol = SCAN_CASES[case]
+    if case == "two_groups_four_blocks_each":
+        monkeypatch.setattr(ssd, "VMEM_BUDGET_BYTES", 0)  # the smallest
+    elif case == "two_groups_two_heads_a_block":
+        monkeypatch.setattr(
+            ssd, "VMEM_BUDGET_BYTES", ssd.step_vmem_bytes(2, 8, 16, 8, 4)
+        )
+    args, w = _scan_inputs(**shape)
+    h, g = shape["h"], shape["g"]
+    block = ssd.head_block(h, g, 8, 16, chunk, jnp.dtype(dtype).itemsize, True)
+    assert block == {"two_groups_four_blocks_each": 1,
+                     "two_groups_two_heads_a_block": 2}.get(case, h // g)
+    cast = lambda x, dt, a, b, c, d: (
+        x.astype(dtype), dt, a, b.astype(dtype), c.astype(dtype), d
+    )
+    scan = lambda *a: ssd_scan(*cast(*a), chunk=chunk)
+    assert rel_rms(scan(*args), _recurrence(*args)) < tol
+    got, want = _gradients(scan, args, w), _gradients(_recurrence, args, w)
+    for name, g_, r in zip("x dt A B C D".split(), got, want):
+        assert float(jnp.abs(r).max()) > 0, name
+        assert rel_rms(g_, r) < tol, name
+
+
+def _scan_kernels(jaxpr):
+    """Names of the kernels of every ``pallas_call`` a jaxpr holds."""
+    from tool.flash_sweep import _sub_jaxprs
+
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["jaxpr"].debug_info.func_name)
+        for sub in _sub_jaxprs(eqn):
+            names.extend(_scan_kernels(sub))
+    return names
+
+
+def test_a_checkpointed_caller_runs_forward_twice_and_backward_once():
+    """Under a layer's ``jax.checkpoint`` the gradient's program holds
+    the forward kernel twice (the primal, and again for the carried
+    states) and the backward kernel once, and the gradients are those
+    of the plain call to the bit."""
+    args, w = _scan_inputs()
+    scan = lambda *a: ssd_scan(*a, chunk=8)
+    grad = lambda f: jax.value_and_grad(
+        lambda *a: jnp.sum(f(*a) * w), argnums=range(6)
+    )
+    kernels = _scan_kernels(
+        jax.make_jaxpr(grad(jax.checkpoint(scan)))(*args).jaxpr
+    )
+    assert sorted(kernels) == ["_bwd_kernel", "_fwd_kernel", "_fwd_kernel"]
+    (loss, got), (plain_loss, want) = (
+        grad(jax.checkpoint(scan))(*args), grad(scan)(*args)
+    )
+    assert float(loss) == float(plain_loss)
+    for g_, r in zip(got, want):
+        np.testing.assert_array_equal(g_, r)
+
+
+# Equations in the forward and backward kernels' jaxprs at the cell's
+# shape (`tool/ssd_sweep.py --lowering`: 104 and 233, 4 and 11 matrix
+# products), with a tenth of room: the head loop is ONE `fori_loop` body
+# however many heads a block holds (unrolled only when Mosaic lowers
+# it), and a head of the block adds two equations, its column of `l`.
+# What a kernel costs to trace is `setup_s` (ledger, PR 29: 1,779
+# equations in three kernels cost two cells their bound; the three flash
+# kernels hold 601).
+SCAN_MAX_EQUATIONS = (115, 256)
+SCAN_PRODUCTS = (4, 11)
+# `ssd.head_block` at the cell's shape, chosen by the budget
+# (`tool/ssd_sweep.py`, PERF.md section 6)
+HEAD_BLOCK_AT_THE_CELL = 16
+
+
+def test_scan_kernel_programs_stay_within_the_size_budget(monkeypatch):
+    from tool.flash_sweep import kernel_counts
+    from tool.ssd_sweep import CELL, scan_grad
+
+    monkeypatch.setattr(ssd._flash, "_interpret_default", lambda: False)
+    grad, args = scan_grad(CELL)
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+    fwd, bwd = kernel_counts(jax.make_jaxpr(grad)(*shapes).jaxpr)[-2:]
+    for (equations, products), most, dots in zip(
+        (fwd, bwd), SCAN_MAX_EQUATIONS, SCAN_PRODUCTS
+    ):
+        assert equations <= most
+        assert products == dots
+    # the same program at half the block (8 heads: the smallest that
+    # tiles), but for eight columns of `l`
+    monkeypatch.setattr(ssd, "VMEM_BUDGET_BYTES", 0)
+    grad, _ = scan_grad(CELL)  # traced anew
+    small = kernel_counts(jax.make_jaxpr(grad)(*shapes).jaxpr)[-2:]
+    assert small == [(n - 2 * 8, dots) for n, dots in (fwd, bwd)]
+
+
+def test_a_process_traces_each_scan_kernel_once(monkeypatch):
+    """The wrappers of the two ``pallas_call``s are jitted on their
+    static arguments: a second program that holds the same call (another
+    party's step, another scanned group, the forward a checkpoint runs
+    again) finds the kernels' jaxprs; another chunk does not.  Under a
+    ``jax.checkpoint`` jax 0.9 traces the forward wrapper in two
+    contexts (the checkpoint's own trace has no abstract mesh, its
+    JVP's an empty one), so a process traces the forward kernel at most
+    twice, however many programs and groups hold it."""
+    traced = []
+    for name in ("_fwd_kernel", "_bwd_kernel"):
+        def counting(*a, _kernel=getattr(ssd, name), _name=name, **kw):
+            traced.append(_name)
+            return _kernel(*a, **kw)
+
+        monkeypatch.setattr(ssd, name, counting)
+    args, w = _scan_inputs()
+
+    def program(chunk, layer=lambda f: f):
+        scan = layer(lambda *a: ssd_scan(*a, chunk=chunk))
+        return jax.jit(jax.grad(
+            # two calls, as two scanned groups of one step
+            lambda *a: jnp.sum((scan(*a) + scan(*a)) * w), argnums=range(6)
+        ))
+
+    jax.clear_caches()
+    first = program(8)(*args)
+    # once each, though a gradient traces the forward twice (the primal
+    # function, then the rule that keeps the carried states)
+    assert sorted(traced) == ["_bwd_kernel", "_fwd_kernel"]
+    again = program(8)(*args)  # a new program, the same kernels
+    assert len(traced) == 2
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    program(8, jax.checkpoint)(*args)
+    assert traced.count("_bwd_kernel") == 1 and len(traced) <= 3
+    held = len(traced)
+    program(8, jax.checkpoint)(*args)
+    assert len(traced) == held
+    program(16)(*args)
+    assert len(traced) == held + 2
+    jax.clear_caches()  # the counting kernels leave with the test
+
+
+@pytest.mark.parametrize("shape, chunk, match", [
+    (dict(t=256, h=8, p=64, g=1, n=128), 100, "multiple of 128"),  # chunk
+    (dict(t=256, h=8, p=20, g=1, n=128), 128, "multiple of 8"),  # head width
+    (dict(t=256, h=8, p=64, g=2, n=64), 128, "groups > 1"),  # state's lanes
+    (dict(t=256, h=6, p=32, g=2, n=128), 128, "128-lane tiles"),  # 3 x 32
+])
+def test_compiled_scan_refuses_shapes_that_do_not_tile(
+    shape, chunk, match, monkeypatch
+):
+    """Interpret mode takes any shape; compiled, a shape the kernels'
+    blocks cannot tile is refused before anything is lowered."""
+    monkeypatch.setattr(ssd._flash, "_interpret_default", lambda: False)
+    args, _ = _scan_inputs(b=1, **shape)
+    with pytest.raises(ValueError, match=match):
+        jax.eval_shape(lambda *a: ssd_scan(*a, chunk=chunk), *args)
+
+
 def test_ssm_scan_record_when_armed():
     """One ``ssm.scan`` record a call traced while the recorder is
-    armed, from static arguments alone; none disarmed."""
+    armed, from static arguments alone; none disarmed.  It says how the
+    kernels are laid out: the head block, the grid, a grid step's VMEM
+    and what the backward pass is handed."""
     (x, dt, a, b, c, d), _ = _scan_inputs()
     jax.make_jaxpr(lambda *v: ssd_scan(*v, chunk=8))(x, dt, a, b, c, d)
     rec = telemetry.install(capacity=64)
@@ -269,14 +454,24 @@ def test_ssm_scan_record_when_armed():
     finally:
         telemetry.uninstall()
     detail = record.detail
+    states = 2 * 5 * 4 * 8 * 16 * 4
     assert detail == dict(
         batch=2, tokens=37, chunk=8, chunks=5, heads=4, head_dim=8, state=16,
         groups=2,
         flops_forward=scan_flops(2 * 37, 4, 8, 16, 2, 8),
         bytes_forward=scan_bytes(2, 37, 4, 8, 16, 2, 2),
-        state_bytes=2 * 5 * 4 * 8 * 16 * 4,
-        working_set_bytes=2 * 5 * 4 * 8 * 8 * 4,
+        state_bytes=states,
+        # nothing of chunk x chunk a head is left in HBM: the carried
+        # states are the largest array a call makes
+        working_set_bytes=states,
+        head_block=2, grid=(2, 5, 2),
+        vmem_bytes=ssd.step_vmem_bytes(2, 8, 16, 8, 2) + 4 * 8 * 16 * 4,
+        residual_bytes=states + 2 * 4 * 8 * 40 * 4,
     )
+    # at the cell's shapes: 16 heads a grid step, 67 MB of carried states
+    # and 16.8 MB of token rows kept for the backward pass
+    assert ssd.head_block(64, 1, 64, 128, 256, 2, False) == HEAD_BLOCK_AT_THE_CELL
+    assert 8192 // 256 * 64 * 64 * 128 * 4 == 67_108_864
     # the count of ISSUE 35 at the published shapes: 3.18 MFLOP a token
     # forward (1.05 + 1.05 + 1.05 + 0.03), 140.5 MB a call in and out
     assert scan_flops(1, 64, 64, 128, 1, 256) == pytest.approx(
