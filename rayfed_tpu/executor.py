@@ -195,11 +195,23 @@ def _resolve_args(
 
 def _run_body(fn: Callable, args: tuple, kwargs: dict, name: str) -> Any:
     """The task's own time (``task.run``): party compute, for a
-    trainer.  Spans the body opens on this thread name it as parent."""
+    trainer.  Spans the body opens on this thread name it as parent.
+    Armed, the task's end also reads the party's devices' memory
+    (``device.memory``: what the chip held under the task's programs)."""
     with telemetry.span("task.run") as sp:
-        if sp is not None:
-            sp.detail = {"name": name}
-        return fn(*args, **kwargs)
+        if sp is None:
+            return fn(*args, **kwargs)
+        sp.detail = {"name": name}
+        out = fn(*args, **kwargs)
+        from rayfed_tpu.runtime import get_runtime_or_none  # imports this module
+
+        runtime = get_runtime_or_none()
+        if runtime is not None:
+            telemetry.emit_device_memory(
+                runtime.local_devices(), name=name, party=sp.party,
+                round=sp.round,
+            )
+        return out
 
 
 class TaskExecutor:

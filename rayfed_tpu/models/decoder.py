@@ -55,7 +55,8 @@ all of whose layers are latent, and the ``granitemoehybrid`` block
 ``reference/granite_hybrid.py``): nine state-space layers to one of
 full attention without positions, a dense FFN after each.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
-``_linear``, ``lm_loss``, ``frozen_head_loss``, ``_adam_update``);
+``_linear``, ``lm_loss``, ``frozen_head_loss``, ``adam_part``,
+``embed_part``);
 ``llama.py``'s own programs do not pass through this module.
 """
 
@@ -74,13 +75,15 @@ import jax.numpy as jnp
 from rayfed_tpu import telemetry
 from rayfed_tpu.models import mamba2, moe
 from rayfed_tpu.models.llama import (
+    HEAD_LOSS_SCOPE,
     REMAT_SAVED,
     YarnScaling,
-    _adam_update,
     _linear,
     _rms_norm,
+    adam_part,
     apply_rope,
     checkpoint_layer,
+    embed_part,
     emit_remat_saved,
     frozen_head_loss,
     lm_loss,  # noqa: F401  (benchmark/families read `decoder.lm_loss`)
@@ -306,9 +309,9 @@ def unstack(tree: Params, config: DecoderConfig) -> Params:
 
 def embed(params: Params, input_ids: jax.Array, config: DecoderConfig):
     """``[B, T]`` ids -> the residual stream before layer 0."""
-    x = params["embed"].astype(config.dtype)[input_ids]
-    return (x * jnp.asarray(config.embed_scale, config.dtype)).astype(
-        config.dtype
+    return embed_part(
+        params["embed"], input_ids, dtype=jnp.dtype(config.dtype),
+        scale=float(config.embed_scale),
     )
 
 
@@ -544,7 +547,8 @@ def _hidden_states(params, input_ids, config, lora, attn_fn):
         if stacked is not None:
             for i in range(start, stop):
                 aux[i] = jax.tree_util.tree_map(lambda a: a[i - start], stacked)
-    x = _rms_norm(x, params["final_norm"], c.rms_eps)
+    with jax.named_scope(HEAD_LOSS_SCOPE):
+        x = _rms_norm(x, params["final_norm"], c.rms_eps)
     return x.astype(c.dtype), aux
 
 
@@ -617,6 +621,8 @@ def make_lora_train_step(
     it waits for those steps: call it where the host waits anyway (the
     end of a round).  Disarmed, nothing is kept or fetched."""
 
+    adam = adam_part(lr, b1, b2, eps)
+
     def loss_fn(lora, base, ids):
         loss, aux = lora_loss(lora, base, ids, config, attn_fn=attn_fn)
         return loss, routing_counts(aux)
@@ -625,7 +631,7 @@ def make_lora_train_step(
         (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             lora, base, ids
         )
-        lora, opt = _adam_update(lora, grads, opt, lr, b1, b2, eps)
+        lora, opt = adam(lora, grads, opt)
         return lora, opt, loss, counts
 
     jitted = jax.jit(decoder_lora_step)

@@ -52,6 +52,55 @@ REMAT_SAVED_NAMES = (SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME)
 REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
 
 
+# The scope vocabulary of a training step outside its layers (inside a
+# layer: ``attn.proj``, ``attn.window`` / ``attn.full``, ``ffn.dense``
+# here; ``decoder.py``, ``moe.py`` and ``mamba2.py`` give theirs): what a
+# device trace's reader charges an operation to, from the ``op_name`` of
+# its instruction in the compiled step's text.  The pass needs no scope:
+# JAX writes ``rematted_computation`` into the ``op_name`` of what a
+# checkpointed layer's second forward runs and ``transpose(`` into the
+# backward pass's.
+EMBED_SCOPE, HEAD_LOSS_SCOPE, ADAM_SCOPE = "embed", "head.loss", "optim.adam"
+
+
+def step_part(scope: str, fn: Callable, **jit_kw) -> Callable:
+    """``fn`` as a named part of a jitted step: under
+    ``jax.named_scope(scope)`` AND as an inner ``jax.jit`` (``jit_kw``
+    its keywords) whose symbol is the scope's name (``optim.adam`` ->
+    ``optim_adam``).
+
+    The scope alone is metadata, and the persistent compile cache keys a
+    program on its text WITHOUT locations (``jax/_src/cache_key.py``
+    strips debug info): a step that differs from an older build's by
+    scopes alone loads the older executable, whose text and trace carry
+    the older ``op_name``s.  A private function's symbol the key keeps
+    and the compiler discards (XLA inlines the call: the compiled step
+    has the same instructions), so a step built from these parts never
+    hashes to a step built without them."""
+
+    def part(*args, **kw):
+        with jax.named_scope(scope):
+            return fn(*args, **kw)
+
+    part.__name__ = part.__qualname__ = scope.replace(".", "_")
+    return jax.jit(part, **jit_kw)
+
+
+def _embed_lookup(table, ids, *, dtype, scale=None):
+    """Rows ``ids`` of ``table`` in ``dtype``, times a model's embedding
+    multiplier where it has one."""
+    x = table.astype(dtype)[ids]
+    if scale is None:
+        return x
+    return (x * jnp.asarray(scale, dtype)).astype(dtype)
+
+
+# One jitted object for every caller: an eager forward finds it compiled.
+embed_part = step_part(
+    EMBED_SCOPE, _embed_lookup, static_argnames=("dtype", "scale")
+)
+
+
 def checkpoint_layer(body, policy, layers: int):
     """``jax.checkpoint`` of a layer body that ``lax.scan`` runs
     ``layers`` times.  Inside a loop the barrier ``prevent_cse`` puts on
@@ -432,19 +481,24 @@ def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
     head, dense attention contracts over the group, ring and Ulysses
     repeat inside their wrapper).  With ``emit_kv`` also returns that
     k/v (for KV-cache assembly)."""
-    y = _rms_norm(x, lp["attn_norm"], config.rms_eps)
-    q, k, v = _qkv_proj(y, lp, config, b, t, lget)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn.proj"):
+        y = _rms_norm(x, lp["attn_norm"], config.rms_eps)
+        q, k, v = _qkv_proj(y, lp, config, b, t, lget)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     if config.sliding_window is not None:
         # Both dense and flash attn_fns accept window=; an attn_fn that
         # cannot honor it (ring/Ulysses wrappers) fails loudly here
         # rather than silently attending outside the band.
-        attn = attn_fn(q, k, v, causal=True, window=config.sliding_window)
+        with jax.named_scope("attn.window"):
+            attn = attn_fn(q, k, v, causal=True, window=config.sliding_window)
     else:
-        attn = attn_fn(q, k, v, causal=True)
-    x = _attn_out(x, attn, lp, config, b, t, lget)
-    x = _mlp_block(x, lp, config, lget)
+        with jax.named_scope("attn.full"):
+            attn = attn_fn(q, k, v, causal=True)
+    with jax.named_scope("attn.proj"):
+        x = _attn_out(x, attn, lp, config, b, t, lget)
+    with jax.named_scope("ffn.dense"):
+        x = _mlp_block(x, lp, config, lget)
     return (x, (k, v)) if emit_kv else (x, None)
 
 
@@ -503,10 +557,11 @@ def _hidden_states(params, input_ids, config, lora, attn_fn, positions=None):
     dtype = config.dtype
     h, kv, dh = config.num_heads, config.num_kv_heads, config.head_dim
 
-    x = params["embed"].astype(dtype)[input_ids]
+    x = embed_part(params["embed"], input_ids, dtype=jnp.dtype(dtype))
     if positions is None:
         positions = jnp.arange(t)
-    cos, sin = rope_tables(positions, dh, config.rope_theta)
+    with jax.named_scope("attn.proj"):
+        cos, sin = rope_tables(positions, dh, config.rope_theta)
 
     lora_layers = (lora or {}).get("layers")
     # Scan xs need a leading layer dim on every leaf — hoist the scalar
@@ -552,7 +607,8 @@ def _hidden_states(params, input_ids, config, lora, attn_fn, positions=None):
     scanned = {"w": params["layers"]}
     if lora_layers is not None:
         scanned["lora"] = lora_layers
-    x, _ = jax.lax.scan(layer_body, x, scanned)
+    with jax.named_scope(f"layers0-{config.num_layers - 1}"):
+        x, _ = jax.lax.scan(layer_body, x, scanned)
     return x
 
 
@@ -979,8 +1035,10 @@ def _head_loss_fwd(head_rows, xs, head, out_scale, targets, weights):
 
 
 def _head_loss_bwd(head_rows, dxs, g):
-    # The head is frozen: no cotangent but the hidden states'.
-    return g.astype(dxs.dtype) * dxs, None, None, None, None
+    # The head is frozen: no cotangent but the hidden states'.  The rule
+    # is traced outside the caller's scope: name it again.
+    with jax.named_scope(HEAD_LOSS_SCOPE):
+        return g.astype(dxs.dtype) * dxs, None, None, None, None
 
 
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
@@ -1008,22 +1066,23 @@ def frozen_head_loss(x, head, ids, out_scale=None, *,
     rows = b * t
     chunk = head_chunk_rows(rows, head.shape[0 if head_rows else 1])
     n = -(-rows // chunk)
-    # The last position of each sequence has no target: weight 0, as the
-    # rows that pad the last chunk.
-    targets = jnp.roll(ids, -1, axis=1)
-    weights = jnp.broadcast_to(
-        (jnp.arange(t) < t - 1) / jnp.float32(max(b * (t - 1), 1)), (b, t)
-    )
 
     def chunks(a):
         a = a.reshape(rows, *a.shape[2:])
         a = jnp.pad(a, [(0, n * chunk - rows)] + [(0, 0)] * (a.ndim - 1))
         return a.reshape(n, chunk, *a.shape[1:])
 
-    return _head_loss(
-        head_rows, chunks(x), head, out_scale, chunks(targets),
-        chunks(weights),
-    )
+    with jax.named_scope(HEAD_LOSS_SCOPE):
+        # The last position of each sequence has no target: weight 0, as
+        # the rows that pad the last chunk.
+        targets = jnp.roll(ids, -1, axis=1)
+        weights = jnp.broadcast_to(
+            (jnp.arange(t) < t - 1) / jnp.float32(max(b * (t - 1), 1)), (b, t)
+        )
+        return _head_loss(
+            head_rows, chunks(x), head, out_scale, chunks(targets),
+            chunks(weights),
+        )
 
 
 def lora_loss(lora, base_params, ids, config: LlamaConfig, *,
@@ -1039,8 +1098,9 @@ def lora_loss(lora, base_params, ids, config: LlamaConfig, *,
         up = ids.size * c.intermediate_size * jnp.dtype(c.dtype).itemsize
         kept[f"layers0-{c.num_layers - 1}"] = (c.num_layers, {FFN_UP_NAME: up})
     emit_remat_saved(kept, ids.size, c.vocab_size)
-    x = _rms_norm(x, base_params["final_norm"], c.rms_eps)
-    head, out_scale = _head_matrix(base_params, c)
+    with jax.named_scope(HEAD_LOSS_SCOPE):
+        x = _rms_norm(x, base_params["final_norm"], c.rms_eps)
+        head, out_scale = _head_matrix(base_params, c)
     return frozen_head_loss(x.astype(c.dtype), head, ids, out_scale)
 
 
@@ -1118,6 +1178,18 @@ def _adam_update(params, grads, opt, lr, b1, b2, eps):
     return params, (count, m, v)
 
 
+def adam_part(lr, b1, b2, eps) -> Callable:
+    """``(params, grads, opt) -> (params, opt)``: :func:`_adam_update`
+    with its constants closed over (Python floats, as every step has
+    folded them), as the step's ``optim.adam`` part."""
+    return step_part(
+        ADAM_SCOPE,
+        lambda params, grads, opt: _adam_update(
+            params, grads, opt, lr, b1, b2, eps
+        ),
+    )
+
+
 def make_lora_train_step(
     config: LlamaConfig,
     lr: float = 1e-4,
@@ -1140,13 +1212,14 @@ def make_lora_train_step(
     """
 
     loss_fn = functools.partial(lora_loss, config=config, attn_fn=attn_fn)
+    adam = adam_part(lr, b1, b2, eps)
 
-    def step_fn(lora, opt, base_params, ids):
+    def llama_lora_step(lora, opt, base_params, ids):
         loss, grads = jax.value_and_grad(loss_fn)(lora, base_params, ids)
-        lora, opt = _adam_update(lora, grads, opt, lr, b1, b2, eps)
+        lora, opt = adam(lora, grads, opt)
         return lora, opt, loss
 
-    return jax.jit(step_fn, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(llama_lora_step, donate_argnums=(0, 1) if donate else ())
 
 
 def make_train_step(

@@ -81,6 +81,16 @@ class Runtime:
         a mesh — JAX's own default)."""
         return None if self.mesh is None else self.mesh.local_devices[0]
 
+    def local_devices(self) -> list:
+        """The devices of this process the party computes on: its
+        mesh's, or without a mesh the first local one (where JAX puts
+        an uncommitted array)."""
+        if self.mesh is not None:
+            return list(self.mesh.local_devices)
+        import jax
+
+        return jax.local_devices()[:1]
+
     def bind_thread(self) -> None:
         """Bind the calling thread to this party: ``get_runtime()``, log
         records and JAX's default device all resolve to it.  Idempotent

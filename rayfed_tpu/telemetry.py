@@ -7,8 +7,10 @@ structured **span records** fed by the named seams that already exist —
 transport send phases, server delivery, mailbox waits, aggregation
 fold/finalize, quorum cutoffs/failovers, ring/hierarchy phase
 boundaries, overlap's hidden-comms window, object-plane pulls,
-checkpoint save/restore — plus the chaos harness, so an injected
-partition appears on the SAME timeline as the failover it caused.
+checkpoint save/restore, the end of a party task (``device.memory``:
+what the party's chips hold, :func:`emit_device_memory`) — plus the
+chaos harness, so an injected partition appears on the SAME timeline as
+the failover it caused.
 
 Record shape (:data:`SPAN_FIELDS`)::
 
@@ -424,6 +426,49 @@ def span(
         return _DISARMED
     return Span(rec, phase, party, round, epoch, peer, stream, nbytes,
                 detail)
+
+
+# ---------------------------------------------------------------------------
+# Device memory: what a party's chips held, read from inside the program
+# ---------------------------------------------------------------------------
+
+DEVICE_MEMORY_PHASE = "device.memory"
+DEVICE_MEMORY_FIELDS = (
+    "bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+    "peak_bytes_reserved", "bytes_limit",
+)
+
+
+def emit_device_memory(devices: Iterable[Any], name: Optional[str] = None,
+                       **kw: Any) -> None:
+    """One ``device.memory`` record of ``devices``' ``memory_stats()``
+    now: ``detail["devices"]`` maps a device's id to its
+    :data:`DEVICE_MEMORY_FIELDS` (``peak_bytes_in_use`` is the
+    process's high-water mark of resident arrays, ``peak_bytes_reserved``
+    that of what running programs reserved for their temporaries: a chip
+    is as full as their sum), ``nbytes`` is the largest
+    ``bytes_in_use``, ``detail["name"]`` the task that ended.  No record
+    where no device reports (the CPU's ``memory_stats()`` is ``None``)
+    or none is armed; never raises."""
+    rec = _ACTIVE
+    if rec is None:
+        return
+    found = {}
+    try:
+        for device in devices:
+            stats = device.memory_stats()
+            if stats:
+                found[str(device.id)] = {
+                    k: int(stats.get(k, 0)) for k in DEVICE_MEMORY_FIELDS
+                }
+    except Exception:  # noqa: BLE001 — a diagnostic must not fail a task
+        return
+    if found:
+        rec.emit(
+            DEVICE_MEMORY_PHASE,
+            nbytes=max(v["bytes_in_use"] for v in found.values()),
+            detail={"name": name, "devices": found}, **kw,
+        )
 
 
 # ---------------------------------------------------------------------------
