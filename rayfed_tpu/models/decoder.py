@@ -32,11 +32,18 @@ group (one compiled body however many layers; ``remat`` is
 selected: a selection made again from recomputed scores need not be the
 one the forward pass used; the flash kernel's output and row
 statistics, ``B*T*H*Dh`` elements of ``dtype`` + ``B*H*T*4`` bytes a
-layer, 68.2 MB a sequence of 8,192 at 32 x 128 heads; and the up
+layer, 68.2 MB a sequence of 8,192 at 32 x 128 heads; the up
 product of the dense FFN or the shared expert, ``B*T*F`` elements of
-``dtype``, 100.7 MB at F = 6,144: the backward pass runs the rest of
-the layer's forward again, the FFN's gate product too, but not the
-attention kernel nor the up product; the routed experts keep what
+``dtype``, 100.7 MB at F = 6,144; the residual stream after the
+mixer's add (``layer.mid``, ``B*T*D`` elements, 33.6 MB at D = 2,048,
+named in :func:`apply_block`); and a state-space layer's input
+projection (``ssm.in``, ``B*T*proj_dim`` elements, 139.5 MB at 8,512,
+named in :func:`mamba2.apply_mixer`): the backward pass runs the rest
+of the layer's forward again, the FFN's gate product too, but not the
+attention kernel, the up product, a state-space layer's ``W_in`` nor
+the mixer's output projection, whose product only the kept stream
+read (where ``post_norms`` puts a norm on that product, the norm's
+backward reads it and it runs again); the routed experts keep what
 their hand-written backward keeps).
 Where a group mixes attention kinds the body picks the layer's kernel
 with a ``cond`` on a scanned flag (the flash kernel's ``window`` is
@@ -71,11 +78,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from rayfed_tpu import telemetry
 from rayfed_tpu.models import mamba2, moe
 from rayfed_tpu.models.llama import (
     HEAD_LOSS_SCOPE,
+    LAYER_MID_NAME,
     REMAT_SAVED,
     YarnScaling,
     _linear,
@@ -87,6 +96,7 @@ from rayfed_tpu.models.llama import (
     emit_remat_saved,
     frozen_head_loss,
     lm_loss,  # noqa: F401  (benchmark/families read `decoder.lm_loss`)
+    remat_saved_bytes,
     rope_tables,
 )
 from rayfed_tpu.models.mamba2 import SsmConfig
@@ -401,6 +411,8 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
             x = _add(x, o, c.residual_scale)
     else:
         x = _attention_block(x, lp, c, kind, attn_fn, lget)
+    # kept by a checkpointed layer: its second forward starts here
+    x = checkpoint_name(x, LAYER_MID_NAME)
     b, t, _ = x.shape
     y = _rms_norm(x, lp["mlp_norm"], c.rms_eps)
     aux = None
@@ -564,21 +576,19 @@ def routing_counts(aux) -> Optional[jax.Array]:
 
 
 def _kept_by_group(config: DecoderConfig, tokens: int):
-    """``{scanned group: (layers, {name: bytes a layer keeps})}`` for the
-    names of ``REMAT_SAVED`` given in ``moe.py``: the up product of the
-    dense FFN or the shared expert, and an expert layer's selection;
+    """``{scanned group: (layers, {name: bytes a layer keeps})}`` by
+    ``llama.remat_saved_bytes`` at the widths of the group's layers;
     empty without ``remat``."""
-    c, itemsize = config, jnp.dtype(config.dtype).itemsize
-    kept = {}
+    c, kept = config, {}
     for start, stop in c.groups() if c.remat else ():
-        if c.layers[start].ffn == "dense":
-            sizes = {moe.FFN_UP_NAME: tokens * c.intermediate_size * itemsize}
-        else:
-            sizes = {
-                moe.FFN_UP_NAME: tokens * c.experts.d_ff * itemsize,
-                moe.SELECTED_NAME: tokens * c.experts.top_k * 4,
-            }
-        kept[f"layers{start}-{stop - 1}"] = (stop - start, sizes)
+        spec = c.layers[start]
+        dense = spec.ffn == "dense"
+        kept[f"layers{start}-{stop - 1}"] = (stop - start, remat_saved_bytes(
+            tokens, c.dtype, hidden=c.hidden_size,
+            ffn_up=c.intermediate_size if dense else c.experts.d_ff,
+            top_k=0 if dense else c.experts.top_k,
+            ssm_in=c.ssm.proj_dim if spec.mixer == "ssm" else 0,
+        ))
     return kept
 
 

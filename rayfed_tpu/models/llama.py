@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from rayfed_tpu import telemetry
@@ -40,16 +41,57 @@ Params = Dict[str, Any]
 # What a rematerialized layer body keeps besides its input, here and in
 # ``decoder.py``: the experts a routed layer selected (selected again
 # from recomputed scores they need not be the same), the flash kernel's
-# output and row statistics, which only the kernel can make again, and
-# the gated FFN's up product (``B*T*F`` elements of the compute dtype a
+# output and row statistics, which only the kernel can make again, the
+# gated FFN's up product (``B*T*F`` elements of the compute dtype a
 # dense FFN or shared expert): the second forward makes ``silu(gate) *
 # up`` from a recomputed gate and the saved up, one FFN-width product
-# less a layer.  Everything else of the layer's forward is recomputed.
+# less a layer; the residual stream between a layer's two sub-blocks
+# (``LAYER_MID_NAME``, ``B*T*D`` elements, named right after the mixer's
+# residual add): the second forward starts the FFN half from it, so the
+# output projection's product and its adapter's wide one are dead there
+# and JAX drops them (what the backward pass needs of that projection,
+# its input and the adapter's ``x a``, is still made); and a state-space
+# layer's input projection (``SSM_IN_NAME``, ``B*T*proj_dim`` elements,
+# named in ``mamba2.apply_mixer``: gate, convolved part and time step
+# are slices of it).  Each is an array the first forward leaves in HBM
+# in the compute dtype anyway; JAX puts a ``reduce_precision`` on a kept
+# residual's producer, so its forward consumers read the ROUNDED array
+# (what the program says) where XLA's excess precision let a fused one
+# read the float32 it was rounded from (the FFN norm's statistic: the
+# benchmark's losses move by 1e-7 to 5e-6 relative, either sign; with
+# ``xla_allow_excess_precision`` off no bit moves: PERF.md section 6,
+# PR 38).  Everything else of the layer's forward is recomputed.  A name
+# engages where a layer's code makes the value: one policy for every
+# model.
 # The gate product (``moe.FFN_GATE_NAME``) is NOT kept: kept in bf16 it
 # moved the losses of the cells that adapt the FFN by 4e-4 (PERF.md
 # section 6, PR 34).
-REMAT_SAVED_NAMES = (SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME)
+LAYER_MID_NAME, SSM_IN_NAME = "layer.mid", "ssm.in"
+REMAT_SAVED_NAMES = (
+    SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME, LAYER_MID_NAME, SSM_IN_NAME
+)
 REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+
+
+def remat_saved_bytes(tokens: int, dtype, *, hidden: int, ffn_up: int,
+                      top_k: int = 0, ssm_in: int = 0) -> Dict[str, int]:
+    """``{name: bytes ONE checkpointed layer keeps under it}`` for the
+    names of ``REMAT_SAVED_NAMES`` that the models' own code gives (the
+    flash kernel's two are counted where it is called), from the layer's
+    widths: ``hidden`` the stream's, ``ffn_up`` the dense FFN's or the
+    shared expert's, ``top_k`` an expert layer's selections a token and
+    ``ssm_in`` a state-space layer's input projection's (0: the layer
+    has none).  The one table behind FR ``remat.saved``, here and in
+    ``decoder.py``: a name added to the policy is priced here.  The
+    stream is kept whether or not the second forward is spared the
+    output projection by it (a norm on that product needs it again)."""
+    elem = tokens * jnp.dtype(dtype).itemsize
+    sizes = {FFN_UP_NAME: elem * ffn_up, LAYER_MID_NAME: elem * hidden}
+    if top_k:
+        sizes[SELECTED_NAME] = tokens * top_k * 4
+    if ssm_in:
+        sizes[SSM_IN_NAME] = elem * ssm_in
+    return sizes
 
 
 # The scope vocabulary of a training step outside its layers (inside a
@@ -135,16 +177,18 @@ class LlamaConfig:
     # float32 (see init_adam for why).
     param_dtype: Any = jnp.float32
     # ``jax.checkpoint`` of the scanned layer body.  A layer keeps its
-    # input and (``REMAT_SAVED``) the FFN's up product and, where
-    # ``attn_fn`` is the flash kernel, the kernel's output and row
-    # statistics, so the backward pass runs the layer's projections,
-    # norms, rotary embedding and the FFN's gate product again, but not
-    # the attention kernel nor the up product: ``B*T*H*Dh`` elements of
-    # ``dtype`` + ``B*H*T*4`` bytes a layer for the kernel's (68.2 MB a
-    # sequence of 8,192 at 32 x 128 heads in bf16) and ``B*T*F``
-    # elements for the FFN's (234.9 MB at F = 14,336): 303 MB a layer
-    # beside its input (9.7 GB at 32 layers), about a third of what
-    # ``remat=False`` would keep.
+    # input and (``REMAT_SAVED``) the FFN's up product, the residual
+    # stream between its two sub-blocks and, where ``attn_fn`` is the
+    # flash kernel, the kernel's output and row statistics, so the
+    # backward pass runs the layer's q/k/v projections, norms, rotary
+    # embedding and the FFN's gate product again, but not the attention
+    # kernel, the output projection nor the up product: ``B*T*H*Dh``
+    # elements of ``dtype`` + ``B*H*T*4`` bytes a layer for the kernel's
+    # (68.2 MB a sequence of 8,192 at 32 x 128 heads in bf16), ``B*T*F``
+    # elements for the FFN's (234.9 MB at F = 14,336) and ``B*T*D`` for
+    # the stream (67.1 MB at D = 4,096): 370 MB a layer beside its input
+    # (11.8 GB at 32 layers), about two fifths of what ``remat=False``
+    # would keep.
     remat: bool = False
     # Rematerialization policy for the scanned layer body: None =
     # recompute everything but the above (lowest memory); "dots" = also
@@ -497,6 +541,7 @@ def _layer_fwd(x, lp, config, cos, sin, attn_fn, b, t, lget=_no_lora,
             attn = attn_fn(q, k, v, causal=True)
     with jax.named_scope("attn.proj"):
         x = _attn_out(x, attn, lp, config, b, t, lget)
+        x = checkpoint_name(x, LAYER_MID_NAME)
     with jax.named_scope("ffn.dense"):
         x = _mlp_block(x, lp, config, lget)
     return (x, (k, v)) if emit_kv else (x, None)
@@ -1095,8 +1140,9 @@ def lora_loss(lora, base_params, ids, config: LlamaConfig, *,
     x = _hidden_states(base_params, ids, c, lora, attn_fn)
     kept = {}
     if c.remat:
-        up = ids.size * c.intermediate_size * jnp.dtype(c.dtype).itemsize
-        kept[f"layers0-{c.num_layers - 1}"] = (c.num_layers, {FFN_UP_NAME: up})
+        kept[f"layers0-{c.num_layers - 1}"] = (c.num_layers, remat_saved_bytes(
+            ids.size, c.dtype, hidden=c.hidden_size, ffn_up=c.intermediate_size
+        ))
     emit_remat_saved(kept, ids.size, c.vocab_size)
     with jax.named_scope(HEAD_LOSS_SCOPE):
         x = _rms_norm(x, base_params["final_norm"], c.rms_eps)

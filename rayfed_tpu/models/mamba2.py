@@ -23,7 +23,13 @@ entry applies as to every other matrix), ``conv_w`` [C, K], ``conv_b``
 [C], ``A_log``, ``D``, ``dt_bias`` [H] (float32 whatever the parameter
 type, as the published code keeps them), ``ssm_norm`` [d_inner].
 Scopes on a device trace: ``ssm.proj`` (both projections and the gated
-norm), ``ssm.conv``, ``ssm.scan``.
+norm), ``ssm.conv``, ``ssm.scan``.  ``W_in``'s product carries the
+checkpoint name ``ssm.in`` (``llama.SSM_IN_NAME``), which a
+rematerialized layer's policy keeps (``llama.REMAT_SAVED``): the second
+forward slices gate, convolved part and time step from the kept array
+and runs neither ``W_in`` nor its adapter's ``(y a) b`` again; the
+convolution, the scan and the gated norm it does run again (their
+backward reads float32 values that are not kept).
 """
 
 from __future__ import annotations
@@ -34,8 +40,9 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from rayfed_tpu.models.llama import _linear
+from rayfed_tpu.models.llama import SSM_IN_NAME, _linear
 from rayfed_tpu.ops.ssd import ssd_scan
 
 Params = Dict[str, Any]
@@ -135,6 +142,7 @@ def apply_mixer(y, lp: Params, config: SsmConfig, lget, dtype, eps):
     gn = m.groups * m.state
     with jax.named_scope("ssm.proj"):
         proj = _linear(y, lp["w_in"], lget("w_in"), dtype)
+        proj = checkpoint_name(proj, SSM_IN_NAME)
         z = proj[..., : m.d_inner]
         xbc = proj[..., m.d_inner: m.d_inner + m.conv_dim]
         dt = jax.nn.softplus(

@@ -720,7 +720,9 @@ def _llama_lora_step():
     shape = (b, t, cfg.vocab_size)
     return step, (adapters, llama.init_adam(adapters), base), shape, {
         "layers": {"layers0-1": 2},
-        "bytes_per_layer": {"layers0-1": {"ffn.up": b * t * f * 2}},
+        "bytes_per_layer": {"layers0-1": {
+            "ffn.up": b * t * f * 2, "layer.mid": b * t * cfg.hidden_size * 2,
+        }},
     }
 
 
@@ -746,12 +748,16 @@ def _decoder_lora_step():
     step = decoder.make_lora_train_step(cfg, attn_fn=flash_attention).jitted
     b, t = 1, 24
     return step, (adapters, llama.init_adam(adapters), base), (b, t, 64), {
-        # a dense FFN's up product; a shared expert's, and the selection
+        # the stream between the sub-blocks in every layer; a dense FFN's
+        # up product; a shared expert's, and the selection
         "layers": {"layers0-0": 1, "layers1-2": 2},
         "bytes_per_layer": {
-            "layers0-0": {"ffn.up": b * t * 48 * 4},
+            "layers0-0": {
+                "layer.mid": b * t * 32 * 4, "ffn.up": b * t * 48 * 4,
+            },
             "layers1-2": {
-                "ffn.up": b * t * 16 * 4, "moe.selected": b * t * 3 * 4,
+                "layer.mid": b * t * 32 * 4, "ffn.up": b * t * 16 * 4,
+                "moe.selected": b * t * 3 * 4,
             },
         },
     }
@@ -779,7 +785,7 @@ def test_remat_saved_record_when_armed(model, monkeypatch):
         telemetry.uninstall()
     detail = record.detail
     assert detail["names"] == list(llama.REMAT_SAVED_NAMES)
-    assert {"ffn.up", "flash.out"} <= set(detail["names"])
+    assert {"ffn.up", "flash.out", "layer.mid"} <= set(detail["names"])
     assert "ffn.gate" not in detail["names"]
     assert detail["head_chunk_rows"] == 16
     assert detail["head_chunks"] == -(-b * t // 16)

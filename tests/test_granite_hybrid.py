@@ -574,8 +574,9 @@ def test_bf16_step_runs_finite_through_the_flash_kernels():
 
 
 def test_remat_saved_record_counts_the_groups_of_a_hybrid(monkeypatch):
-    """A Mamba layer keeps its input and ``ffn.up``, as every dense FFN
-    does: the record lists all three groups with the product's bytes."""
+    """Every layer keeps its input, ``ffn.up`` and the stream between
+    its sub-blocks (``layer.mid``), a Mamba layer also ``W_in``'s product
+    (``ssm.in``): the record lists all three groups with their bytes."""
     cfg, base, adapters, ids = make(cfg=toy_config(remat=True))
     rec = telemetry.install(capacity=64)
     try:
@@ -589,9 +590,11 @@ def test_remat_saved_record_counts_the_groups_of_a_hybrid(monkeypatch):
     assert record.detail["layers"] == {
         "layers0-1": 2, "layers2-2": 1, "layers3-4": 2,
     }
-    up = T * FFN * 4
+    every = {"ffn.up": T * FFN * 4, "layer.mid": T * D * 4}
+    mamba = dict(every, **{"ssm.in": T * SSM.proj_dim * 4})
     assert record.detail["bytes_per_layer"] == {
-        g: {"ffn.up": up} for g in record.detail["layers"]
+        "layers0-1": mamba, "layers2-2": every, "layers3-4": mamba,
     }
     assert record.detail["names"] == list(llama.REMAT_SAVED_NAMES)
+    assert {"layer.mid", "ssm.in"} <= set(record.detail["names"])
     assert len(scans) >= 2  # a record a scanned group of Mamba layers
