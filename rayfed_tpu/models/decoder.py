@@ -11,8 +11,17 @@ their own width; or no attention at all but a state-space layer, the
 Mamba-2 mixer of :mod:`rayfed_tpu.models.mamba2`: one input projection
 split three ways, a causal depthwise convolution, the chunked scan of
 :mod:`rayfed_tpu.ops.ssd`, a gated norm, an output projection) and its
-FFN kind (dense SwiGLU, or routed + shared experts through
-:func:`rayfed_tpu.models.moe.apply_expert_share`).
+FFN kind (dense SwiGLU, routed + shared experts through
+:func:`rayfed_tpu.models.moe.apply_expert_share`, or none: a block that
+is its mixer alone, the ``nemotron_h`` block's single-part layers paired
+so that a mixer block and the FFN block after it are one layer, each
+part with its own pre-norm and plain residual, the arithmetic the same).
+A multi-token-prediction module (:class:`MtpConfig`: DeepSeek-V3's and
+``nemotron_h``'s MTP) may follow the layers: the embedding of the next
+token and the last layer's output, each normed, concatenated and
+projected (``w_eh``), layers of its own, a final norm of its own and the
+shared head, which predicts the token after next; the LoRA step's loss
+is then the main loss plus :data:`MTP_LOSS_WEIGHT` times that one.
 What the sparse-expert decoders published since 2025 add to the block
 is the configuration's to switch (:class:`DecoderConfig`, on by default
 as the first configuration has them all): an RMS norm of q and k over
@@ -53,14 +62,17 @@ static); the branches' residuals are of one shape and share the
 :func:`rayfed_tpu.models.lora.init_lora` mirrors it with the group's
 index as a string.  :func:`unstack` gives either tree layer by layer.
 
-Three configurations run through it: the AFMoE family's
+Four configurations run through it: the AFMoE family's
 (``benchmark/families/afmoe_lm.py``, reference in
 ``benchmark/reference/afmoe.py``), the ``kimi_k2`` / DeepSeek-V3
 block (``benchmark/families/kimi_k2_lm.py``, ``reference/kimi_k2.py``),
-all of whose layers are latent, and the ``granitemoehybrid`` block
+all of whose layers are latent, the ``granitemoehybrid`` block
 (``benchmark/families/granite_hybrid_lm.py``,
 ``reference/granite_hybrid.py``): nine state-space layers to one of
-full attention without positions, a dense FFN after each.  Helpers are shared with
+full attention without positions, a dense FFN after each, and the
+``nemotron_h`` block (``benchmark/families/nemotron_h_lm.py``,
+``reference/nemotron_h.py``): single-part Mamba-2, latent-expert and
+attention blocks, and an MTP module.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
 ``_linear``, ``lm_loss``, ``frozen_head_loss``, ``adam_part``,
 ``embed_part``);
@@ -107,8 +119,11 @@ Params = Dict[str, Any]
 # Every linear matrix of the block but the router (LoraConfig.targets):
 # wq wk wv wo and the output gate wz, a latent layer's five (wq_a wq_b
 # wkv_a wkv_b wo), or a state-space layer's two (w_in w_out); the dense
-# FFN's, the shared expert's and each held expert's three.
-ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$", r"/w_(gate|up|down|in|out)$")
+# FFN's, the shared expert's and each held expert's three (two where
+# they are squared-ReLU), an expert layer's latent pair (w_lat_in
+# w_lat_out) and the MTP module's projection (w_eh).
+ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$",
+              r"/w_(gate|up|down|in|out|lat_in|lat_out|eh)$")
 
 # A mixer's kind -> the kind of its PARAMETERS: layers stack into one
 # scanned group only where these agree.
@@ -116,7 +131,10 @@ MIXER_PARAMS = {
     "window": "attention", "full": "attention", "latent": "latent",
     "ssm": "ssm",
 }
-FFN_KINDS = ("dense", "moe")
+FFN_KINDS = ("dense", "moe", "none")
+# The scopes of the MTP module on a device trace: all of it, and its
+# input (the two norms, the concatenation and ``w_eh``).
+MTP_SCOPE, MTP_FUSE_SCOPE = "mtp", "mtp.fuse"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,7 +143,7 @@ class LayerSpec:
     # part of the head, DecoderConfig.latent) | "ssm" (no attention: the
     # Mamba-2 mixer, DecoderConfig.ssm)
     mixer: str = "window"
-    ffn: str = "dense"  # "dense" | "moe"
+    ffn: str = "dense"  # "dense" | "moe" | "none" (the mixer alone)
 
     def __post_init__(self):
         if self.mixer not in MIXER_PARAMS:
@@ -159,6 +177,29 @@ class LatentConfig:
     v_dim: int = 128
 
 
+# The MTP module's loss enters the step's loss times this: Megatron-Core's
+# ``mtp_loss_scaling_factor`` default (a model's config does not state it).
+MTP_LOSS_WEIGHT = 0.1
+
+
+@dataclasses.dataclass(frozen=True)
+class MtpConfig:
+    """A multi-token-prediction module after the layers: its own layers
+    (their widths are the decoder's)."""
+
+    layers: Tuple[LayerSpec, ...]
+
+
+def _groups_of(layers, offset: int = 0) -> Tuple[Tuple[int, int], ...]:
+    stacks = lambda s: (s.ffn, MIXER_PARAMS[s.mixer])
+    out, start = [], 0
+    for i in range(1, len(layers) + 1):
+        if i == len(layers) or stacks(layers[i]) != stacks(layers[start]):
+            out.append((offset + start, offset + i))
+            start = i
+    return tuple(out)
+
+
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     layers: Tuple[LayerSpec, ...]
@@ -185,6 +226,7 @@ class DecoderConfig:
     logit_scale: float = 1.0  # logits times this
     tie_embeddings: bool = False  # the head is the embedding, transposed
     experts: Optional[moe.ExpertShareConfig] = None
+    mtp: Optional[MtpConfig] = None  # a multi-token-prediction module
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
@@ -192,29 +234,31 @@ class DecoderConfig:
     def __post_init__(self):
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
-        if any(s.ffn == "moe" for s in self.layers) and self.experts is None:
+        if any(s.ffn == "moe" for s in self.stack) and self.experts is None:
             raise ValueError("a layer with ffn='moe' needs config.experts")
         for kind in ("latent", "ssm"):
             if getattr(self, kind) is None and any(
-                s.mixer == kind for s in self.layers
+                s.mixer == kind for s in self.stack
             ):
                 raise ValueError(
                     f"a layer with mixer={kind!r} needs config.{kind}"
                 )
 
+    @property
+    def stack(self) -> Tuple[LayerSpec, ...]:
+        """Every layer, the MTP module's after the decoder's: what a
+        layer index counts (``aux``, ``moe.counts``, a group's scope)."""
+        return self.layers + (self.mtp.layers if self.mtp else ())
+
     def groups(self) -> Tuple[Tuple[int, int], ...]:
         """``(first layer, one past the last)`` of every run of
         consecutive layers with one FFN kind and one kind of mixer
         parameters (``MIXER_PARAMS``): what one scan can stack."""
-        stacks = lambda s: (s.ffn, MIXER_PARAMS[s.mixer])
-        out, start = [], 0
-        for i in range(1, len(self.layers) + 1):
-            if i == len(self.layers) or stacks(self.layers[i]) != stacks(
-                self.layers[start]
-            ):
-                out.append((start, i))
-                start = i
-        return tuple(out)
+        return _groups_of(self.layers)
+
+    def mtp_groups(self) -> Tuple[Tuple[int, int], ...]:
+        """The MTP module's groups, by their index in :attr:`stack`."""
+        return _groups_of(self.mtp.layers, len(self.layers)) if self.mtp else ()
 
 
 def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
@@ -223,7 +267,10 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     size the sub-blocks' normed outputs add to), norms at one; a
     state-space layer's buffers as :func:`mamba2.init_mixer` draws them.
     Every layer draws from a key of its own; a group's layers are
-    stacked.  No ``lm_head`` where the head is tied to the embedding."""
+    stacked.  No ``lm_head`` where the head is tied to the embedding.
+    The MTP module (``params["mtp"]``) draws from a key of its own: its
+    two norms, ``w_eh`` [2 D, D], its layers as the decoder's, its final
+    norm."""
     c = config
     d, dh, h, kv = c.hidden_size, c.head_dim, c.num_heads, c.num_kv_heads
     pdt = c.param_dtype
@@ -236,7 +283,9 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
         ks = jax.random.split(key, 9)
         ones = lambda n: jnp.ones((n,), pdt)
         # `attn_norm` is the norm before the mixer, whatever the mixer
-        lp = {"attn_norm": ones(d), "mlp_norm": ones(d)}
+        lp = {"attn_norm": ones(d)}
+        if spec.ffn != "none":
+            lp["mlp_norm"] = ones(d)
         if spec.mixer == "ssm":
             lp.update(mamba2.init_mixer(ks[0], d, c.ssm, pdt))
         elif spec.mixer == "latent":
@@ -273,25 +322,43 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
             lp["w_gate"] = dense(ks[5], d, f, fan_in=d)
             lp["w_up"] = dense(ks[6], d, f, fan_in=d)
             lp["w_down"] = dense(ks[7], f, d, fan_in=f)
-        else:
+        elif spec.ffn == "moe":
             lp["moe"] = moe.init_expert_share(ks[8], c.experts, pdt)
         return lp
 
-    keys = jax.random.split(k_layers, len(c.layers))
     stack = lambda *leaves: jnp.stack(leaves)
+
+    def stacked(keys, layers, groups, offset=0):
+        return [
+            jax.tree_util.tree_map(stack, *(
+                layer(keys[i - offset], layers[i - offset])
+                for i in range(start, stop)
+            ))
+            for start, stop in groups
+        ]
+
     params = {
         "embed": (jax.random.normal(k_embed, (c.vocab_size, d)) * 0.02
                   ).astype(pdt),
-        "layers": [
-            jax.tree_util.tree_map(stack, *(
-                layer(keys[i], c.layers[i]) for i in range(start, stop)
-            ))
-            for start, stop in c.groups()
-        ],
+        "layers": stacked(
+            jax.random.split(k_layers, len(c.layers)), c.layers, c.groups()
+        ),
         "final_norm": jnp.ones((d,), pdt),
     }
     if not c.tie_embeddings:
         params["lm_head"] = dense(k_head, d, c.vocab_size, fan_in=d)
+    if c.mtp:
+        k_eh, k_mtp = jax.random.split(jax.random.fold_in(key, 2))
+        params["mtp"] = {
+            "enorm": jnp.ones((d,), pdt),
+            "hnorm": jnp.ones((d,), pdt),
+            "w_eh": dense(k_eh, 2 * d, d, fan_in=2 * d),
+            "layers": stacked(
+                jax.random.split(k_mtp, len(c.mtp.layers)), c.mtp.layers,
+                c.mtp_groups(), len(c.layers),
+            ),
+            "final_norm": jnp.ones((d,), pdt),
+        }
     return params
 
 
@@ -299,13 +366,23 @@ def unstack(tree: Params, config: DecoderConfig) -> Params:
     """``tree`` (parameters, or adapters mirroring them) with ``layers``
     layer by layer: a list of per-layer dicts for parameters, a dict
     keyed by the layer's index as a string for adapters.  What a reader
-    that knows nothing of groups takes (the plain reference)."""
-    groups = tree["layers"]
+    that knows nothing of groups takes (the plain reference).  The MTP
+    module's layers alike, indexed from 0 within it."""
+    out = _unstack_layers(tree, config.groups())
+    if config.mtp and tree.get("mtp"):
+        out["mtp"] = _unstack_layers(
+            tree["mtp"], _groups_of(config.mtp.layers)
+        )
+    return out
+
+
+def _unstack_layers(tree, group_bounds):
+    groups = tree.get("layers", {})
     of_group = (lambda g: groups[g]) if isinstance(groups, list) else (
         lambda g: groups.get(str(g), {})
     )
     layers = {}
-    for g, (start, stop) in enumerate(config.groups()):
+    for g, (start, stop) in enumerate(group_bounds):
         for i in range(start, stop):
             layers[i] = jax.tree_util.tree_map(
                 # an adapter's `scale` is one number for the whole group
@@ -395,7 +472,8 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
     (or ``attention=``, its older name: one of the two) is the layer's kind,
     or a traced boolean "is windowed" (both kernels are then in the
     program, under a ``cond``); ``aux`` is what
-    :func:`moe.apply_expert_share` reports, None for a dense FFN."""
+    :func:`moe.apply_expert_share` reports, None for a dense FFN or
+    none (``ffn="none"``: the layer is its mixer alone)."""
     c = config
     lget = (lora or {}).get
     if (mixer is None) == (attention is None):
@@ -411,6 +489,8 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
             x = _add(x, o, c.residual_scale)
     else:
         x = _attention_block(x, lp, c, kind, attn_fn, lget)
+    if ffn == "none":
+        return x, None
     # kept by a checkpointed layer: its second forward starts here
     x = checkpoint_name(x, LAYER_MID_NAME)
     b, t, _ = x.shape
@@ -532,12 +612,35 @@ def _head(params, config: DecoderConfig):
 def _hidden_states(params, input_ids, config, lora, attn_fn):
     """The final-normed residual stream [B, T, D] in the compute dtype,
     and ``apply_decoder``'s ``aux``."""
+    _, x, aux = streams(params, input_ids, config, lora, attn_fn)
+    return _final_norm(x, params["final_norm"], config), aux
+
+
+def _final_norm(x, scale, config):
+    with jax.named_scope(HEAD_LOSS_SCOPE):
+        x = _rms_norm(x, scale, config.rms_eps)
+    return x.astype(config.dtype)
+
+
+def streams(params, input_ids, config: DecoderConfig, lora=None,
+            attn_fn: Callable = dot_product_attention):
+    """``(the embedded ids, the stream after the last layer before the
+    final norm, aux)``: what :func:`mtp_fuse` reads."""
     c = config
-    x = embed(params, input_ids, c)
-    lora_groups = (lora or {}).get("layers", {})
+    x0 = embed(params, input_ids, c)
     aux = {}
-    for g, (start, stop) in enumerate(c.groups()):
-        specs = c.layers[start:stop]
+    x = _run_groups(params["layers"], x0, c, c.groups(),
+                    (lora or {}).get("layers", {}), attn_fn, aux)
+    return x0, x, aux
+
+
+def _run_groups(groups, x, config, bounds, lora_groups, attn_fn, aux):
+    """The stream ``x`` through the scanned groups ``groups`` (``bounds``
+    their layers' indices in ``config.stack``); each expert layer's
+    report goes into ``aux`` under its index."""
+    c = config
+    for g, (start, stop) in enumerate(bounds):
+        specs = c.stack[start:stop]
         kinds = {s.mixer for s in specs}
         adapters, rebuild = _split_scalars(lora_groups.get(str(g)))
 
@@ -554,14 +657,25 @@ def _hidden_states(params, input_ids, config, lora, attn_fn):
         windowed = jnp.asarray([s.mixer == "window" for s in specs])
         with jax.named_scope(f"layers{start}-{stop - 1}"):
             x, stacked = jax.lax.scan(
-                body, x, (params["layers"][g], adapters, windowed),
+                body, x, (groups[g], adapters, windowed),
             )
         if stacked is not None:
             for i in range(start, stop):
                 aux[i] = jax.tree_util.tree_map(lambda a: a[i - start], stacked)
-    with jax.named_scope(HEAD_LOSS_SCOPE):
-        x = _rms_norm(x, params["final_norm"], c.rms_eps)
-    return x.astype(c.dtype), aux
+    return x
+
+
+def mtp_fuse(params, x0, h, config: DecoderConfig, lora=None):
+    """The MTP module's input [B, T, D]: position ``i`` reads the
+    embedding of token ``i + 1`` (``x0``, the embedded ids, rolled: the
+    last position reads the first token's and has no target) beside the
+    last layer's output ``h`` at ``i``, each normed, concatenated and
+    projected by ``w_eh``."""
+    c, p = config, params["mtp"]
+    with jax.named_scope(MTP_FUSE_SCOPE):
+        e = _rms_norm(jnp.roll(x0, -1, axis=1), p["enorm"], c.rms_eps)
+        y = jnp.concatenate([e, _rms_norm(h, p["hnorm"], c.rms_eps)], -1)
+        return _linear(y, p["w_eh"], (lora or {}).get("w_eh"), c.dtype)
 
 
 def routing_counts(aux) -> Optional[jax.Array]:
@@ -580,13 +694,16 @@ def _kept_by_group(config: DecoderConfig, tokens: int):
     ``llama.remat_saved_bytes`` at the widths of the group's layers;
     empty without ``remat``."""
     c, kept = config, {}
-    for start, stop in c.groups() if c.remat else ():
-        spec = c.layers[start]
-        dense = spec.ffn == "dense"
+    for start, stop in c.groups() + c.mtp_groups() if c.remat else ():
+        spec = c.stack[start]
+        routed = spec.ffn == "moe"
         kept[f"layers{start}-{stop - 1}"] = (stop - start, remat_saved_bytes(
             tokens, c.dtype, hidden=c.hidden_size,
-            ffn_up=c.intermediate_size if dense else c.experts.d_ff,
-            top_k=0 if dense else c.experts.top_k,
+            # the dense FFN's or the shared expert's up product, if any
+            ffn_up=c.intermediate_size if spec.ffn == "dense" else (
+                c.experts.shared_width if routed else 0
+            ),
+            top_k=c.experts.top_k if routed else 0,
             ssm_in=c.ssm.proj_dim if spec.mixer == "ssm" else 0,
         ))
     return kept
@@ -597,16 +714,36 @@ def lora_loss(lora, base, ids, config: DecoderConfig, *,
     """Next-token loss of ``ids`` [B, T] with adapters ``lora`` on the
     frozen ``base``, and ``apply_decoder``'s ``aux``: what the LoRA step
     differentiates.  The head is frozen too, so head and loss are fused
-    (:func:`llama.frozen_head_loss`: no ``[B, T, V]`` array)."""
-    x, aux = _hidden_states(base, ids, config, lora, attn_fn)
-    emit_remat_saved(
-        _kept_by_group(config, ids.size), ids.size, config.vocab_size
-    )
-    head, rows = _head(base, config)
-    scale = None if config.logit_scale == 1.0 else jnp.float32(
-        config.logit_scale
-    )
-    return frozen_head_loss(x, head, ids, scale, head_rows=bool(rows)), aux
+    (:func:`llama.frozen_head_loss`: no ``[B, T, V]`` array).  With an
+    MTP module the loss is :func:`lora_loss_terms`' total."""
+    loss, aux, _ = lora_loss_terms(lora, base, ids, config, attn_fn=attn_fn)
+    return loss, aux
+
+
+def lora_loss_terms(lora, base, ids, config: DecoderConfig, *,
+                    attn_fn: Callable = dot_product_attention):
+    """``(loss, aux, terms)``: :func:`lora_loss` and, with an MTP module,
+    ``terms = (main, mtp)`` where ``loss = main + MTP_LOSS_WEIGHT * mtp`` and
+    ``mtp`` is the module's loss on the token after next (the last two
+    positions have no target); ``aux`` then holds the module's expert
+    layers too.  ``terms`` is None without one."""
+    c = config
+    x0, h, aux = streams(base, ids, c, lora, attn_fn)
+    x = _final_norm(h, base["final_norm"], c)
+    emit_remat_saved(_kept_by_group(c, ids.size), ids.size, c.vocab_size)
+    head, rows = _head(base, c)
+    scale = None if c.logit_scale == 1.0 else jnp.float32(c.logit_scale)
+    loss = frozen_head_loss(x, head, ids, scale, head_rows=bool(rows))
+    if not c.mtp:
+        return loss, aux, None
+    with jax.named_scope(MTP_SCOPE):
+        lm = (lora or {}).get("mtp") or {}
+        y = _run_groups(base["mtp"]["layers"], mtp_fuse(base, x0, h, c, lm),
+                        c, c.mtp_groups(), lm.get("layers", {}), attn_fn, aux)
+        y = _final_norm(y, base["mtp"]["final_norm"], c)
+        mtp = frozen_head_loss(y, head, ids, scale, head_rows=bool(rows),
+                               shift=2)
+    return loss + jnp.float32(MTP_LOSS_WEIGHT) * mtp, aux, (loss, mtp)
 
 
 def make_lora_train_step(
@@ -622,41 +759,53 @@ def make_lora_train_step(
     bias frozen.  ``(lora, opt, base, ids) -> (lora, opt, loss,
     counts)`` with ``counts`` as :func:`routing_counts` gives them (None
     for a decoder with no expert layer).  The jitted program is
-    ``step.jitted`` (``jit_decoder_lora_step`` on a device trace).
+    ``step.jitted`` (``jit_decoder_lora_step`` on a device trace); it
+    also returns the MTP module's loss terms (None without one).
 
     While the flight recorder is armed the step keeps each call's
-    counts, still on the device, and ``step.flush_routing()`` writes one
-    ``moe.counts`` record for every call the calling thread made since
-    its last flush (:func:`routing_detail`).  The flush fetches them, so
-    it waits for those steps: call it where the host waits anyway (the
-    end of a round).  Disarmed, nothing is kept or fetched."""
+    counts and loss terms, still on the device, and
+    ``step.flush_routing()`` writes one ``moe.counts`` record (with an
+    MTP module also one ``mtp.loss`` record) for every call the calling
+    thread made since its last flush (:func:`routing_detail`,
+    :func:`mtp_detail`).  The flush fetches them, so it waits for those
+    steps: call it where the host waits anyway (the end of a round).
+    Disarmed, nothing is kept or fetched."""
 
     adam = adam_part(lr, b1, b2, eps)
 
     def loss_fn(lora, base, ids):
-        loss, aux = lora_loss(lora, base, ids, config, attn_fn=attn_fn)
-        return loss, routing_counts(aux)
+        loss, aux, terms = lora_loss_terms(
+            lora, base, ids, config, attn_fn=attn_fn
+        )
+        return loss, (routing_counts(aux), terms)
 
     def decoder_lora_step(lora, opt, base, ids):
-        (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            lora, base, ids
-        )
+        (loss, (counts, terms)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True
+        )(lora, base, ids)
         lora, opt = adam(lora, grads, opt)
-        return lora, opt, loss, counts
+        return lora, opt, loss, counts, terms
 
     jitted = jax.jit(decoder_lora_step)
-    kept = collections.defaultdict(list)  # thread -> [(t, counts, tokens)]
+    # thread -> [(t, counts, terms, tokens)]
+    kept = collections.defaultdict(list)
 
     def step(lora, opt, base, ids):
         out = jitted(lora, opt, base, ids)
-        if telemetry.armed() and out[3] is not None:
-            kept[threading.get_ident()].append((time.time(), out[3], ids.size))
-        return out
+        if telemetry.armed() and (out[3] is not None or out[4] is not None):
+            kept[threading.get_ident()].append(
+                (time.time(), out[3], out[4], ids.size)
+            )
+        return out[:4]
 
     def flush_routing():
-        for t, counts, tokens in kept.pop(threading.get_ident(), ()):
-            telemetry.emit("moe.counts", t_start=t,
-                           detail=routing_detail(counts, config, tokens))
+        for t, counts, terms, tokens in kept.pop(threading.get_ident(), ()):
+            if counts is not None:
+                telemetry.emit("moe.counts", t_start=t,
+                               detail=routing_detail(counts, config, tokens))
+            if terms is not None:
+                telemetry.emit("mtp.loss", t_start=t,
+                               detail=mtp_detail(terms, tokens))
 
     step.jitted, step.flush_routing = jitted, flush_routing
     return step
@@ -672,12 +821,18 @@ def routing_detail(counts, config: DecoderConfig, tokens: int) -> dict:
     import numpy as np
 
     counts = np.asarray(counts)
-    moe_layers = [i for i, s in enumerate(config.layers) if s.ffn == "moe"]
-    assignments = tokens * config.experts.top_k
+    moe_layers = [i for i, s in enumerate(config.stack) if s.ffn == "moe"]
+    e = config.experts
+    assignments = tokens * e.top_k
+    widths = {}
+    if e.latent is not None or e.activation != "swiglu" or e.shared_d_ff:
+        widths = {"latent": e.latent, "d_ff": e.d_ff,
+                  "shared_d_ff": e.shared_width, "activation": e.activation}
     return {
         "tokens": int(tokens),
-        "top_k": config.experts.top_k,
-        "held": list(config.experts.held),
+        "top_k": e.top_k,
+        "held": list(e.held),
+        **widths,
         # rows of one chunk of the sorted buffer (moe._routed's loop)
         "chunk_rows": moe._chunk_rows(tokens, config.experts)[0],
         "layers": [
@@ -691,3 +846,13 @@ def routing_detail(counts, config: DecoderConfig, tokens: int) -> dict:
         ],
         "dropped": int((counts[:, -1] - counts[:, :-1].sum(axis=1)).sum()),
     }
+
+
+def mtp_detail(terms, tokens: int) -> dict:
+    """The ``mtp.loss`` record's detail from a step's loss terms
+    (fetches them): the main and the MTP module's loss, the weight of the
+    latter and the total the step differentiated."""
+    main, mtp = (float(v) for v in terms)
+    return {"tokens": int(tokens), "main": main, "mtp": mtp,
+            "loss_weight": MTP_LOSS_WEIGHT,
+            "total": main + MTP_LOSS_WEIGHT * mtp}

@@ -79,14 +79,19 @@ def remat_saved_bytes(tokens: int, dtype, *, hidden: int, ffn_up: int,
     names of ``REMAT_SAVED_NAMES`` that the models' own code gives (the
     flash kernel's two are counted where it is called), from the layer's
     widths: ``hidden`` the stream's, ``ffn_up`` the dense FFN's or the
-    shared expert's, ``top_k`` an expert layer's selections a token and
-    ``ssm_in`` a state-space layer's input projection's (0: the layer
-    has none).  The one table behind FR ``remat.saved``, here and in
-    ``decoder.py``: a name added to the policy is priced here.  The
-    stream is kept whether or not the second forward is spared the
-    output projection by it (a norm on that product needs it again)."""
+    shared expert's up product (a gated or a squared-ReLU one alike),
+    ``top_k`` an expert layer's selections a token and ``ssm_in`` a
+    state-space layer's input projection's (0: the layer has none; a
+    layer with no FFN, ``ffn_up`` 0, keeps neither ``ffn.up`` nor the
+    stream between its sub-blocks, since it has one sub-block).  The one
+    table behind FR ``remat.saved``, here and in ``decoder.py``: a name
+    added to the policy is priced here.  The stream is kept whether or
+    not the second forward is spared the output projection by it (a
+    norm on that product needs it again)."""
     elem = tokens * jnp.dtype(dtype).itemsize
-    sizes = {FFN_UP_NAME: elem * ffn_up, LAYER_MID_NAME: elem * hidden}
+    sizes = {}
+    if ffn_up:
+        sizes = {FFN_UP_NAME: elem * ffn_up, LAYER_MID_NAME: elem * hidden}
     if top_k:
         sizes[SELECTED_NAME] = tokens * top_k * 4
     if ssm_in:
@@ -1090,12 +1095,15 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def frozen_head_loss(x, head, ids, out_scale=None, *,
-                     head_rows: bool = False) -> jax.Array:
+                     head_rows: bool = False, shift: int = 1) -> jax.Array:
     """``lm_loss(logits[:, :-1], ids[:, 1:])`` of the final-normed hidden
     states ``x`` [B, T, D] through a FROZEN head [D, V] (``out_scale``
     [V] or a scalar on its output: a quantized head's, a model's logit
     multiplier), with no ``[B, T, V]`` array.  ``head_rows``: the head
     is [V, D], an embedding the model ties its head to, read as it lies.
+    ``shift``: position ``i`` predicts ``ids[:, i + shift]`` (2: a
+    multi-token-prediction module's next-but-one token), the last
+    ``shift`` positions have no target.
 
     A ``lax.scan`` over chunks of :func:`head_chunk_rows` rows of the
     flattened ``[B*T, D]``: per chunk the logits (``x``'s and the head's
@@ -1118,11 +1126,12 @@ def frozen_head_loss(x, head, ids, out_scale=None, *,
         return a.reshape(n, chunk, *a.shape[1:])
 
     with jax.named_scope(HEAD_LOSS_SCOPE):
-        # The last position of each sequence has no target: weight 0, as
-        # the rows that pad the last chunk.
-        targets = jnp.roll(ids, -1, axis=1)
+        # The last `shift` positions of each sequence have no target:
+        # weight 0, as the rows that pad the last chunk.
+        targets = jnp.roll(ids, -shift, axis=1)
         weights = jnp.broadcast_to(
-            (jnp.arange(t) < t - 1) / jnp.float32(max(b * (t - 1), 1)), (b, t)
+            (jnp.arange(t) < t - shift)
+            / jnp.float32(max(b * (t - shift), 1)), (b, t)
         )
         return _head_loss(
             head_rows, chunks(x), head, out_scale, chunks(targets),

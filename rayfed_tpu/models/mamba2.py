@@ -15,7 +15,9 @@ width ``P``, a state of ``N`` a head, ``G`` groups, ``d_inner = H P``:
   ``dt <- softplus(dt + dt_bias)``; ``A = -exp(A_log)``;
 - ``y = ssd_scan(x, dt, A, B, C, D)`` (:mod:`rayfed_tpu.ops.ssd`);
 - ``u = y * silu(z)``; ``o = u / sqrt(mean(u^2) + eps) * ssm_norm`` (the
-  gate INSIDE the norm, one group over all of ``d_inner``);
+  gate INSIDE the norm; the mean over each group's ``d_inner / G``
+  channels: ``nemotron_h``'s ``MambaRMSNormGated``, and over all of
+  ``d_inner`` where ``G`` is 1, as granitemoehybrid's);
 - ``mixer(y) = o W_out``.
 
 Parameters: ``w_in``, ``w_out`` (through ``llama._linear``, so a LoRA
@@ -126,10 +128,19 @@ def causal_conv(xbc, w, bias):
     return jax.nn.silu(out).astype(xbc.dtype)
 
 
-def gated_norm(y, z, scale, eps):
-    """``rms_norm(y * silu(z)) * scale`` over the last dim, float32."""
+def gated_norm(y, z, scale, eps, groups: int = 1):
+    """``rms_norm(y * silu(z)) * scale`` over the last dim, float32; with
+    ``groups`` each of that many equal parts of the last dim normed
+    alone."""
     u = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    u = u * jax.lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
+    if groups == 1:
+        u = u * jax.lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
+    else:
+        parts = u.reshape(*u.shape[:-1], groups, -1)
+        parts = parts * jax.lax.rsqrt(
+            jnp.mean(parts * parts, axis=-1, keepdims=True) + eps
+        )
+        u = parts.reshape(u.shape)
     return (u * scale.astype(jnp.float32)).astype(y.dtype)
 
 
@@ -157,5 +168,6 @@ def apply_mixer(y, lp: Params, config: SsmConfig, lget, dtype, eps):
     A = -jnp.exp(lp["A_log"].astype(jnp.float32))
     s = ssd_scan(x, dt, A, B, C, lp["D"], chunk=m.chunk)
     with jax.named_scope("ssm.proj"):
-        o = gated_norm(s.reshape(b, t, m.d_inner), z, lp["ssm_norm"], eps)
+        o = gated_norm(s.reshape(b, t, m.d_inner), z, lp["ssm_norm"], eps,
+                       m.groups)
         return _linear(o, lp["w_out"], lget("w_out"), dtype)

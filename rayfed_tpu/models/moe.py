@@ -234,8 +234,19 @@ class ExpertShareConfig:
     held: Tuple[int, ...] = tuple(range(16))
     top_k: int = 8
     d_model: int = 2048
-    d_ff: int = 1024  # width of one routed expert, and of the shared one
+    d_ff: int = 1024  # width of one routed expert
     route_scale: float = 1.0
+    # The shared expert's width; None: ``d_ff``.
+    shared_d_ff: Optional[int] = None
+    # The width the routed experts run in (NVIDIA's LatentMoE): the
+    # layer projects its input down to it and the routed sum back up, by
+    # two matrices of its own (``w_lat_in``, ``w_lat_out``); the router
+    # and the shared expert read the input at ``d_model``.  None: no
+    # projection, the experts run at ``d_model``.
+    latent: Optional[int] = None
+    # "swiglu": ``(silu(x Wg) * (x Wu)) Wd``, three matrices an expert;
+    # "relu2": ``relu(x Wu)^2 Wd``, two (the shared expert alike).
+    activation: str = "swiglu"
 
     def __post_init__(self):
         if len(set(self.held)) != len(self.held) or not self.held:
@@ -245,34 +256,57 @@ class ExpertShareConfig:
                 f"held ids {self.held} outside the router's "
                 f"{self.num_experts} outputs"
             )
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(
+                f"unknown expert activation {self.activation!r}: one of "
+                f"{', '.join(ACTIVATIONS)}"
+            )
+
+    @property
+    def shared_width(self) -> int:
+        return self.d_ff if self.shared_d_ff is None else self.shared_d_ff
+
+    @property
+    def routed_width(self) -> int:
+        """The width the routed experts read and write."""
+        return self.d_model if self.latent is None else self.latent
 
 
 def init_expert_share(key: jax.Array, config: ExpertShareConfig,
                       dtype=jnp.float32) -> Params:
     """Random share: router over all experts, a non-zero selection bias
     (so that a path that drops it is seen), the held experts stacked on
-    dim 0, one shared expert.  Normal, ``fan_in ** -0.5``."""
-    e, d, f = config.num_experts, config.d_model, config.d_ff
-    n = len(config.held)
+    dim 0, one shared expert, and with ``latent`` the pair of matrices
+    into and out of it.  Normal, ``fan_in ** -0.5``."""
+    c = config
+    e, d, f, fs = c.num_experts, c.d_model, c.d_ff, c.shared_width
+    r = c.routed_width
+    n = len(c.held)
     ks = jax.random.split(key, 8)
 
     def dense(key, *shape, fan_in):
         return (jax.random.normal(key, shape) * fan_in**-0.5).astype(dtype)
 
-    return {
+    params = {
         "router": dense(ks[0], d, e, fan_in=d),
         "router_bias": jax.random.normal(ks[1], (e,), jnp.float32) * 0.1,
         "experts": {
-            "w_gate": dense(ks[2], n, d, f, fan_in=d),
-            "w_up": dense(ks[3], n, d, f, fan_in=d),
-            "w_down": dense(ks[4], n, f, d, fan_in=f),
+            "w_up": dense(ks[3], n, r, f, fan_in=r),
+            "w_down": dense(ks[4], n, f, r, fan_in=f),
         },
         "shared": {
-            "w_gate": dense(ks[5], d, f, fan_in=d),
-            "w_up": dense(ks[6], d, f, fan_in=d),
-            "w_down": dense(ks[7], f, d, fan_in=f),
+            "w_up": dense(ks[6], d, fs, fan_in=d),
+            "w_down": dense(ks[7], fs, d, fan_in=fs),
         },
     }
+    if c.activation == "swiglu":  # relu2 has no gate matrix
+        params["experts"]["w_gate"] = dense(ks[2], n, r, f, fan_in=r)
+        params["shared"]["w_gate"] = dense(ks[5], d, fs, fan_in=d)
+    if c.latent is not None:
+        k_in, k_out = jax.random.split(jax.random.fold_in(key, 1))
+        params["w_lat_in"] = dense(k_in, d, r, fan_in=d)
+        params["w_lat_out"] = dense(k_out, r, d, fan_in=r)
+    return params
 
 
 def route_tokens(params: Params, x: jax.Array, config: ExpertShareConfig):
@@ -455,11 +489,28 @@ def swiglu(x, p, lget, dtype):
     return _linear(jax.nn.silu(gate) * up, p["w_down"], lget("w_down"), dtype)
 
 
+def relu2(x, p, lget, dtype):
+    """``relu(x Wu)^2 Wd`` with each matrix's optional LoRA entry from
+    ``lget(name)``: the non-gated FFN of the ``nemotron_h`` block's
+    experts.  The one FFN-width product carries ``FFN_UP_NAME``, which a
+    rematerialized layer keeps as it keeps ``swiglu``'s up product."""
+    from rayfed_tpu.models.llama import _linear
+
+    up = checkpoint_name(_linear(x, p["w_up"], lget("w_up"), dtype),
+                         FFN_UP_NAME)
+    return _linear(jnp.square(jax.nn.relu(up)), p["w_down"], lget("w_down"),
+                   dtype)
+
+
+# An expert's FFN by ``ExpertShareConfig.activation``.
+ACTIVATIONS = {"swiglu": swiglu, "relu2": relu2}
+
+
 def _chunk_sizes(static, c, group_sizes):
     """``[held + 1]``: of the sorted rows ``[c * rows, (c + 1) * rows)``
     how many are each held expert's, and how many (the rest) nobody's
     here: what the grouped products of chunk ``c`` are given."""
-    _, n_held, rows = static
+    _, n_held, rows, _ = static
     lo = c * rows
     ends = jnp.cumsum(group_sizes[:n_held])
     starts = ends - group_sizes[:n_held]
@@ -469,9 +520,10 @@ def _chunk_sizes(static, c, group_sizes):
 
 def _routed_chunk(static, c, x, weights, experts, elora, route):
     """The held experts' part of the routed sum for the sorted rows
-    ``[c * rows, (c + 1) * rows)``: gather, three grouped products with
-    their adapters, weighted sum back onto the tokens."""
-    k, _, rows = static
+    ``[c * rows, (c + 1) * rows)``: gather, the activation's grouped
+    products (three for swiglu, two for relu2) with their adapters,
+    weighted sum back onto the tokens."""
+    k, _, rows, activation = static
     n_tok, dtype = x.shape[0], x.dtype
     order, sorted_local, group_sizes = route
     lo = c * rows
@@ -487,12 +539,18 @@ def _routed_chunk(static, c, x, weights, experts, elora, route):
         sizes = _chunk_sizes(static, c, group_sizes)
     with jax.named_scope("moe.experts"):
         lget = lambda name: None if elora is None else elora.get(name)
-        gate = _expert_linear(xs, experts["w_gate"], sizes, row_expert,
-                              lget("w_gate"))
-        up = _expert_linear(xs, experts["w_up"], sizes, row_expert,
-                            lget("w_up"))
-        ys = _expert_linear(jax.nn.silu(gate) * up, experts["w_down"], sizes,
-                            row_expert, lget("w_down"))
+        if activation == "swiglu":
+            gate = _expert_linear(xs, experts["w_gate"], sizes, row_expert,
+                                  lget("w_gate"))
+            up = _expert_linear(xs, experts["w_up"], sizes, row_expert,
+                                lget("w_up"))
+            hidden = jax.nn.silu(gate) * up
+        else:
+            hidden = jnp.square(jax.nn.relu(_expert_linear(
+                xs, experts["w_up"], sizes, row_expert, lget("w_up")
+            )))
+        ys = _expert_linear(hidden, experts["w_down"], sizes, row_expert,
+                            lget("w_down"))
     with jax.named_scope("moe.combine"):
         ys = (ys.astype(jnp.float32) * w_row).astype(dtype)
         return _sum_rows(ys, row_token, n_tok)
@@ -578,6 +636,15 @@ def _chunk_rows(n_tok: int, config: ExpertShareConfig) -> Tuple[int, int]:
     return rows, -(-worst // rows)
 
 
+def _latent(v, params, lora, name):
+    """``v`` through one of the latent pair (``w_lat_in``: into the
+    routed experts' width, ``w_lat_out``: back) with its LoRA entry."""
+    from rayfed_tpu.models.llama import _linear
+
+    with jax.named_scope("moe.latent"):
+        return _linear(v, params[name], lora.get(name), v.dtype)
+
+
 def apply_expert_share(
     params: Params,
     x: jax.Array,
@@ -588,12 +655,18 @@ def apply_expert_share(
     """``x`` [N, d] -> (``out`` [N, d], ``aux``): the shared expert plus
     the held experts' part of the routed sum.
 
+    With ``config.latent`` the routed experts run in that width: the
+    input goes down through ``w_lat_in`` before the dispatch and the
+    routed sum up through ``w_lat_out`` after the combine (scope
+    ``moe.latent``); the router and the shared expert read ``x``.
+
     ``aux``: ``counts`` [held] the rows each held expert's grouped
     products were given, ``held_assignments`` () the (token, choice)
     pairs whose expert is held (nothing is dropped, so the counts sum to
     it), ``selected`` [N, k] the experts chosen, ``scores`` [N, E] the
     sigmoid scores they were chosen by.  ``lora`` mirrors ``params``
-    (entries under ``experts`` and ``shared``; the router takes none).
+    (entries under ``experts`` and ``shared``, and ``w_lat_in`` /
+    ``w_lat_out``; the router takes none).
     The held experts' matrices are a FROZEN base: their gradient is
     stopped here, so ``jax.grad`` with respect to them is zero by
     statement, not by omission.
@@ -630,13 +703,18 @@ def apply_expert_share(
         group_sizes,
     )
     chunks = -(-held_assignments // rows)
+    routed_in = _latent(x, params, lora, "w_lat_in") if config.latent else x
     out, multiplied = _routed(
-        (k, n_held, rows), chunks, x, weights,
+        (k, n_held, rows, config.activation), chunks, routed_in, weights,
         jax.lax.stop_gradient(params["experts"]), lora.get("experts"), route,
     )
+    if config.latent:
+        out = _latent(out, params, lora, "w_lat_out")
     with jax.named_scope("moe.shared"):
         shared_lora = lora.get("shared", {})
-        out = out + swiglu(x, params["shared"], shared_lora.get, x.dtype)
+        out = out + ACTIVATIONS[config.activation](
+            x, params["shared"], shared_lora.get, x.dtype
+        )
     aux = {
         "counts": multiplied,
         "held_assignments": held_assignments,

@@ -296,8 +296,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
 # between a layer's sub-blocks: the loss moved by 1e-4 on these toys
 # from the bits of PR 33's parent, 441dd9c), and `strict`, compiled
 # without excess precision, where the parent of PR 38 (0616888) and its
-# tree compute the same bits.
-STRICT = {"compiler_options": {"xla_allow_excess_precision": False}}
+# tree compute the same bits.  The strict build also runs XLA:CPU's
+# contractions on one thread (``xla_cpu_multi_thread_eigen`` off): a
+# multi-threaded contraction splits its sums by the host's thread pool,
+# and Mistral's strict digest read on one host (c9088b17e1d01ccd) was
+# not what another computed from the same tree, or from its parent
+# (870a5ec1deb0434a).  Single-threaded, 0616888 (before the stream was
+# kept) and this tree still agree on all five strict pins here and in
+# `test_parent_bits.py`.
+STRICT = {"compiler_options": {"xla_allow_excess_precision": False,
+                               "xla_cpu_multi_thread_eigen": False}}
 BUILDS = {"default": {}, "strict": STRICT}
 BITS = {
     "default": {
@@ -305,8 +313,8 @@ BITS = {
         "mistral": ("0x1.8bfc1e0000000p+2", "ec0bc06699df0c28"),
     },
     "strict": {
-        "trinity": ("0x1.35605a0000000p+2", "b1e3c40d9e18bb1e"),
-        "mistral": ("0x1.8bfc1e0000000p+2", "c9088b17e1d01ccd"),
+        "trinity": ("0x1.35605a0000000p+2", "7eed91e65ed5b14a"),
+        "mistral": ("0x1.8bfd5c0000000p+2", "1664a93921d34897"),
     },
 }
 
