@@ -794,15 +794,17 @@ def make_lora_train_step(
         out = jitted(lora, opt, base, ids)
         if telemetry.armed() and (out[3] is not None or out[4] is not None):
             kept[threading.get_ident()].append(
-                (time.time(), out[3], out[4], ids.size)
+                (time.time(), out[3], out[4], ids.size, _expert_rank(lora))
             )
         return out[:4]
 
     def flush_routing():
-        for t, counts, terms, tokens in kept.pop(threading.get_ident(), ()):
+        for t, counts, terms, tokens, rank in kept.pop(threading.get_ident(),
+                                                       ()):
             if counts is not None:
-                telemetry.emit("moe.counts", t_start=t,
-                               detail=routing_detail(counts, config, tokens))
+                telemetry.emit("moe.counts", t_start=t, detail=routing_detail(
+                    counts, config, tokens, rank
+                ))
             if terms is not None:
                 telemetry.emit("mtp.loss", t_start=t,
                                detail=mtp_detail(terms, tokens))
@@ -811,13 +813,25 @@ def make_lora_train_step(
     return step
 
 
-def routing_detail(counts, config: DecoderConfig, tokens: int) -> dict:
+def _expert_rank(lora) -> Optional[int]:
+    """The rank of the routed experts' adapters; None where they have
+    none."""
+    for group in lora.get("layers", {}).values():
+        for entry in group.get("moe", {}).get("experts", {}).values():
+            return entry["a"].shape[-1]
+    return None
+
+
+def routing_detail(counts, config: DecoderConfig, tokens: int,
+                   rank: Optional[int] = None) -> dict:
     """The ``moe.counts`` record's detail from a step's counts (fetches
     them).  Per expert layer the rows each held expert's grouped
     products were given, the held share of the step's ``tokens * top_k``
     assignments, and ``dropped``: held assignments that no product was
     given (the layer has no capacity, so 0 unless the chunk loop skipped
-    rows)."""
+    rows).  With the experts' adapter ``rank``, the form of their LoRA
+    bypass (``moe.lora_blocks``): ``lora_blocks`` (1: the dense form) of
+    ``lora_block_experts`` experts each."""
     import numpy as np
 
     counts = np.asarray(counts)
@@ -828,11 +842,16 @@ def routing_detail(counts, config: DecoderConfig, tokens: int) -> dict:
     if e.latent is not None or e.activation != "swiglu" or e.shared_d_ff:
         widths = {"latent": e.latent, "d_ff": e.d_ff,
                   "shared_d_ff": e.shared_width, "activation": e.activation}
+    form = {}
+    if rank is not None:
+        blocks, per_block = moe.lora_blocks(len(e.held), rank)
+        form = {"lora_blocks": blocks, "lora_block_experts": per_block}
     return {
         "tokens": int(tokens),
         "top_k": e.top_k,
         "held": list(e.held),
         **widths,
+        **form,
         # rows of one chunk of the sorted buffer (moe._routed's loop)
         "chunk_rows": moe._chunk_rows(tokens, config.experts)[0],
         "layers": [

@@ -401,6 +401,9 @@ def _grouped_impl() -> str:
 # chip; the products read 45% of the bf16 peak with it at 512 rows an
 # expert of K = 2,048 (PERF.md section 5).
 GMM_TILING = (512, 2048, 512)
+# The v5e MXU's width, which is also the lane width: the columns of a
+# product the systolic array fills in one pass.
+LANES = 128
 # The sorted rows pass through the experts in chunks sized for the
 # expected held assignments of a call (tokens * top_k * held /
 # num_experts) times this headroom; a call takes as many chunks as its
@@ -417,28 +420,36 @@ def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs`` [R, K] rows sorted by group, ``rhs`` [G, K, N],
     ``group_sizes`` [G + 1] whose last entry counts the trailing rows
     that belong to no group here: rows of group ``g`` times ``rhs[g]``;
-    the trailing rows come out zero and are not multiplied."""
-    impl = _grouped_impl()
+    the trailing rows come out zero and are not multiplied.  Its scope,
+    ``grouped_matmul``, marks the experts' base products: the roofline
+    readers charge every kernel under it with one."""
     with jax.named_scope("grouped_matmul"):
-        if impl == "ragged":
-            return jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1])
-        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        return _grouped(lhs, rhs, group_sizes)
 
-        tm, tk, tn = GMM_TILING
-        tiling = (
-            min(tm, lhs.shape[0]), _contraction_tile(lhs.shape[1], tk),
-            min(tn, rhs.shape[2]),
+
+def _grouped(lhs, rhs, group_sizes):
+    """:func:`grouped_matmul` outside its scope (the LoRA bypass's
+    products, :func:`_lora_by_block`)."""
+    impl = _grouped_impl()
+    if impl == "ragged":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1])
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    tm, tk, tn = GMM_TILING
+    tiling = (
+        min(tm, lhs.shape[0]), _contraction_tile(lhs.shape[1], tk),
+        min(tn, rhs.shape[2]),
+    )
+    if lhs.shape[0] % tiling[0]:
+        raise ValueError(
+            f"{lhs.shape[0]} sorted rows are no multiple of the row "
+            f"tile {tiling[0]} (see _chunk_rows)"
         )
-        if lhs.shape[0] % tiling[0]:
-            raise ValueError(
-                f"{lhs.shape[0]} sorted rows are no multiple of the row "
-                f"tile {tiling[0]} (see _chunk_rows)"
-            )
-        return megablox.gmm(
-            lhs, rhs, group_sizes, lhs.dtype, tiling,
-            jnp.zeros((), jnp.int32), None, False,
-            impl == "megablox-interpret",
-        )
+    return megablox.gmm(
+        lhs, rhs, group_sizes, lhs.dtype, tiling,
+        jnp.zeros((), jnp.int32), None, False,
+        impl == "megablox-interpret",
+    )
 
 
 def _contraction_tile(k: int, most: int) -> int:
@@ -453,24 +464,69 @@ def _contraction_tile(k: int, most: int) -> int:
     return most
 
 
+def lora_blocks(experts: int, rank: int) -> Tuple[int, int]:
+    """``(blocks, experts a block)`` of the routed experts' LoRA bypass:
+    a block is as many experts as fill the MXU's columns with their
+    adapters side by side (``LANES // rank``).  One block is the dense
+    form of :func:`_expert_linear`."""
+    per_block = max(1, LANES // rank)
+    return -(-experts // per_block), per_block
+
+
 def _expert_linear(xs, w, group_sizes, row_expert, lora_entry):
     """Sorted rows through their experts' matrix ``w`` [G, in, out], plus
-    the experts' LoRA bypass.  The bypass is two dense products: every
-    row times all experts' ``A`` side by side ([in, G * rank], the MXU's
-    width at 16 experts of rank 8), masked to the row's own expert,
-    times the stacked ``B``: rank-sized grouped products would leave the
-    MXU idle, and an expert no row reached gets an exactly zero
-    gradient."""
+    the experts' LoRA bypass, in the form :func:`lora_blocks` picks from
+    ``G`` and the rank.  Where one block holds every expert (``G * rank``
+    no wider than the MXU) the bypass is two dense products: every row
+    times all experts' ``A`` side by side ([in, G * rank]), masked to the
+    row's own expert, times the stacked ``B`` (rank-sized grouped
+    products would leave the MXU idle).  Wider, the dense form would
+    multiply every row, padding included, by experts it does not use:
+    the bypass is then two grouped products over blocks of experts
+    (:func:`_lora_by_block`).  In either form an expert no row reached
+    gets an exactly zero gradient."""
     out = grouped_matmul(xs, w, group_sizes)
     if lora_entry is None:
         return out
     a, b = (lora_entry[side].astype(xs.dtype) for side in "ab")
     scale = jax.lax.stop_gradient(lora_entry["scale"]).astype(xs.dtype)
     g, d_in, rank = a.shape
+    blocks, per_block = lora_blocks(g, rank)
+    if blocks > 1:
+        return out + _lora_by_block(
+            xs, a, b, group_sizes, row_expert, per_block
+        ) * scale
     own = jax.nn.one_hot(row_expert, g, dtype=xs.dtype)  # padding: zeros
     u = xs @ a.transpose(1, 0, 2).reshape(d_in, g * rank)
     u = (u.reshape(-1, g, rank) * own[:, :, None]).reshape(-1, g * rank)
     return out + (u @ b.reshape(g * rank, -1)) * scale
+
+
+def _lora_by_block(xs, a, b, group_sizes, row_expert, per_block):
+    """``(x A_e) B_e`` for each sorted row of expert ``e`` (``a`` [G, in,
+    rank], ``b`` [G, rank, out]) as two grouped products over blocks of
+    ``per_block`` experts: a row times its block's ``A`` side by side
+    ([in, per_block * rank]), masked to its own expert, times the block's
+    stacked ``B``.  The rows are sorted by expert, so a block's rows are
+    consecutive and its group size is its experts' summed; the trailing
+    rows (``group_sizes[G]``) are neither multiplied nor written.  Zero
+    experts fill the last block where ``per_block`` does not divide
+    ``G``.  Outside ``grouped_matmul``'s scope, so that no reader
+    charges a bypass product as a base one."""
+    g, d_in, rank = a.shape
+    blocks = -(-g // per_block)
+    fill = ((0, blocks * per_block - g), (0, 0), (0, 0))
+    a = jnp.pad(a, fill).reshape(blocks, per_block, d_in, rank)
+    a = a.transpose(0, 2, 1, 3).reshape(blocks, d_in, per_block * rank)
+    b = jnp.pad(b, fill).reshape(blocks, per_block * rank, -1)
+    sizes = jnp.pad(group_sizes[:g], fill[0]).reshape(blocks, per_block)
+    sizes = jnp.concatenate([sizes.sum(1), group_sizes[g:]])
+    # a trailing row's own column is its expert's modulo the block, but
+    # the first product leaves that row zero
+    own = jax.nn.one_hot(row_expert % per_block, per_block, dtype=xs.dtype)
+    u = _grouped(xs, a, sizes).reshape(-1, per_block, rank)
+    u = (u * own[:, :, None]).reshape(-1, per_block * rank)
+    return _grouped(u, b, sizes)
 
 
 def swiglu(x, p, lget, dtype):

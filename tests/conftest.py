@@ -32,3 +32,17 @@ force_cpu_devices(8)
 # and a cache miss (or corrupt read) falls back to a normal compile.
 os.environ["JAX_COMPILATION_CACHE_DIR"] = use_compilation_cache()
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(params=["ragged", "megablox"])
+def grouped(request, monkeypatch):
+    """Both grouped products of ``models/moe.py``: ``ragged_dot`` (the
+    CPU's) and the Pallas kernel under the interpreter."""
+    if request.param == "megablox":
+        from rayfed_tpu.models import moe
+
+        monkeypatch.setattr(moe, "_grouped_impl", lambda: "megablox-interpret")
+    return request.param

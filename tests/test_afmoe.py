@@ -34,15 +34,6 @@ SPECS = (
 )
 
 
-@pytest.fixture(params=["ragged", "megablox"])
-def grouped(request, monkeypatch):
-    """Both grouped products: ``ragged_dot`` (the CPU's) and the Pallas
-    kernel under the interpreter."""
-    if request.param == "megablox":
-        monkeypatch.setattr(moe, "_grouped_impl", lambda: "megablox-interpret")
-    return request.param
-
-
 def toy_config(dtype=jnp.float32, held=HELD, **kw):
     experts = moe.ExpertShareConfig(
         num_experts=E, held=held, top_k=TOPK, d_model=D, d_ff=16,

@@ -496,9 +496,10 @@ def test_the_step_writes_its_records_while_armed():
     """While the recorder is armed: ``remat.saved`` prices what every
     group keeps (no ``ffn.up`` or ``layer.mid`` where a layer has no
     FFN; the shared expert's up product at its own width; the MTP
-    module's group), ``moe.counts`` carries the latent, the widths and
-    the activation, and one ``mtp.loss`` record a step gives both terms
-    and the weight; disarmed, nothing is kept."""
+    module's group), ``moe.counts`` carries the latent, the widths, the
+    activation and the form of the experts' LoRA bypass, and one
+    ``mtp.loss`` record a step gives both terms and the weight;
+    disarmed, nothing is kept."""
     cfg, base, adapters, ids = make(cfg=toy_config(remat=True))
     step = decoder.make_lora_train_step(cfg)
     opt = llama.init_adam(adapters)
@@ -531,6 +532,8 @@ def test_the_step_writes_its_records_while_armed():
     counts = records["moe.counts"].detail
     assert (counts["latent"], counts["d_ff"], counts["shared_d_ff"],
             counts["activation"]) == (8, 12, 24, "relu2")
+    # four held experts of rank 2 fill no MXU: one block, the dense form
+    assert (counts["lora_blocks"], counts["lora_block_experts"]) == (1, 64)
     assert [row["layer"] for row in counts["layers"]] == [0, 1, 2, 4, 5, 6]
     (record,) = mtp
     detail = record.detail
@@ -543,7 +546,9 @@ def test_the_step_writes_its_records_while_armed():
 
 def test_a_model_without_mtp_or_latent_keeps_its_records():
     """Trinity's kind of expert layer writes ``moe.counts`` without the
-    new widths and no ``mtp.loss``; its step's outputs are as before."""
+    new widths and no ``mtp.loss``; its step's outputs are as before, and
+    its experts' LoRA bypass is the dense form (two held experts of rank
+    8 are 16 of the MXU's 128 columns)."""
     experts = moe.ExpertShareConfig(num_experts=8, held=(0, 1), top_k=2,
                                     d_model=D, d_ff=16)
     cfg = decoder.DecoderConfig(
@@ -566,5 +571,6 @@ def test_a_model_without_mtp_or_latent_keeps_its_records():
         telemetry.uninstall()
     assert len(out) == 4 and "mtp.loss" not in phases
     assert not {"latent", "shared_d_ff", "activation"} & set(counts)
+    assert (counts["lora_blocks"], counts["lora_block_experts"]) == (1, 16)
     assert "w_gate" in base["layers"][0]["moe"]["experts"]
     assert "w_lat_in" not in base["layers"][0]["moe"]

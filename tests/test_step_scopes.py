@@ -33,10 +33,12 @@ _OP = re.compile(
 )
 
 
-def lowered_op_names(text: str) -> list:
+def lowered_op_names(text: str, every_call: bool = False) -> list:
     """``[(operation, full name, its line), ...]`` of a lowered module
     printed with debug info: every ``stablehlo`` operation under every
-    name stack its function is called with."""
+    name stack its function is called with (``every_call``: once for
+    each chain of calls that reaches it, so that a function called twice
+    under one name counts twice)."""
     alias = dict(_ALIAS.findall(text))
     ops, callers, inside = [], {}, None
     for line in text.splitlines():
@@ -58,10 +60,12 @@ def lowered_op_names(text: str) -> list:
 
     def stacks_of(func):
         if func not in stacks:
-            stacks[func] = {
+            stacks[func] = [
                 join(outer, local) for caller, local in callers.get(func, ())
                 for outer in stacks_of(caller)
-            }
+            ]
+            if not every_call:
+                stacks[func] = set(stacks[func])
         return stacks[func]
 
     return [

@@ -455,3 +455,64 @@ def test_state_space_hybrid_step_compiles_and_one_step_fits_beside_two_parties(
     # gives 12.0: ROADMAP Queue 1 asks to re-base it.
     assert temp / 1e9 < 13.2
     assert memory.peak_memory_in_bytes / 1e9 < 11.9
+
+
+def test_the_lora_bypass_by_block_adds_no_kernel_the_readers_charge(
+    monkeypatch,
+):
+    """A Nemotron-shaped expert layer (64 held squared-ReLU experts in a
+    latent, rank-8 adapters: the bypass in four blocks of 16) lowered for
+    the chip and differentiated: ``expert_mm_roofline`` and
+    ``latent_expert_mm_roofline`` charge every kernel under
+    ``grouped_matmul`` with a base product's FLOPs, so those are the
+    base products' alone, as many as without adapters; the bypass's own
+    kernels (a second forward's two products a matrix, a backward's two
+    and two ``tgmm``) sit under ``moe.experts`` beside them, and the
+    chip's compiler takes them."""
+    from rayfed_tpu.models import moe
+    from tests.test_step_scopes import lowered_op_names
+
+    monkeypatch.setattr(moe, "_grouped_impl", lambda: "megablox")
+    held, rank = 64, 8
+    cfg = moe.ExpertShareConfig(
+        num_experts=128, held=tuple(range(held)), top_k=4, d_model=256,
+        d_ff=256, shared_d_ff=256, latent=128, activation="relu2",
+    )
+    assert moe.lora_blocks(held, rank) == (4, 16)
+    params = jax.eval_shape(
+        lambda: moe.init_expert_share(jax.random.PRNGKey(0), cfg,
+                                      jnp.bfloat16)
+    )
+    adapters = {"experts": {
+        name: {
+            "a": jax.ShapeDtypeStruct((held, w.shape[1], rank), jnp.float32),
+            "b": jax.ShapeDtypeStruct((held, rank, w.shape[2]), jnp.float32),
+            "scale": jax.ShapeDtypeStruct((), jnp.float32),
+        } for name, w in params["experts"].items()
+    }}
+
+    def grads(params, x, adapters):
+        def loss(x, adapters):
+            out, _ = moe.apply_expert_share(params, x, cfg, lora=adapters)
+            return jnp.sum(out.astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1))(x, adapters)
+
+    x = jax.ShapeDtypeStruct((512, cfg.d_model), jnp.bfloat16)
+    kernels = {}
+    for name, lora in (("without", {}), ("with", adapters)):
+        lowered = jax.jit(grads).lower(*_on_chip((params, x, lora)))
+        kernels[name] = [
+            full for op, full, line in lowered_op_names(
+                lowered.as_text(debug_info=True), every_call=True
+            ) if op == "stablehlo.custom_call" and "tpu_custom_call" in line
+        ]
+        if name == "with":
+            lowered.compile()
+    base = lambda names: sorted(n for n in names if "grouped_matmul" in n)
+    # a second forward's up and down product, their transposes, and the
+    # frozen base's `tgmm`s, which the backward rule discards
+    assert len(base(kernels["without"])) == 6
+    assert base(kernels["with"]) == base(kernels["without"])
+    bypass = [n for n in kernels["with"] if "grouped_matmul" not in n]
+    assert len(bypass) == 12 and all("moe.experts" in n for n in bypass)
