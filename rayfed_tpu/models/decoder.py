@@ -10,8 +10,17 @@ k_pe`` against ONE rotary key head all query heads share, and values of
 their own width; or no attention at all but a state-space layer, the
 Mamba-2 mixer of :mod:`rayfed_tpu.models.mamba2`: one input projection
 split three ways, a causal depthwise convolution, the chunked scan of
-:mod:`rayfed_tpu.ops.ssd`, a gated norm, an output projection) and its
-FFN kind (dense SwiGLU, routed + shared experts through
+:mod:`rayfed_tpu.ops.ssd`, a gated norm, an output projection; or
+BLOCK-SPARSE attention, InfLLM-v2's: no positions, and beyond
+``SparseConfig.dense_len`` tokens each query attends only to the key
+blocks its K/V group selected, by scores against mean-pooled compressed
+keys (:mod:`rayfed_tpu.ops.sparse_attention`: the selection in XLA, a
+Pallas kernel pair whose key blocks are data, the choice kept by the
+checkpoint); or DECAYED LINEAR attention, Lightning's: ``q``, ``k``,
+``v`` a head each, rotated, a state a head that decays by a constant
+rate a token (:class:`LightningConfig`), computed by the chunked scan of
+:mod:`rayfed_tpu.ops.ssd` with one group a head, an output norm and a
+gate) and its FFN kind (dense SwiGLU, routed + shared experts through
 :func:`rayfed_tpu.models.moe.apply_expert_share`, or none: a block that
 is its mixer alone, the ``nemotron_h`` block's single-part layers paired
 so that a mixer block and the FFN block after it are one layer, each
@@ -29,11 +38,13 @@ the head width, a sigmoid gate on the attention output, norms after
 each sub-block, a scaled embedding; rotary frequencies may be YaRN's.
 Off by default, the multipliers of the ``granitemoehybrid`` block: one
 on each residual branch, a score scale that is not ``head_dim ** -0.5``,
-one on the logits, and a head tied to the embedding.
+one on the logits, and a head tied to the embedding (MiniCPM's muP
+multipliers are three of them).
 
 Consecutive layers with one FFN kind and one kind of mixer PARAMETERS
-(window and full attention share theirs; latent attention and a
-state-space layer each have their own) are a GROUP: their parameters are
+(window and full attention share theirs; latent attention, a
+state-space layer, block-sparse and linear attention each have their
+own) are a GROUP: their parameters are
 stacked on a leading dim and the forward pass is one ``lax.scan`` a
 group (one compiled body however many layers; ``remat`` is
 ``jax.checkpoint`` of that body with ``llama.py``'s policy
@@ -62,7 +73,7 @@ static); the branches' residuals are of one shape and share the
 :func:`rayfed_tpu.models.lora.init_lora` mirrors it with the group's
 index as a string.  :func:`unstack` gives either tree layer by layer.
 
-Four configurations run through it: the AFMoE family's
+Five configurations run through it: the AFMoE family's
 (``benchmark/families/afmoe_lm.py``, reference in
 ``benchmark/reference/afmoe.py``), the ``kimi_k2`` / DeepSeek-V3
 block (``benchmark/families/kimi_k2_lm.py``, ``reference/kimi_k2.py``),
@@ -72,7 +83,10 @@ all of whose layers are latent, the ``granitemoehybrid`` block
 full attention without positions, a dense FFN after each, and the
 ``nemotron_h`` block (``benchmark/families/nemotron_h_lm.py``,
 ``reference/nemotron_h.py``): single-part Mamba-2, latent-expert and
-attention blocks, and an MTP module.  Helpers are shared with
+attention blocks, and an MTP module; and the ``minicpm_sala`` block
+(``benchmark/families/minicpm_sala_lm.py``, ``reference/minicpm_sala.py``):
+one block-sparse layer to three of linear attention, a dense FFN after
+each.  Helpers are shared with
 ``llama.py`` by import (``_rms_norm``, ``rope_tables``, ``apply_rope``,
 ``_linear``, ``lm_loss``, ``frozen_head_loss``, ``adam_part``,
 ``embed_part``);
@@ -112,7 +126,10 @@ from rayfed_tpu.models.llama import (
     rope_tables,
 )
 from rayfed_tpu.models.mamba2 import SsmConfig
+from rayfed_tpu.ops import sparse_attention as sparse
 from rayfed_tpu.ops.attention import dot_product_attention
+from rayfed_tpu.ops.sparse_attention import SparseConfig
+from rayfed_tpu.ops.ssd import ssd_scan
 
 Params = Dict[str, Any]
 
@@ -129,7 +146,7 @@ ALL_LINEAR = (r"/w([qkvoz]|q_[ab]|kv_[ab])$",
 # scanned group only where these agree.
 MIXER_PARAMS = {
     "window": "attention", "full": "attention", "latent": "latent",
-    "ssm": "ssm",
+    "ssm": "ssm", "sparse": "sparse", "lightning": "lightning",
 }
 FFN_KINDS = ("dense", "moe", "none")
 # The scopes of the MTP module on a device trace: all of it, and its
@@ -141,7 +158,9 @@ MTP_SCOPE, MTP_FUSE_SCOPE = "mtp", "mtp.fuse"
 class LayerSpec:
     # "window" (with RoPE) | "full" (no positions) | "latent" (RoPE on a
     # part of the head, DecoderConfig.latent) | "ssm" (no attention: the
-    # Mamba-2 mixer, DecoderConfig.ssm)
+    # Mamba-2 mixer, DecoderConfig.ssm) | "sparse" (no positions, each
+    # query over its selected key blocks, DecoderConfig.sparse) |
+    # "lightning" (decayed linear attention, DecoderConfig.lightning)
     mixer: str = "window"
     ffn: str = "dense"  # "dense" | "moe" | "none" (the mixer alone)
 
@@ -183,6 +202,30 @@ MTP_LOSS_WEIGHT = 0.1
 
 
 @dataclasses.dataclass(frozen=True)
+class LightningConfig:
+    """A decayed linear-attention layer (Lightning Attention-2,
+    arXiv:2401.04658; MiniMax-01, arXiv:2501.08313): a head's state
+    decays by ``exp(-r_h)`` a token, ``r_h = 2 ** (-8 (h + 1) / H) (1 -
+    l / (depth - 1) + 1e-5)`` for layer ``l`` of a model ``depth``
+    layers deep (MiniMax-01's ALiBi-style slopes; ``H`` a power of two),
+    and the scan runs in chunks of ``chunk`` tokens
+    (:func:`rayfed_tpu.ops.ssd.ssd_scan`, one group a head)."""
+
+    depth: int = 32  # the published layers the slopes' depth factor reads
+    chunk: int = 256
+
+    def decay_rates(self, layer: int, heads: int):
+        """``r_h`` [heads] float32 of layer ``layer``."""
+        import numpy as np
+
+        if heads & (heads - 1):
+            raise ValueError(f"{heads} heads: the slopes need a power of two")
+        slopes = 2.0 ** (-8.0 * np.arange(1, heads + 1) / heads)
+        depth = 1.0 - layer / max(self.depth - 1, 1) + 1e-5
+        return jnp.asarray(slopes * depth, jnp.float32)
+
+
+@dataclasses.dataclass(frozen=True)
 class MtpConfig:
     """A multi-token-prediction module after the layers: its own layers
     (their widths are the decoder's)."""
@@ -215,6 +258,8 @@ class DecoderConfig:
     rope_scaling: Optional[YarnScaling] = None
     latent: Optional[LatentConfig] = None  # a "latent" layer's widths
     ssm: Optional[SsmConfig] = None  # an "ssm" layer's widths
+    sparse: Optional[SparseConfig] = None  # a "sparse" layer's sizes
+    lightning: Optional[LightningConfig] = None  # a "lightning" layer's
     # The block's optional parts.
     qk_norm: bool = True  # RMS norm of q and k over the head width
     output_gate: bool = True  # attention output * sigmoid(x wz)
@@ -236,7 +281,7 @@ class DecoderConfig:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if any(s.ffn == "moe" for s in self.stack) and self.experts is None:
             raise ValueError("a layer with ffn='moe' needs config.experts")
-        for kind in ("latent", "ssm"):
+        for kind in ("latent", "ssm", "sparse", "lightning"):
             if getattr(self, kind) is None and any(
                 s.mixer == kind for s in self.stack
             ):
@@ -279,7 +324,7 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     def dense(key, *shape, fan_in):
         return (jax.random.normal(key, shape) * fan_in**-0.5).astype(pdt)
 
-    def layer(key, spec: LayerSpec) -> Params:
+    def layer(key, spec: LayerSpec, index: int) -> Params:
         ks = jax.random.split(key, 9)
         ones = lambda n: jnp.ones((n,), pdt)
         # `attn_norm` is the norm before the mixer, whatever the mixer
@@ -305,14 +350,21 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
                 wo=dense(ks[4], h * m.v_dim, d, fan_in=h * m.v_dim),
             )
         else:
+            # a linear-attention layer's keys and values have a head each
+            heads_kv = h if spec.mixer == "lightning" else kv
             lp.update(
                 wq=dense(ks[0], d, h * dh, fan_in=d),
-                wk=dense(ks[1], d, kv * dh, fan_in=d),
-                wv=dense(ks[2], d, kv * dh, fan_in=d),
+                wk=dense(ks[1], d, heads_kv * dh, fan_in=d),
+                wv=dense(ks[2], d, heads_kv * dh, fan_in=d),
                 wo=dense(ks[4], h * dh, d, fan_in=h * dh),
             )
             if c.qk_norm:
                 lp.update(q_norm=ones(dh), k_norm=ones(dh))
+            if spec.mixer == "lightning":
+                # the output's norm, a head at a time; the state's decay
+                # rates (a buffer, float32 whatever the parameter type)
+                lp.update(o_norm=ones(h * dh),
+                          decay=c.lightning.decay_rates(index, h))
         if c.output_gate and spec.mixer != "ssm":
             lp["wz"] = dense(ks[3], d, lp["wo"].shape[0], fan_in=d)
         if c.post_norms:
@@ -331,7 +383,7 @@ def init_decoder(key: jax.Array, config: DecoderConfig) -> Params:
     def stacked(keys, layers, groups, offset=0):
         return [
             jax.tree_util.tree_map(stack, *(
-                layer(keys[i - offset], layers[i - offset])
+                layer(keys[i - offset], layers[i - offset], i)
                 for i in range(start, stop)
             ))
             for start, stop in groups
@@ -479,6 +531,7 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
     if (mixer is None) == (attention is None):
         raise TypeError("apply_block takes mixer= (or attention=, not both)")
     kind = attention if mixer is None else mixer
+    mixed = None  # what the mixer reports (a block-sparse layer's choice)
     if isinstance(kind, str) and kind == "ssm":
         with jax.named_scope("ssm.proj"):
             y = _rms_norm(x, lp["attn_norm"], c.rms_eps)
@@ -487,10 +540,12 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
             if c.post_norms:
                 o = _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
             x = _add(x, o, c.residual_scale)
+    elif isinstance(kind, str) and kind == "lightning":
+        x = _lightning_block(x, lp, c, lget)
     else:
-        x = _attention_block(x, lp, c, kind, attn_fn, lget)
+        x, mixed = _attention_block(x, lp, c, kind, attn_fn, lget)
     if ffn == "none":
-        return x, None
+        return x, mixed
     # kept by a checkpointed layer: its second forward starts here
     x = checkpoint_name(x, LAYER_MID_NAME)
     b, t, _ = x.shape
@@ -506,12 +561,16 @@ def apply_block(x, lp, config: DecoderConfig, *, ffn: str, mixer=None,
         f = f.reshape(b, t, -1)
     if c.post_norms:
         f = _rms_norm(f, lp["post_mlp_norm"], c.rms_eps)
+    if mixed is not None:
+        aux = dict(mixed, **(aux or {}))
     return _add(x, f, c.residual_scale), aux
 
 
 def _attention_block(x, lp, config: DecoderConfig, attention, attn_fn, lget):
     """``x`` plus an attention layer's first sub-block: norm,
-    projections, the kernel of its kind, gate, output projection."""
+    projections, the kernel of its kind, gate, output projection; and
+    what a block-sparse layer reports of its selection (None for
+    another kind, or at a length it attends densely)."""
     c = config
     b, t, _ = x.shape
     h, kv, dh, dtype = c.num_heads, c.num_kv_heads, c.head_dim, c.dtype
@@ -532,7 +591,10 @@ def _attention_block(x, lp, config: DecoderConfig, attention, attn_fn, lget):
                 q = _rms_norm(q, lp["q_norm"], c.rms_eps)
                 k = _rms_norm(k, lp["k_norm"], c.rms_eps)
     attend = functools.partial(_attend, config=c, attn_fn=attn_fn)
-    if isinstance(attention, str):
+    mixed = None
+    if isinstance(attention, str) and attention == "sparse":
+        o, mixed = _sparse_attend(q, k, v, c, attn_fn)
+    elif isinstance(attention, str):
         o = attend(q, k, v, rope, kind=attention)
     else:
         o = jax.lax.cond(
@@ -540,6 +602,76 @@ def _attention_block(x, lp, config: DecoderConfig, attention, attn_fn, lget):
             functools.partial(attend, kind="full"), q, k, v, rope,
         )
     with jax.named_scope("attn.proj"):
+        o = o.reshape(b, t, -1)
+        if c.output_gate:
+            z = _linear(y, lp["wz"], lget("wz"), dtype)
+            o = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(dtype)
+        o = _linear(o, lp["wo"], lget("wo"), dtype)
+        if c.post_norms:
+            o = _rms_norm(o, lp["post_attn_norm"], c.rms_eps)
+        return _add(x, o, c.residual_scale), mixed
+
+
+def _sparse_attend(q, k, v, config: DecoderConfig, attn_fn):
+    """A block-sparse layer's attention (no positions) and its report:
+    up to ``dense_len`` tokens causal attention through ``attn_fn``
+    (``attn.sparse``, no report); beyond, the selection
+    (``attn.select``: compressed keys, scores, top-k, no gradient) and
+    the kernel over the selected blocks (``attn.sparse``), reporting
+    ``selected`` [B, KV, T, topk], the mean causal keys and blocks a
+    query visits and the mean (query tile, key tile) pairs the kernels
+    walk a K/V head."""
+    s = config.sparse
+    scaled = {} if config.attn_scale is None else {"sm_scale": config.attn_scale}
+    t = q.shape[1]
+    if t <= s.dense_len:
+        with jax.named_scope("attn.sparse"):
+            return attn_fn(q, k, v, causal=True, **scaled), None
+    with jax.named_scope("attn.select"):
+        chosen = sparse.select_blocks(
+            jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), s
+        )
+        arrays = sparse.selection_arrays(chosen, t, s)
+        keys, blocks = sparse.visit_stats(chosen, s.block_size)
+        pairs = jnp.mean(arrays[1][2].astype(jnp.float32))
+    with jax.named_scope("attn.sparse"):
+        o = sparse.sparse_attention(q, k, v, arrays, s, **scaled)
+    return o, {"selected": chosen, "visited_keys": keys, "blocks": blocks,
+               "pairs": pairs}
+
+
+def _lightning_block(x, lp, config: DecoderConfig, lget):
+    """``x`` plus a decayed linear-attention layer's first sub-block:
+    norm; ``q``, ``k``, ``v`` a head each, RMS-normed (``q``, ``k``) and
+    rotated over the whole head; per head ``S_t = exp(-r_h) S_{t-1} +
+    k_t v_t^T``, ``o_t = (q_t / sqrt(d))^T S_t`` (``attn.lightning``:
+    :func:`ssd_scan` with ``dt = 1``, ``A = -r``, ``B = k``, ``C = q /
+    sqrt(d)``, ``x = v``, ``D = 0``, one group a head); the output
+    RMS-normed a head at a time, gated, projected."""
+    c = config
+    b, t, _ = x.shape
+    h, dh, dtype = c.num_heads, c.head_dim, c.dtype
+    rope = rope_tables(jnp.arange(t), dh, c.rope_theta, c.rope_scaling)
+    with jax.named_scope("attn.proj"):
+        y = _rms_norm(x, lp["attn_norm"], c.rms_eps)
+        q, k, v = (
+            _linear(y, lp[name], lget(name), dtype).reshape(b, t, h, dh)
+            for name in ("wq", "wk", "wv")
+        )
+        if c.qk_norm:
+            q = _rms_norm(q, lp["q_norm"], c.rms_eps)
+            k = _rms_norm(k, lp["k_norm"], c.rms_eps)
+        # the score scale rides q's rotation: no pass of its own
+        q = apply_rope(q, *(table * dh ** -0.5 for table in rope))
+        k = apply_rope(k, *rope)
+    with jax.named_scope("attn.lightning"):
+        o = ssd_scan(
+            v, jnp.ones((b, t, h), jnp.float32),
+            -lp["decay"].astype(jnp.float32), k, q,
+            jnp.zeros((h,), jnp.float32), chunk=c.lightning.chunk,
+        )
+    with jax.named_scope("attn.proj"):
+        o = _rms_norm(o, lp["o_norm"].reshape(h, dh), c.rms_eps)
         o = o.reshape(b, t, -1)
         if c.output_gate:
             z = _linear(y, lp["wz"], lget("wz"), dtype)
@@ -681,22 +813,34 @@ def mtp_fuse(params, x0, h, config: DecoderConfig, lora=None):
 def routing_counts(aux) -> Optional[jax.Array]:
     """``[expert layers, held + 1]`` int32: every expert layer's rows per
     held expert, and in the last column its held assignments."""
-    if not aux:
-        return None
-    return jnp.stack([
+    rows = [
         jnp.concatenate([a["counts"], a["held_assignments"][None]])
-        for _, a in sorted(aux.items())
-    ])
+        for _, a in sorted(aux.items()) if "counts" in a
+    ]
+    return jnp.stack(rows) if rows else None
 
 
-def _kept_by_group(config: DecoderConfig, tokens: int):
+def selection_stats(aux) -> Optional[jax.Array]:
+    """``[block-sparse layers, 3]`` float32: every such layer's mean
+    causal keys and selected blocks a query, and tile pairs its kernels
+    walk a K/V head; None where no layer selected."""
+    rows = [
+        jnp.stack([a["visited_keys"], a["blocks"], a["pairs"]])
+        for _, a in sorted(aux.items()) if "visited_keys" in a
+    ]
+    return jnp.stack(rows) if rows else None
+
+
+def _kept_by_group(config: DecoderConfig, shape):
     """``{scanned group: (layers, {name: bytes a layer keeps})}`` by
-    ``llama.remat_saved_bytes`` at the widths of the group's layers;
-    empty without ``remat``."""
+    ``llama.remat_saved_bytes`` at the widths of the group's layers, for
+    ids of ``shape`` [B, T]; empty without ``remat``."""
     c, kept = config, {}
+    (batch, t), tokens = shape, shape[0] * shape[1]
     for start, stop in c.groups() + c.mtp_groups() if c.remat else ():
         spec = c.stack[start]
         routed = spec.ffn == "moe"
+        selects = spec.mixer == "sparse" and t > c.sparse.dense_len
         kept[f"layers{start}-{stop - 1}"] = (stop - start, remat_saved_bytes(
             tokens, c.dtype, hidden=c.hidden_size,
             # the dense FFN's or the shared expert's up product, if any
@@ -705,6 +849,9 @@ def _kept_by_group(config: DecoderConfig, tokens: int):
             ),
             top_k=c.experts.top_k if routed else 0,
             ssm_in=c.ssm.proj_dim if spec.mixer == "ssm" else 0,
+            selection=sparse.selection_bytes(
+                batch, t, c.num_kv_heads, c.sparse
+            ) if selects else 0,
         ))
     return kept
 
@@ -730,7 +877,7 @@ def lora_loss_terms(lora, base, ids, config: DecoderConfig, *,
     c = config
     x0, h, aux = streams(base, ids, c, lora, attn_fn)
     x = _final_norm(h, base["final_norm"], c)
-    emit_remat_saved(_kept_by_group(c, ids.size), ids.size, c.vocab_size)
+    emit_remat_saved(_kept_by_group(c, ids.shape), ids.size, c.vocab_size)
     head, rows = _head(base, c)
     scale = None if c.logit_scale == 1.0 else jnp.float32(c.logit_scale)
     loss = frozen_head_loss(x, head, ids, scale, head_rows=bool(rows))
@@ -763,11 +910,12 @@ def make_lora_train_step(
     also returns the MTP module's loss terms (None without one).
 
     While the flight recorder is armed the step keeps each call's
-    counts and loss terms, still on the device, and
-    ``step.flush_routing()`` writes one ``moe.counts`` record (with an
-    MTP module also one ``mtp.loss`` record) for every call the calling
-    thread made since its last flush (:func:`routing_detail`,
-    :func:`mtp_detail`).  The flush fetches them, so it waits for those
+    counts, loss terms and block-sparse layers' visits, still on the
+    device, and ``step.flush_routing()`` writes one ``moe.counts`` record
+    (with an MTP module also one ``mtp.loss`` record, with a block-sparse
+    layer that selects one ``attn.select`` record) for every call the
+    calling thread made since its last flush (:func:`routing_detail`,
+    :func:`mtp_detail`, :func:`selection_detail`).  The flush fetches them, so it waits for those
     steps: call it where the host waits anyway (the end of a round).
     Disarmed, nothing is kept or fetched."""
 
@@ -777,30 +925,32 @@ def make_lora_train_step(
         loss, aux, terms = lora_loss_terms(
             lora, base, ids, config, attn_fn=attn_fn
         )
-        return loss, (routing_counts(aux), terms)
+        return loss, (routing_counts(aux), terms, selection_stats(aux))
 
     def decoder_lora_step(lora, opt, base, ids):
-        (loss, (counts, terms)), grads = jax.value_and_grad(
+        (loss, (counts, terms, visits)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(lora, base, ids)
         lora, opt = adam(lora, grads, opt)
-        return lora, opt, loss, counts, terms
+        return lora, opt, loss, counts, terms, visits
 
     jitted = jax.jit(decoder_lora_step)
-    # thread -> [(t, counts, terms, tokens)]
+    # thread -> [(t, counts, terms, visits, ids shape, rank)]
     kept = collections.defaultdict(list)
 
     def step(lora, opt, base, ids):
         out = jitted(lora, opt, base, ids)
-        if telemetry.armed() and (out[3] is not None or out[4] is not None):
+        if telemetry.armed() and any(v is not None for v in out[3:]):
             kept[threading.get_ident()].append(
-                (time.time(), out[3], out[4], ids.size, _expert_rank(lora))
+                (time.time(), *out[3:], ids.shape, _expert_rank(lora))
             )
         return out[:4]
 
     def flush_routing():
-        for t, counts, terms, tokens, rank in kept.pop(threading.get_ident(),
-                                                       ()):
+        for t, counts, terms, visits, shape, rank in kept.pop(
+            threading.get_ident(), ()
+        ):
+            tokens = shape[0] * shape[1]
             if counts is not None:
                 telemetry.emit("moe.counts", t_start=t, detail=routing_detail(
                     counts, config, tokens, rank
@@ -808,6 +958,9 @@ def make_lora_train_step(
             if terms is not None:
                 telemetry.emit("mtp.loss", t_start=t,
                                detail=mtp_detail(terms, tokens))
+            if visits is not None:
+                telemetry.emit("attn.select", t_start=t,
+                               detail=selection_detail(visits, config, shape))
 
     step.jitted, step.flush_routing = jitted, flush_routing
     return step
@@ -864,6 +1017,31 @@ def routing_detail(counts, config: DecoderConfig, tokens: int,
             for i, row in zip(moe_layers, counts)
         ],
         "dropped": int((counts[:, -1] - counts[:, :-1].sum(axis=1)).sum()),
+    }
+
+
+def selection_detail(visits, config: DecoderConfig, shape) -> dict:
+    """The ``attn.select`` record's detail from a step's
+    :func:`selection_stats` (fetches them), per block-sparse layer: the
+    mean causal keys a query visited and blocks it selected, beside the
+    causal keys dense attention would visit (``(T + 1) / 2``); and the
+    (query tile, key tile) pairs the kernels walked a K/V head, beside
+    the causal pairs, the most a selection can make them walk."""
+    import numpy as np
+
+    s = config.sparse
+    layers = [i for i, spec in enumerate(config.stack)
+              if spec.mixer == "sparse"]
+    return {
+        "batch": int(shape[0]), "tokens": int(shape[1]),
+        "block": s.block_size, "topk": s.topk,
+        "dense_keys": (shape[1] + 1) / 2,
+        "causal_pairs": sparse.causal_pairs(shape[1] // s.tile_for(shape[1])),
+        "layers": [
+            {"layer": i, "visited_keys": float(row[0]),
+             "blocks": float(row[1]), "pairs": float(row[2])}
+            for i, row in zip(layers, np.asarray(visits))
+        ],
     }
 
 

@@ -35,6 +35,7 @@ from rayfed_tpu import telemetry
 from rayfed_tpu.models.moe import FFN_UP_NAME, SELECTED_NAME, swiglu
 from rayfed_tpu.ops.attention import NEG_INF, dot_product_attention
 from rayfed_tpu.ops.flash_attention import RESIDUAL_NAMES
+from rayfed_tpu.ops.sparse_attention import SELECTION_NAME
 
 Params = Dict[str, Any]
 
@@ -53,7 +54,11 @@ Params = Dict[str, Any]
 # its input and the adapter's ``x a``, is still made); and a state-space
 # layer's input projection (``SSM_IN_NAME``, ``B*T*proj_dim`` elements,
 # named in ``mamba2.apply_mixer``: gate, convolved part and time step
-# are slices of it).  Each is an array the first forward leaves in HBM
+# are slices of it); and a block-sparse attention layer's selection
+# (``SELECTION_NAME``, the words and visit lists its kernels read, named
+# in ``ops/sparse_attention.py``: a selection made again from
+# recomputed scores need not be the one the forward pass used).  Each
+# is an array the first forward leaves in HBM
 # in the compute dtype anyway; JAX puts a ``reduce_precision`` on a kept
 # residual's producer, so its forward consumers read the ROUNDED array
 # (what the program says) where XLA's excess precision let a fused one
@@ -68,20 +73,23 @@ Params = Dict[str, Any]
 # section 6, PR 34).
 LAYER_MID_NAME, SSM_IN_NAME = "layer.mid", "ssm.in"
 REMAT_SAVED_NAMES = (
-    SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME, LAYER_MID_NAME, SSM_IN_NAME
+    SELECTED_NAME, *RESIDUAL_NAMES, FFN_UP_NAME, LAYER_MID_NAME, SSM_IN_NAME,
+    SELECTION_NAME,
 )
 REMAT_SAVED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
 
 
 def remat_saved_bytes(tokens: int, dtype, *, hidden: int, ffn_up: int,
-                      top_k: int = 0, ssm_in: int = 0) -> Dict[str, int]:
+                      top_k: int = 0, ssm_in: int = 0,
+                      selection: int = 0) -> Dict[str, int]:
     """``{name: bytes ONE checkpointed layer keeps under it}`` for the
     names of ``REMAT_SAVED_NAMES`` that the models' own code gives (the
     flash kernel's two are counted where it is called), from the layer's
     widths: ``hidden`` the stream's, ``ffn_up`` the dense FFN's or the
     shared expert's up product (a gated or a squared-ReLU one alike),
-    ``top_k`` an expert layer's selections a token and ``ssm_in`` a
-    state-space layer's input projection's (0: the layer has none; a
+    ``top_k`` an expert layer's selections a token, ``ssm_in`` a
+    state-space layer's input projection's and ``selection`` the bytes
+    of a block-sparse layer's selection arrays (0: the layer has none; a
     layer with no FFN, ``ffn_up`` 0, keeps neither ``ffn.up`` nor the
     stream between its sub-blocks, since it has one sub-block).  The one
     table behind FR ``remat.saved``, here and in ``decoder.py``: a name
@@ -96,6 +104,8 @@ def remat_saved_bytes(tokens: int, dtype, *, hidden: int, ffn_up: int,
         sizes[SELECTED_NAME] = tokens * top_k * 4
     if ssm_in:
         sizes[SSM_IN_NAME] = elem * ssm_in
+    if selection:
+        sizes[SELECTION_NAME] = selection
     return sizes
 
 

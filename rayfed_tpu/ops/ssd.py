@@ -146,13 +146,18 @@ def head_block(heads: int, groups: int, head_dim: int, state: int,
     tiles (its lanes of ``x`` a multiple of 128 and its rows of ``l`` a
     multiple of 8, or all the heads there are) and whose
     ``step_vmem_bytes`` fit the budget; the smallest that tiles where
-    none fits.  Interpret mode takes any divisor."""
+    none fits.  Interpret mode takes any divisor.  With one group a head
+    (decayed linear attention: ``B`` and ``C`` are a head's own keys and
+    queries) a block is one head, whose lanes of ``x`` must fill whole
+    128-lane tiles; its ``l`` is read from its token rows
+    (:func:`_columns`)."""
     per_group = heads // groups
     tiles = [
         hb for hb in range(1, per_group + 1)
         if per_group % hb == 0
         and (interpret or hb == heads
-             or ((hb * head_dim) % 128 == 0 and hb % 8 == 0))
+             or ((hb * head_dim) % 128 == 0
+                 and (hb % 8 == 0 or groups == heads)))
     ]
     if not tiles:
         raise ValueError(
@@ -167,20 +172,23 @@ def head_block(heads: int, groups: int, head_dim: int, state: int,
     return max(fits) if fits else min(tiles)
 
 
-def _check_tiling(chunk, head_dim, state, groups, itemsize):
+def _check_tiling(chunk, head_dim, state, groups, itemsize, heads=None):
     """What the compiled kernels ask of shapes (interpret mode asks
     nothing): a chunk's tokens are lanes of the token rows and sublanes
     of ``x``; a head is whole sublane tiles of the ``[heads P, Q]``
     arrays, the operand type's among them; a group's ``B``/``C`` columns
-    are a lane block."""
+    are a lane block; with one group a head, a head's lanes of ``x``
+    are a lane block too."""
     sublanes = 8 * 4 // itemsize
-    if chunk % 128 or head_dim % sublanes or (groups > 1 and state % 128):
+    per_head = groups > 1 and groups == heads
+    if (chunk % 128 or head_dim % sublanes or (groups > 1 and state % 128)
+            or (per_head and head_dim % 128)):
         raise ValueError(
             f"chunk {chunk}, head width {head_dim}, state {state}, "
             f"{groups} groups: the compiled scan needs a chunk that is a "
             f"multiple of 128, a head width that is a multiple of "
-            f"{sublanes}, and a state that is a multiple of 128 where "
-            f"groups > 1"
+            f"{sublanes} (of 128 with one group a head), and a state that "
+            f"is a multiple of 128 where groups > 1"
         )
 
 
@@ -242,6 +250,12 @@ def _columns(l_ref, lcol_scr):
     """``l`` of a block's heads as columns ``[heads, Q, 1]``: ONE
     transpose a grid step (a head's own ``[8, Q]`` tile transposed cost
     0.32 ms a forward call: `tool/ssd_sweep.py`, my chip run, PR 36)."""
+    if len(l_ref.shape) == 3:
+        # A block of one head is handed its token rows [1, 8, Q] as
+        # l_ref (a [1, Q] block of l does not tile): their transposed
+        # row _L is the column.
+        lcol_scr[0] = l_ref[0].T[:, _L:_L + 1]
+        return
     lt = l_ref[...].T  # [Q, heads]
     for h in range(lcol_scr.shape[0]):
         lcol_scr[h] = lt[:, h:h + 1]
@@ -389,6 +403,12 @@ class _Plan(NamedTuple):
     interpret: bool
 
 
+def _l_rows(rows, plan):
+    """What the kernels read ``l`` from: the token rows' ``l`` row, or
+    with one group a head the token rows whole (:func:`_columns`)."""
+    return rows if plan.groups == rows.shape[1] else rows[:, :, _L]
+
+
 def _vmem_bytes(plan, heads, state, itemsize):
     """A grid step's working set and the carried states of all heads."""
     return (
@@ -416,7 +436,11 @@ def _specs(x, cm, plan, reverse):
         rows=pl.BlockSpec(
             (None, hb, _ROWS, chunk), lambda b, c, j: (b, j, 0, at(c))
         ),
-        l=pl.BlockSpec((None, hb, chunk), lambda b, c, j: (b, j, at(c))),
+        l=pl.BlockSpec(
+            (None, hb, _ROWS, chunk), lambda b, c, j: (b, j, 0, at(c))
+        ) if groups == hp // p else pl.BlockSpec(
+            (None, hb, chunk), lambda b, c, j: (b, j, at(c))
+        ),
         group=pl.BlockSpec(
             (None, chunk, n), lambda b, c, j: (b, at(c), j // a_group)
         ),
@@ -469,7 +493,7 @@ def _forward(x, rows, whole, bm, cm, *, plan):
                 s["across"](f32), s["across"](x.dtype),
             ],
             **s["call"],
-        )(x, rows, rows[:, :, _L], whole, bm, cm)
+        )(x, rows, _l_rows(rows, plan), whole, bm, cm)
 
 
 @functools.partial(jax.jit, static_argnames="plan")
@@ -501,7 +525,7 @@ def _backward(x, rows, whole, bm, cm, before, dy, *, plan):
                 pltpu.VMEM((plan.chunk, plan.chunk), f32),
             ],
             **s["call"],
-        )(x, rows, rows[:, :, _L], whole, bm, cm, before, dy)
+        )(x, rows, _l_rows(rows, plan), whole, bm, cm, before, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -550,7 +574,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int):
     dtype, f32 = x.dtype, jnp.float32
     interpret = _flash._interpret_default()
     if not interpret:
-        _check_tiling(chunk, p, n, g, dtype.itemsize)
+        _check_tiling(chunk, p, n, g, dtype.itemsize, h)
     plan = _Plan(
         chunk, head_block(h, g, p, n, chunk, dtype.itemsize, interpret), p, g,
         interpret,
