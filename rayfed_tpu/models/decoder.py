@@ -616,11 +616,15 @@ def _sparse_attend(q, k, v, config: DecoderConfig, attn_fn):
     """A block-sparse layer's attention (no positions) and its report:
     up to ``dense_len`` tokens causal attention through ``attn_fn``
     (``attn.sparse``, no report); beyond, the selection
-    (``attn.select``: compressed keys, scores, top-k, no gradient) and
-    the kernel over the selected blocks (``attn.sparse``), reporting
-    ``selected`` [B, KV, T, topk], the mean causal keys and blocks a
-    query visits and the mean (query tile, key tile) pairs the kernels
-    walk a K/V head."""
+    (``attn.select``: compressed keys, scores, the top-k mask, no
+    gradient) and the kernel over the selected blocks
+    (``attn.sparse``), reporting ``selected`` [B, KV, T, topk] (each
+    query's blocks in ascending order, -1 where fewer), the mean causal
+    keys and blocks a query visits, the mean (query tile, key tile)
+    pairs the kernels walk a K/V head, and the shares of (query, K/V
+    head) rows where the block index broke a tie at the threshold
+    (``tie_rows``) and with fewer than ``topk`` causal blocks
+    (``short_rows``)."""
     s = config.sparse
     scaled = {} if config.attn_scale is None else {"sm_scale": config.attn_scale}
     t = q.shape[1]
@@ -628,16 +632,22 @@ def _sparse_attend(q, k, v, config: DecoderConfig, attn_fn):
         with jax.named_scope("attn.sparse"):
             return attn_fn(q, k, v, causal=True, **scaled), None
     with jax.named_scope("attn.select"):
-        chosen = sparse.select_blocks(
+        mask, tied = sparse.select_mask(
             jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), s
         )
-        arrays = sparse.selection_arrays(chosen, t, s)
-        keys, blocks = sparse.visit_stats(chosen, s.block_size)
+        arrays = sparse.selection_arrays(mask, t, s)
+        keys, blocks = sparse.visit_stats(mask, s.block_size)
         pairs = jnp.mean(arrays[1][2].astype(jnp.float32))
+        # every causal block is selected where fewer than topk exist
+        short = jnp.mean(jnp.sum(mask, -1) < s.topk, dtype=jnp.float32)
+        report = {
+            "selected": sparse.mask_indices(mask, min(s.topk, mask.shape[-1])),
+            "visited_keys": keys, "blocks": blocks, "pairs": pairs,
+            "tie_rows": jnp.mean(tied, dtype=jnp.float32), "short_rows": short,
+        }
     with jax.named_scope("attn.sparse"):
         o = sparse.sparse_attention(q, k, v, arrays, s, **scaled)
-    return o, {"selected": chosen, "visited_keys": keys, "blocks": blocks,
-               "pairs": pairs}
+    return o, report
 
 
 def _lightning_block(x, lp, config: DecoderConfig, lget):
@@ -820,12 +830,17 @@ def routing_counts(aux) -> Optional[jax.Array]:
     return jnp.stack(rows) if rows else None
 
 
+_SELECTION_STATS = ("visited_keys", "blocks", "pairs", "tie_rows",
+                    "short_rows")
+
+
 def selection_stats(aux) -> Optional[jax.Array]:
-    """``[block-sparse layers, 3]`` float32: every such layer's mean
-    causal keys and selected blocks a query, and tile pairs its kernels
-    walk a K/V head; None where no layer selected."""
+    """``[block-sparse layers, 5]`` float32: every such layer's mean
+    causal keys and selected blocks a query, tile pairs its kernels walk
+    a K/V head, and shares of rows the tie-break decided and with fewer
+    than ``topk`` causal blocks; None where no layer selected."""
     rows = [
-        jnp.stack([a["visited_keys"], a["blocks"], a["pairs"]])
+        jnp.stack([a[name] for name in _SELECTION_STATS])
         for _, a in sorted(aux.items()) if "visited_keys" in a
     ]
     return jnp.stack(rows) if rows else None
@@ -1024,9 +1039,14 @@ def selection_detail(visits, config: DecoderConfig, shape) -> dict:
     """The ``attn.select`` record's detail from a step's
     :func:`selection_stats` (fetches them), per block-sparse layer: the
     mean causal keys a query visited and blocks it selected, beside the
-    causal keys dense attention would visit (``(T + 1) / 2``); and the
+    causal keys dense attention would visit (``(T + 1) / 2``); the
     (query tile, key tile) pairs the kernels walked a K/V head, beside
-    the causal pairs, the most a selection can make them walk."""
+    the causal pairs, the most a selection can make them walk; and the
+    shares of (query, K/V head) rows whose threshold score a left-out
+    block shared (``tie_rows``: the block index decided) and with fewer
+    than ``topk`` causal blocks (``short_rows``), beside the
+    compare-and-count passes the top-k mask makes a row
+    (``threshold_passes``)."""
     import numpy as np
 
     s = config.sparse
@@ -1037,9 +1057,12 @@ def selection_detail(visits, config: DecoderConfig, shape) -> dict:
         "block": s.block_size, "topk": s.topk,
         "dense_keys": (shape[1] + 1) / 2,
         "causal_pairs": sparse.causal_pairs(shape[1] // s.tile_for(shape[1])),
+        "threshold_passes": sparse.threshold_passes(
+            -(-shape[1] // s.block_size)
+        ),
         "layers": [
-            {"layer": i, "visited_keys": float(row[0]),
-             "blocks": float(row[1]), "pairs": float(row[2])}
+            {"layer": i, **{name: float(v)
+                            for name, v in zip(_SELECTION_STATS, row)}}
             for i, row in zip(layers, np.asarray(visits))
         ],
     }

@@ -2,7 +2,7 @@
 MiniCPM4 report, arXiv:2506.07900, section 2.1): every query attends to
 the key blocks its K/V group SELECTED, and to nothing else.
 
-The selection, per K/V head ``g`` and query ``t`` (:func:`select_blocks`,
+The selection, per K/V head ``g`` and query ``t`` (:func:`select_mask`,
 plain XLA, no gradient: the choice is discrete)::
 
     K~_j     = mean(k[s j : s j + w])           compressed keys, stride s, width w
@@ -11,7 +11,11 @@ plain XLA, no gradient: the choice is discrete)::
     score_m  = max of p_{t,j} over the windows that overlap block m
     S_t      = the first ``init_blocks`` blocks, the blocks of the last
                ``window_size`` tokens, and the highest-scoring causal
-               blocks left, ``topk`` blocks in all
+               blocks left, ``topk`` blocks in all, equal scores to the
+               lower block (``jax.lax.top_k``'s set)
+
+``S_t`` is a set and is kept as a mask over the blocks: no sort ranks
+them (:func:`top_mask` finds the ``topk``-th score by bisection).
 
 and the attention (:func:`sparse_attention`, a Pallas kernel pair with a
 hand-written backward)::
@@ -133,11 +137,70 @@ def compress_keys(k, kernel: int, stride: int):
     return total / kernel
 
 
-def select_blocks(q, k, config: SparseConfig):
-    """``[B, KV, T, topk]`` int32: each query's selected blocks for its
-    K/V head, the forced ones (init, local) first, then by score; -1
-    where fewer causal blocks exist.  ``q`` [B, T, H, D], ``k`` [B, T,
-    KV, D]; products in ``q``'s type, float32 out, softmax in float32."""
+def _order_keys(score):
+    """``score`` float32 as uint32 keys in the same total order that
+    ``jax.lax.top_k`` ranks by (the low 31 bits of a negative flipped,
+    then the sign bit), ``-inf`` at 0, the lowest: a key above 0 is a
+    block that may be selected."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.uint32)
+    negative = jax.lax.shift_right_logical(bits, jnp.uint32(31)) == 1
+    key = jnp.where(negative, ~bits, bits | jnp.uint32(1 << 31))
+    return jnp.where(score > -jnp.inf, key, jnp.uint32(0))
+
+
+def threshold_passes(nblocks: int) -> int:
+    """Compare-and-count passes over a row of ``nblocks`` keys that
+    :func:`top_mask` makes: 32 for the threshold's bits, then those of
+    the highest block index for the tie."""
+    return 32 + max(nblocks - 1, 0).bit_length()
+
+
+def top_mask(score, k: int):
+    """``(mask, tied)``: ``mask`` [..., N] bool is true for the blocks
+    ``jax.lax.top_k(score, k)`` returns with a value above ``-inf``,
+    without a sort: the k-th largest key by bisection on its bits (the
+    largest ``t`` with at least ``k`` keys ``>= t``), every key above
+    it, and of the keys equal to it the lowest block indices, by
+    bisection on the index bound, until there are ``k`` (``top_k``'s own
+    rule for ties); where fewer than ``k`` keys lie above ``-inf``, all
+    of them.  ``tied`` [...] marks rows where a block equal to the
+    threshold was left out: the index decided."""
+    key = _order_keys(score)
+    count = lambda hit: jnp.sum(hit, axis=-1, dtype=jnp.int32)
+
+    def threshold_bit(i, t):
+        cand = t | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        return jnp.where(count(key >= cand[..., None]) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, threshold_bit,
+                          jnp.zeros(key.shape[:-1], jnp.uint32))
+    above = count(key > t[..., None])
+    tie = key == t[..., None]
+    need = k - above
+    blocks = jnp.arange(key.shape[-1], dtype=jnp.int32)
+    index_bits = threshold_passes(key.shape[-1]) - 32
+
+    def index_bit(i, last):
+        # the largest bound with fewer than ``need`` ties below it: the
+        # index of the tie that makes ``k``
+        cand = last | jnp.left_shift(1, index_bits - 1 - i)
+        fewer = count(tie & (blocks < cand[..., None])) < need
+        return jnp.where(fewer, cand, last)
+
+    last = jax.lax.fori_loop(0, index_bits, index_bit,
+                             jnp.zeros(key.shape[:-1], jnp.int32))
+    mask = (key > t[..., None]) | (tie & (blocks <= last[..., None]))
+    # t == 0: fewer than k keys above -inf, and the ties are the -infs
+    return mask & (key > 0), (t > 0) & (above + count(tie) > k)
+
+
+def block_scores(q, k, config: SparseConfig):
+    """``[chunks, B, KV, Q, blocks]`` float32: each query's block scores
+    for its K/V head, ``select_chunk`` queries a chunk (the last padded
+    past ``T``): ``+inf`` for the forced causal blocks (init and local),
+    ``-inf`` after the query's own block.  ``q`` [B, T, H, D], ``k`` [B,
+    T, KV, D]; products in ``q``'s type, float32 out, softmax in
+    float32."""
     c = config
     b, t, h, d = q.shape
     kv = k.shape[2]
@@ -184,40 +247,80 @@ def select_blocks(q, k, config: SparseConfig):
         causal = blocks[None, :] <= own
         forced = (blocks[None, :] < c.init_blocks) | (blocks[None, :] >= first_local)
         score = jnp.where(forced & causal, jnp.inf, score)
-        score = jnp.where(causal, score, -jnp.inf)
-        vals, idx = jax.lax.top_k(score, min(c.topk, nblocks))
-        return jnp.where(vals > -jnp.inf, idx, -1).astype(jnp.int32)
+        return jnp.where(causal, score, -jnp.inf)
 
     starts = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
-    sel = jax.lax.map(one, (qs, starts))  # [chunks, B, KV, Q, topk]
-    sel = sel.transpose(1, 2, 0, 3, 4).reshape(b, kv, n_chunks * chunk, -1)
-    return sel[:, :, :t]
+    return jax.lax.map(one, (qs, starts))
+
+
+def select_mask(q, k, config: SparseConfig):
+    """``(mask, tied)``: ``mask`` [B, KV, T, blocks] bool, each query's
+    selected blocks for its K/V head (the forced ones, init and local,
+    and the highest-scoring causal blocks left, ``topk`` in all, ties to
+    the lower block; every causal block where fewer exist); ``tied`` [B,
+    KV, T] as :func:`top_mask` gives it.  Arguments as
+    :func:`block_scores` takes them."""
+    score = block_scores(q, k, config)  # [chunks, B, KV, Q, blocks]
+    mask, tied = top_mask(score, min(config.topk, score.shape[-1]))
+    b, kv, t = k.shape[0], k.shape[2], q.shape[1]
+    rows = lambda x: x.transpose(1, 2, 0, 3, *range(4, x.ndim)).reshape(
+        b, kv, -1, *x.shape[4:])[:, :, :t]
+    return rows(mask), rows(tied)
+
+
+def as_mask(sel, t: int, block: int):
+    """A selection as a mask ``[B, KV, T, blocks]`` bool: ``sel`` itself
+    where it is one, else from block indices ``[B, KV, T, topk]`` (-1
+    for none)."""
+    if sel.dtype == jnp.bool_:
+        return sel
+    blocks = jnp.arange(-(-t // block), dtype=sel.dtype)
+    return jnp.any(sel[..., None] == blocks, axis=-2)
+
+
+def mask_indices(mask, k: int):
+    """``[B, KV, T, k]`` int32: the blocks of ``mask`` in ascending
+    order, -1 where fewer exist (no sort: slot ``s`` holds the count of
+    blocks before the one that makes ``s + 1``)."""
+    n = mask.shape[-1]
+    made = jnp.cumsum(mask, axis=-1, dtype=jnp.int32)  # [.., N]
+    at = jnp.sum(made[..., None, :] <= jnp.arange(k, dtype=jnp.int32)[:, None],
+                 axis=-1, dtype=jnp.int32)
+    return jnp.where(at < n, at, -1)
+
+
+def select_blocks(q, k, config: SparseConfig):
+    """``[B, KV, T, topk]`` int32: each query's selected blocks for its
+    K/V head (:func:`select_mask`) in ascending order, -1 where fewer
+    causal blocks exist."""
+    mask, _ = select_mask(q, k, config)
+    return mask_indices(mask, min(config.topk, mask.shape[-1]))
 
 
 def visit_stats(sel, block: int):
     """``(keys, blocks)`` float32: the mean over (batch, K/V head,
     query) of the causal keys a query visits and of the blocks it
-    selected."""
+    selected; ``sel`` a mask or block indices (:func:`as_mask`)."""
     t = sel.shape[2]
+    mask = as_mask(sel, t, block)
     pos = jnp.arange(t)[None, None, :, None]
-    keys = jnp.clip(pos - sel * block + 1, 0, block)
-    valid = sel >= 0
-    return (jnp.mean(jnp.sum(jnp.where(valid, keys, 0), -1).astype(jnp.float32)),
-            jnp.mean(jnp.sum(valid, -1).astype(jnp.float32)))
+    starts = jnp.arange(mask.shape[-1]) * block
+    keys = jnp.clip(pos - starts + 1, 0, block)
+    return (jnp.mean(jnp.sum(jnp.where(mask, keys, 0), -1).astype(jnp.float32)),
+            jnp.mean(jnp.sum(mask, -1, dtype=jnp.int32).astype(jnp.float32)))
 
 
 def block_words(sel, t: int, config: SparseConfig):
     """``[B, KV, T, key tiles]`` float32: bit ``b`` of a token's word for
     a key tile says it selected block ``b`` of that tile (at most
-    ``_BITS`` blocks a tile, so that float32 holds a word whole).  A
-    compare and a sum over the ``topk`` choices, which XLA fuses: no
-    one-hot array over the blocks is made."""
+    ``_BITS`` blocks a tile, so that float32 holds a word whole); ``sel``
+    a mask or block indices (:func:`as_mask`)."""
     tile = config.tile_for(t)
     per_tile = tile // config.block_size
-    tiles = jnp.arange(t // tile, dtype=jnp.int32)
-    of = jnp.where(sel >= 0, sel // per_tile, -1)[..., None]  # [.., topk, 1]
-    bit = jnp.left_shift(1, jnp.maximum(sel, 0) % per_tile)[..., None]
-    return jnp.sum(jnp.where(of == tiles, bit, 0), axis=-2).astype(jnp.float32)
+    mask = as_mask(sel, t, config.block_size)
+    tiles = mask.reshape(*mask.shape[:-1], t // tile, per_tile)
+    bit = jnp.left_shift(1, jnp.arange(per_tile, dtype=jnp.int32))
+    return jnp.sum(jnp.where(tiles, bit, 0), axis=-1).astype(jnp.float32)
 
 
 def causal_pairs(n_tiles: int) -> int:
@@ -563,7 +666,8 @@ def selection_bytes(batch: int, t: int, kv: int, config: SparseConfig) -> int:
 
 def selection_arrays(sel, t: int, config: SparseConfig):
     """``(words, forward lists, dK/dV lists)`` the kernels read, from a
-    selection ``[B, KV, T, topk]``; each under :data:`SELECTION_NAME`."""
+    selection (a mask or block indices, :func:`as_mask`); each under
+    :data:`SELECTION_NAME`."""
     words = block_words(sel, t, config)
     fwd, bwd = visit_lists(words)
     b, kv = sel.shape[:2]
@@ -577,7 +681,7 @@ def sparse_attention(q, k, v, selection, config: SparseConfig, *,
     """``[B, T, H, Dv]``: each query over the keys of its K/V head's
     selected blocks that are not after it.  ``q`` [B, T, H, D], ``k``
     [B, T, KV, D], ``v`` [B, T, KV, Dv]; ``selection`` is what
-    :func:`selection_arrays` makes of :func:`select_blocks`' choice, in
+    :func:`selection_arrays` makes of :func:`select_mask`'s choice, in
     which every query holds its own block (the local window's): a query
     tile is then always paired with itself, so each tile's output and row
     statistics are written and no query is left with nothing to attend.

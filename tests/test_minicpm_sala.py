@@ -231,6 +231,131 @@ def test_the_visit_lists_walk_each_tiles_union_in_order():
     assert tq[0].tolist() == [0, 2, 1, 2, 2, 2]
 
 
+def _top_k_set(score, k):
+    """``[..., N]`` bool: the blocks ``jax.lax.top_k`` returns with a
+    value above ``-inf``, the set the threshold's mask is held to."""
+    vals, idx = jax.lax.top_k(score, k)
+    hit = (idx[..., None] == jnp.arange(score.shape[-1])) & (
+        vals > -jnp.inf)[..., None]
+    return hit.any(-2)
+
+
+def _scores(case, rows=256, n=40):
+    """Block scores ``[rows, n]`` of one kind: ``top_k``'s tie rule and
+    the edges of the selection (forced and non-causal blocks, rows with
+    fewer causal blocks than ``k``)."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    s = rng.uniform(size=(rows, n)).astype(np.float32)
+    if case == "levels":  # a few values: ties at the threshold
+        s = np.round(s * 4) / 4
+    elif case == "equal":
+        s = np.full_like(s, 0.25)
+    elif case == "forced":  # +inf forced, -inf after the query's block
+        s[rng.uniform(size=s.shape) < 0.2] = np.inf
+        s[rng.uniform(size=s.shape) < 0.4] = -np.inf
+    elif case == "short":  # most rows with fewer than k blocks above -inf
+        s = np.round(s * 2) / 2
+        causal = rng.integers(0, n + 1, size=(rows, 1))
+        s = np.where(np.arange(n) < causal, s, -np.inf).astype(np.float32)
+    elif case == "signs":  # negatives, both zeros, infinities
+        s = rng.choice(np.float32([-np.inf, -2.5, -1.0, -0.0, 0.0, 1e-30,
+                                   1.0, 3.0, np.inf]), size=s.shape)
+    return jnp.asarray(s)
+
+
+@pytest.mark.parametrize(
+    "case", ["uniform", "levels", "equal", "forced", "short", "signs"])
+@pytest.mark.parametrize("k", [1, 7, 39, 40])
+def test_the_top_k_mask_is_top_ks_set(case, k):
+    """The threshold's mask is the set ``jax.lax.top_k`` returns, block
+    for block, and ``tied`` marks the rows where a block equal to the
+    threshold was left out."""
+    score = _scores(case)
+    mask, tied = jax.jit(sa.top_mask, static_argnums=1)(score, k)
+    np.testing.assert_array_equal(mask, _top_k_set(score, k))
+    key = np.asarray(score)
+    kth = -np.sort(-key, axis=-1)[:, k - 1]  # the k-th largest score
+    selected = np.asarray(mask)
+    left_out_tie = ((key == kth[:, None]) & ~selected).any(-1) & (
+        kth > -np.inf)
+    np.testing.assert_array_equal(tied, left_out_tie)
+    if case == "equal" and k < score.shape[-1]:
+        assert np.asarray(tied).all()
+
+
+def _top_k_selection(q, k, config):
+    """``[B, KV, T, topk]``: the selection by ``jax.lax.top_k`` over
+    :func:`sa.block_scores` (-1 where fewer causal blocks exist)."""
+    score = sa.block_scores(q, k, config)
+    vals, idx = jax.lax.top_k(score, min(config.topk, score.shape[-1]))
+    sel = jnp.where(vals > -jnp.inf, idx, -1)
+    b, kv, t = k.shape[0], k.shape[2], q.shape[1]
+    return sel.transpose(1, 2, 0, 3, 4).reshape(b, kv, -1, sel.shape[-1])[
+        :, :, :t]
+
+
+def _words_from_indices(sel, t, config):
+    """The words from block indices by a compare and a sum over the
+    choices: the reference the words from a mask are held to."""
+    tile = config.tile_for(t)
+    per_tile = tile // config.block_size
+    tiles = jnp.arange(t // tile, dtype=jnp.int32)
+    of = jnp.where(sel >= 0, sel // per_tile, -1)[..., None]
+    bit = jnp.left_shift(1, jnp.maximum(sel, 0) % per_tile)[..., None]
+    return jnp.sum(jnp.where(of == tiles, bit, 0), axis=-2).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "zeros", "random", "more-topk-than-blocks", "chunk-not-whole"])
+def test_the_selection_is_top_ks_and_its_words_the_same_bits(case):
+    """``select_mask`` and ``select_blocks`` choose the sets that
+    ``jax.lax.top_k`` does over the same scores (on zero queries and
+    keys every score ties), and the kernels' words and visit lists made
+    from the mask equal, bit for bit, those made from the indices."""
+    config = {
+        "more-topk-than-blocks": dataclasses.replace(SPARSE, topk=20),
+        "chunk-not-whole": dataclasses.replace(SPARSE, select_chunk=36),
+    }.get(case, SPARSE)
+    q, k, _, _ = _qkv(11, T)
+    if case == "zeros":
+        q, k = jnp.zeros_like(q), jnp.zeros_like(k)
+    want = _top_k_selection(q, k, config)
+    mask, tied = sa.select_mask(q, k, config)
+    assert mask.shape == (1, KV, T, T // 8) and tied.shape == (1, KV, T)
+    np.testing.assert_array_equal(mask, sa.as_mask(want, T, 8))
+    got = sa.select_blocks(q, k, config)
+    np.testing.assert_array_equal(np.sort(got, -1), np.sort(want, -1))
+    last = np.where(got < 0, T, got)  # ascending, the -1s after
+    np.testing.assert_array_equal(last, np.sort(last, -1))
+    if case == "zeros":
+        assert np.asarray(tied).any()
+    words = sa.block_words(mask, T, config)
+    np.testing.assert_array_equal(words, _words_from_indices(want, T, config))
+    from_mask = sa.selection_arrays(mask, T, config)
+    from_indices = sa.selection_arrays(want, T, config)
+    for a, b in zip(jax.tree_util.tree_leaves(from_mask),
+                    jax.tree_util.tree_leaves(from_indices)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_sparse_layer_sorts_no_blocks():
+    """Compiled, the toy sparse layer's forward ranks no row of block
+    scores: no sort over the blocks and no ``TopK``; the visit lists'
+    argsort over the 6 x 6 tile pairs stays."""
+    cfg, base, _, _ = make()
+    lp = jax.tree_util.tree_map(lambda a: a[0], base["layers"][0])
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, T, D))
+    hlo = jax.jit(lambda x, lp: decoder.apply_block(
+        x, lp, cfg, ffn="dense", mixer="sparse", attn_fn=flash_attention
+    )).lower(x, lp).compile().as_text()
+    sorts = [line.split(" sort(")[0] for line in hlo.splitlines()
+             if " sort(" in line]
+    nblocks, pairs = T // SPARSE.block_size, (T // SPARSE.tile_for(T)) ** 2
+    assert sorts and all(f",{pairs}]" in out for out in sorts), sorts
+    assert not any(f",{nblocks}]" in out for out in sorts)
+    assert "TopK" not in hlo
+
+
 @pytest.mark.parametrize("decay", [0.3, 0.0])
 def test_the_scan_with_a_group_a_head_is_the_recurrence(decay):
     """``ssd_scan`` as the linear attention calls it (``dt = 1``, ``A``
@@ -318,7 +443,9 @@ def test_float32_system_matches_the_reference(remat):
     assert rel_rms(logits[0], want_logits) < 1e-4
     assert abs(float(loss) - float(want_loss)) < 1e-4 * float(want_loss)
     assert ref.selection_agreement(aux[0]["selected"][0], chosen[0]) == 1.0
-    np.testing.assert_array_equal(aux[0]["selected"][0], chosen[0])
+    # the same sets: the system lists a query's blocks in ascending order
+    np.testing.assert_array_equal(np.sort(aux[0]["selected"][0], -1),
+                                  np.sort(chosen[0], -1))
     flat_got = jax.tree_util.tree_leaves_with_path(decoder.unstack(grads, cfg))
     flat_want = jax.tree_util.tree_leaves(want_grads)
     assert len(flat_got) == len(flat_want) == 4 * 8 * 3
@@ -386,6 +513,34 @@ def test_bf16_step_runs_and_records_the_selection(monkeypatch):
     )
     assert "attn.selected" not in saved.detail["bytes_per_layer"]["layers1-3"]
     assert any(r.phase == "attn.sparse" for r in records)
+
+
+def test_the_select_record_counts_the_tie_break_and_the_short_rows():
+    """Zero queries in the sparse layer (its ``q_norm`` zeroed) make
+    every causal block's score the same: the block index decides the
+    rows with more causal blocks than ``topk``, and the armed step's
+    ``attn.select`` record says so (``tie_rows`` above 0); the first 32
+    of the 96 queries have fewer than 5 causal blocks of 8 tokens
+    (``short_rows`` a third); 32 passes find a threshold and 4 more the
+    tie among 12 blocks."""
+    cfg, base, adapters, ids = make()
+    layers = [dict(base["layers"][0],
+                   q_norm=jnp.zeros_like(base["layers"][0]["q_norm"])),
+              *base["layers"][1:]]
+    base = dict(base, layers=layers)
+    step = decoder.make_lora_train_step(cfg, attn_fn=flash_attention)
+    rec = telemetry.install(capacity=64)
+    try:
+        step(adapters, llama.init_adam(adapters), base, ids)
+        step.flush_routing()
+        records = rec.records()
+    finally:
+        telemetry.uninstall()
+    (select,) = [r for r in records if r.phase == "attn.select"]
+    assert select.detail["threshold_passes"] == 32 + 4
+    (layer,) = select.detail["layers"]
+    assert 0 < layer["tie_rows"] <= 1 - layer["short_rows"]
+    assert layer["short_rows"] == pytest.approx(32 / 96)
 
 
 def test_a_dense_attention_fn_gives_the_dense_branch_and_the_kernels_the_rest():
