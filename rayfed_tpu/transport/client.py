@@ -67,10 +67,11 @@ _INLINE_MATERIALIZE_BYTES = 256 * 1024
 def _default_stripe_rails() -> int:
     """Host-adaptive rail count: striping pays only when spare cores
     run the per-rail CRC/copy stages concurrently with the socket
-    writes.  On a 1-2 core host every rail shares one core AND the
-    receiver pays an extra reassembly memcpy per byte — measured 2×
-    SLOWER than the single-frame path there — so few-core hosts keep
-    one rail (striping off) and the wire-v3 single-frame pipeline.
+    writes.  On a 1-2 core host every rail shares one core with the
+    receiver's per-chunk CRC pass (striping measured 2× SLOWER than
+    the single-frame path there, when the receiver also copied every
+    chunk into its reassembly buffer) — so few-core hosts keep one
+    rail (striping off) and the wire-v3 single-frame pipeline.
     The ``stripe_rails`` transport option overrides this (tests and
     the multirail bench force it)."""
     import os
@@ -1774,7 +1775,7 @@ class TransportClient:
             )
             # Stripe only with >= 2 rails: on one rail the per-chunk
             # frames still pay per-frame ACKs and the receiver's
-            # reassembly memcpy with nothing pipelining against them —
+            # per-chunk CRC pass with nothing pipelining against them —
             # the v3 single-frame path (below) already overlaps CRC
             # with the socket and delivers zero-copy, and it now snaps
             # into the reusable arena too.

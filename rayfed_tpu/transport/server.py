@@ -10,6 +10,8 @@ preallocated per-frame ``bytearray`` via ``get_buffer``/``buffer_updated``
 — no 64 KiB chunk joins, no intermediate copies.  On localhost this is
 ~3.5× the StreamReader read path; the decode side then reads arrays
 zero-copy out of the same buffer (``np.frombuffer`` → ``device_put``).
+A multi-rail stripe frame's chunk (wire v4) is read straight into its
+slice of the payload's reassembly buffer instead.
 TLS (including mutual auth) is plain ``ssl`` on the listener (asyncio's
 sslproto supports buffered protocols on 3.11+).
 
@@ -85,12 +87,18 @@ class _StripeAsm:
     tracks the contiguous VERIFIED chunk prefix — the only bytes a
     chunk sink ever sees, which is what lets a streaming aggregator
     keep folding under shuffled cross-rail arrival.
+
+    A raw-read frame that carries one whole chunk is read straight into
+    that chunk's slice of ``buf`` (``_land_stripe_frame``): ``landing``
+    holds the chunks so reserved, which no other frame then writes,
+    until the frame is verified in place.  Such bytes lie outside the
+    verified prefix until then.
     """
 
     __slots__ = (
         "sid", "total", "csz", "nch", "nf", "buf", "ccrc", "have",
         "frames", "is_delta", "prefix", "shipped", "read_s", "lock",
-        "touched",
+        "touched", "landing", "inplace_frames", "inplace_bytes",
     )
 
     def __init__(self, sid, total, csz, nch, nf, buf, ccrc, is_delta):
@@ -109,6 +117,9 @@ class _StripeAsm:
         self.read_s = 0.0
         self.lock = threading.Lock()
         self.touched = time.monotonic()
+        self.landing: set = set()  # chunks reserved for an in-place read
+        self.inplace_frames = 0
+        self.inplace_bytes = 0
 
 
 class _FrameProtocol(asyncio.BufferedProtocol):
@@ -130,6 +141,9 @@ class _FrameProtocol(asyncio.BufferedProtocol):
         self._header: Dict[str, Any] = {}
         self._payload: Optional[bytearray] = None
         self._payload_view: Optional[memoryview] = None
+        # (assembly, view) when the payload is being read into its
+        # stripe assembly's buffer (_land_stripe_frame), else None.
+        self._landed: Optional[Tuple["_StripeAsm", memoryview]] = None
         self._payload_t0 = 0.0
         self._trailer_crc: Optional[int] = None
         self._peer = None
@@ -268,31 +282,38 @@ class _FrameProtocol(asyncio.BufferedProtocol):
             else:
                 self._dispatch_frame()
             return
-        self._payload = bytearray(self._plen)
-        self._payload_view = memoryview(self._payload)
+        sock = None
+        if self._plen >= _RAW_READ_BYTES and self._server._ssl_context is None:
+            sock = self._transport.get_extra_info("socket")
+        if (
+            sock is not None
+            and self._msg_type == wire.MSG_DATA
+            and self._header.get("stp") is not None
+        ):
+            # A stripe frame: the executor job reads it into its chunk's
+            # slice of the assembly where it can (_raw_read), so neither
+            # a buffer of its own nor the assembly is made on the loop.
+            self._payload = self._payload_view = None
+        else:
+            self._payload = bytearray(self._plen)
+            self._payload_view = memoryview(self._payload)
         self._payload_t0 = 0.0
-        if self._plen >= _RAW_READ_BYTES:
-            sock = (
-                None
-                if self._server._ssl_context is not None
-                else self._transport.get_extra_info("socket")
+        if sock is not None:
+            # Off-loop bulk read.  Safe w.r.t. buffering: get_buffer
+            # windows are exact, so at this point the transport holds
+            # no payload bytes — they're all still in the kernel.
+            # State is "payload" for the whole drain (no protocol
+            # callbacks fire while paused) so connection_lost's
+            # mid-payload sink-abort applies to raw-read frames too.
+            self._state = "payload"
+            self._transport.pause_reading()
+            self._payload_t0 = time.perf_counter()
+            loop = asyncio.get_running_loop()
+            fut = loop.run_in_executor(None, self._raw_read, sock.fileno())
+            fut.add_done_callback(
+                lambda f: loop.call_soon_threadsafe(self._raw_read_done, f)
             )
-            if sock is not None:
-                # Off-loop bulk read.  Safe w.r.t. buffering: get_buffer
-                # windows are exact, so at this point the transport holds
-                # no payload bytes — they're all still in the kernel.
-                # State is "payload" for the whole drain (no protocol
-                # callbacks fire while paused) so connection_lost's
-                # mid-payload sink-abort applies to raw-read frames too.
-                self._state = "payload"
-                self._transport.pause_reading()
-                self._payload_t0 = time.perf_counter()
-                loop = asyncio.get_running_loop()
-                fut = loop.run_in_executor(None, self._raw_read, sock.fileno())
-                fut.add_done_callback(
-                    lambda f: loop.call_soon_threadsafe(self._raw_read_done, f)
-                )
-                return
+            return
         self._expect("payload", self._plen)
 
     def _raw_read(self, fd: int) -> None:
@@ -305,10 +326,23 @@ class _FrameProtocol(asyncio.BufferedProtocol):
         declares a payload then stalls cannot pin a shared executor
         thread forever, while a slow-but-flowing large transfer is never
         cut off.
+
+        A stripe frame comes with no buffer (``_begin_payload``): it is
+        read into its chunk's slice of the assembly where
+        ``_land_stripe_frame`` grants one, else into a buffer of its own.
         """
         import os
         import select
 
+        if self._payload_view is None:
+            self._landed = _land_stripe_frame(
+                self._server, self._header, self._plen
+            )
+            self._payload = (
+                self._landed[1] if self._landed is not None
+                else bytearray(self._plen)
+            )
+            self._payload_view = memoryview(self._payload)
         idle_limit = 120.0
         deadline = time.monotonic() + idle_limit
         poller = select.poll()
@@ -379,6 +413,7 @@ class _FrameProtocol(asyncio.BufferedProtocol):
     def _reset(self) -> None:
         self._payload = None
         self._payload_view = None
+        self._landed = None
         self._cur_sink = None
         self._expect("prefix", _PREFIX_SIZE)
 
@@ -418,6 +453,7 @@ class _FrameProtocol(asyncio.BufferedProtocol):
         msg_type = self._msg_type
         header = self._header
         payload = self._payload if self._payload is not None else bytearray(0)
+        landed = self._landed
         read_seconds = (
             (time.perf_counter() - self._payload_t0) if self._payload_t0 else 0.0
         )
@@ -544,7 +580,7 @@ class _FrameProtocol(asyncio.BufferedProtocol):
             # skipped (it would double-hash multi-GB payloads on the hot
             # receive path).  Delta frames also reconstruct against the
             # cached base here.
-            self._handle_stream_data(header, payload, read_seconds)
+            self._handle_stream_data(header, payload, read_seconds, landed)
             return
 
         expected_crc = header.get("crc")
@@ -593,12 +629,16 @@ class _FrameProtocol(asyncio.BufferedProtocol):
             actual = _crc_of(payload)
         self._finish_data(header, payload, read_seconds, expected_crc, actual)
 
-    def _handle_stream_data(self, header, payload, read_seconds) -> None:
+    def _handle_stream_data(
+        self, header, payload, read_seconds, landed=None
+    ) -> None:
         """Verify per-chunk CRCs and (for deltas) rebuild the full payload.
 
         Both are byte-bound work (CRC pass + a full-payload memcpy for
         deltas), so large frames run them off-loop with reading paused —
-        same discipline as the whole-payload CRC offload."""
+        same discipline as the whole-payload CRC offload.  ``landed``:
+        the stripe frame's payload is its assembly's own slice
+        (``_land_stripe_frame``)."""
         server = self._server
         if header.get("stp") is not None:
             # Multi-rail stripe frame (wire v4): verify + place this
@@ -621,7 +661,7 @@ class _FrameProtocol(asyncio.BufferedProtocol):
             if big:
                 fut = loop.run_in_executor(
                     None, _apply_stripe_frame, server, header, payload,
-                    read_seconds,
+                    read_seconds, landed,
                 )
 
                 def _done(f):
@@ -641,7 +681,7 @@ class _FrameProtocol(asyncio.BufferedProtocol):
                 return
             try:
                 final, read_total = _apply_stripe_frame(
-                    server, header, payload, read_seconds
+                    server, header, payload, read_seconds, landed
                 )
                 exc = None
             except Exception as e:
@@ -1029,38 +1069,51 @@ def _verify_and_apply_stream(server: "TransportServer", header, payload):
     return new
 
 
-def _apply_stripe_frame(
-    server: "TransportServer", header, payload, read_seconds
-):
-    """Verify and place one stripe frame's chunks (wire v4).
+class _StripeFrame:
+    """A stripe frame's header fields (wire v4)."""
 
-    Returns ``(full_payload, read_s_total)`` when the frame completes
-    its payload's reassembly, ``(None, read_seconds)`` while partial.
-    Executor-thread safe: frames of one payload arrive concurrently on
-    different rail connections — the assembly map is guarded by the
-    server's stripe lock, chunk placement by the per-assembly lock.
+    __slots__ = (
+        "src", "stm", "key", "sid", "nf", "total", "csz", "nch",
+        "is_delta", "dlt",
+    )
+
+    def __init__(self, header) -> None:
+        stp = header["stp"]
+        self.dlt = header["dlt"]
+        self.src = header.get("src", "?")
+        self.stm = header.get("stm")
+        self.key = (
+            self.src, str(header.get("up")), str(header.get("down"))
+        )
+        self.sid = int(stp["sid"])
+        self.nf = int(stp["nf"])
+        self.total = int(self.dlt["total"])
+        self.csz = int(header.get("ccsz") or wire.DELTA_CHUNK_BYTES)
+        self.nch = max(1, -(-self.total // self.csz))
+        self.is_delta = "bfp" in self.dlt
+
+    def fits(self, asm: _StripeAsm) -> bool:
+        """The frame and the assembly agree on the payload's geometry."""
+        return (
+            asm.total == self.total and asm.csz == self.csz
+            and asm.nf == self.nf and asm.is_delta == self.is_delta
+        )
+
+
+def _stripe_assembly(
+    server: "TransportServer", f: _StripeFrame
+) -> _StripeAsm:
+    """The assembly frame ``f`` belongs to, made if it is the first of
+    its payload (any thread; takes the server's stripe lock).
 
     A frame whose ``sid`` is newer than the pending assembly's replaces
     it (the sender's retry re-ships the whole payload under a fresh
     sid); an older ``sid`` is a stale frame of a failed attempt and is
-    rejected.  Fresh payloads additionally feed any registered chunk
-    sink their growing contiguous VERIFIED prefix, so streaming
-    aggregation keeps overlapping the wire under shuffled arrival.
+    rejected, as is a frame of a group whose assembly was evicted.  A
+    new assembly is a zeroed buffer of the payload's size or, for a
+    delta, a copy of the cached base.
     """
-    import zlib
-
-    stp = header["stp"]
-    dlt = header["dlt"]
-    src = header.get("src", "?")
-    stm = header.get("stm")
-    sid = int(stp["sid"])
-    nf = int(stp["nf"])
-    total = int(dlt["total"])
-    csz = int(header.get("ccsz") or wire.DELTA_CHUNK_BYTES)
-    nch = max(1, -(-total // csz))
-    key = (src, str(header.get("up")), str(header.get("down")))
-    is_delta = "bfp" in dlt
-
+    key, sid, total = f.key, f.sid, f.total
     with server._stripe_lock:
         now = time.monotonic()
         for k in list(server._stripes):  # drop abandoned assemblies
@@ -1088,7 +1141,8 @@ def _apply_stripe_frame(
                     f"striped message of {total} bytes exceeds max "
                     f"{server._max_message_size}"
                 )
-            if is_delta:
+            src, stm = f.src, f.stm
+            if f.is_delta:
                 if stm is None:
                     raise ValueError("delta stripe frame without a stream")
                 base = server._get_delta_base(src, stm)
@@ -1096,7 +1150,10 @@ def _apply_stripe_frame(
                     raise _DeltaBaseMissing(
                         f"no cached base for stream {stm!r} from {src!r}"
                     )
-                if len(base["data"]) != total or base["fp"] != int(dlt["bfp"]):
+                if (
+                    len(base["data"]) != total
+                    or base["fp"] != int(f.dlt["bfp"])
+                ):
                     raise _DeltaBaseMissing(
                         f"cached base for stream {stm!r} from {src!r} "
                         f"desynced (restart or lost update)"
@@ -1105,8 +1162,10 @@ def _apply_stripe_frame(
                 ccrc = list(base["ccrc"])
             else:
                 buf = bytearray(total)
-                ccrc = [0] * nch
-            asm = _StripeAsm(sid, total, csz, nch, nf, buf, ccrc, is_delta)
+                ccrc = [0] * f.nch
+            asm = _StripeAsm(
+                sid, total, f.csz, f.nch, f.nf, buf, ccrc, f.is_delta
+            )
             server._stripes[key] = asm
             server._stripes.move_to_end(key)
             while len(server._stripes) > _MAX_STRIPE_ASM:
@@ -1119,38 +1178,116 @@ def _apply_stripe_frame(
         else:
             server._stripes.move_to_end(key)
         asm.touched = now
+    return asm
+
+
+def _land_stripe_frame(server: "TransportServer", header, plen: int):
+    """Where a raw-read stripe frame's payload is read to (executor
+    thread, before the read).
+
+    Returns ``(assembly, view)`` when the header names exactly one
+    chunk, of ``plen`` bytes, and its assembly is usable: ``view`` is
+    that chunk's slice of the assembly's buffer, reserved for this frame
+    (``_StripeAsm.landing``) until ``_apply_stripe_frame`` verifies it
+    there.  Otherwise ``None``, and the frame is read into a buffer of
+    its own: a stale or evicted sid, geometry that disagrees, an
+    oversize payload or a chunk already placed or reserved never writes
+    into an assembly here, and ``_apply_stripe_frame`` rejects it as it
+    rejects any frame.
+    """
+    try:
+        f = _StripeFrame(header)
+        (i,) = wire.decode_chunk_bitmap(f.dlt["map"], f.nch)
+        size = min(f.csz, f.total - i * f.csz)
+        if len(header["ccrc"]) != 1 or size != plen:
+            return None
+        asm = _stripe_assembly(server, f)
+    except (LookupError, TypeError, ValueError, _StripeFatal,
+            _DeltaBaseMissing):
+        return None
+    if not f.fits(asm):
+        return None
+    with asm.lock:
+        if i in asm.have or i in asm.landing:
+            return None
+        asm.landing.add(i)
+    start = i * f.csz
+    return asm, memoryview(asm.buf)[start : start + plen]
+
+
+def _apply_stripe_frame(
+    server: "TransportServer", header, payload, read_seconds, landed=None
+):
+    """Verify and place one stripe frame's chunks (wire v4).
+
+    Returns ``(full_payload, read_s_total)`` when the frame completes
+    its payload's reassembly, ``(None, read_seconds)`` while partial.
+    Executor-thread safe: frames of one payload arrive concurrently on
+    different rail connections — the assembly map is guarded by the
+    server's stripe lock, chunk placement by the per-assembly lock.
+
+    The frame's assembly is ``_stripe_assembly``'s.  ``landed`` is
+    ``_land_stripe_frame``'s grant: where it names this assembly the
+    payload is already its chunk's slice, so the chunk is verified where
+    it lies and not copied.  Fresh payloads additionally feed any
+    registered chunk sink their growing contiguous VERIFIED prefix, so
+    streaming aggregation keeps overlapping the wire under shuffled
+    arrival.
+    """
+    import zlib
+
+    f = _StripeFrame(header)
+    total, csz, nch = f.total, f.csz, f.nch
+    key, stm = f.key, f.stm
+    asm = _stripe_assembly(server, f)
+    in_place = landed is not None and landed[0] is asm
 
     try:
-        if (
-            asm.total != total or asm.csz != csz or asm.nf != nf
-            or asm.is_delta != is_delta
-        ):
+        if not f.fits(asm):
             raise _StripeReject("stripe frames disagree on payload geometry")
-        indices = wire.decode_chunk_bitmap(dlt["map"], nch)
+        indices = wire.decode_chunk_bitmap(f.dlt["map"], nch)
         ccrc_hdr = header["ccrc"]
         if len(indices) != len(ccrc_hdr):
             raise ValueError(
                 f"stripe bitmap selects {len(indices)} chunks but "
                 f"{len(ccrc_hdr)} CRCs were sent"
             )
+        # Verified before the lock: the payload is this frame's own
+        # buffer, or a slice of the assembly no other frame writes.
         mv = memoryview(payload)
+        chunks = []
+        off = 0
+        for i, expect in zip(indices, ccrc_hdr):
+            size = min(csz, total - i * csz)
+            chunk = mv[off : off + size]
+            if len(chunk) != size:
+                raise ValueError("stripe payload shorter than its bitmap")
+            if zlib.crc32(chunk) != expect:
+                raise ValueError(f"stripe chunk {i} CRC mismatch")
+            chunks.append((i, expect, chunk))
+            off += size
+        if off != len(mv):
+            raise ValueError(
+                f"stripe payload has {len(mv) - off} trailing bytes"
+            )
         with asm.lock:
-            off = 0
-            for i, expect in zip(indices, ccrc_hdr):
-                size = min(csz, total - i * csz)
-                chunk = mv[off : off + size]
-                if len(chunk) != size:
-                    raise ValueError("stripe payload shorter than its bitmap")
-                if zlib.crc32(chunk) != expect:
-                    raise ValueError(f"stripe chunk {i} CRC mismatch")
-                asm.buf[i * csz : i * csz + size] = chunk
+            if not in_place and any(i in asm.landing for i, _, _ in chunks):
+                # A second frame for a chunk still being read in place:
+                # placing it would expose that slice to the sink before
+                # the first frame's bytes are verified.
+                raise _StripeReject(
+                    "stripe chunk sent twice while read in place"
+                )
+            for i, expect, chunk in chunks:
+                if in_place:
+                    asm.landing.discard(i)
+                else:
+                    asm.buf[i * csz : i * csz + len(chunk)] = chunk
                 asm.ccrc[i] = expect
                 asm.have.add(i)
-                off += size
-            if off != len(mv):
-                raise ValueError(
-                    f"stripe payload has {len(mv) - off} trailing bytes"
-                )
+            if in_place:
+                asm.inplace_frames += 1
+                asm.inplace_bytes += len(mv)
             asm.frames += 1
             asm.shipped += len(mv)
             asm.read_s += read_seconds
@@ -1158,6 +1295,13 @@ def _apply_stripe_frame(
             if complete and not asm.is_delta and len(asm.have) != nch:
                 raise ValueError(
                     f"stripe group complete with {len(asm.have)}/{nch} chunks"
+                )
+            if complete and asm.landing:
+                # Only a repeated frame can count a group out while a
+                # chunk is still being read into it: delivering ``buf``
+                # now would let that read write into the delivered bytes.
+                raise ValueError(
+                    "stripe group complete while a chunk is read in place"
                 )
             feed_to = 0
             if not asm.is_delta:
@@ -1195,10 +1339,18 @@ def _apply_stripe_frame(
             del server._stripes[key]
     if stm is not None:
         server._store_delta_base(
-            src, stm, asm.buf, asm.ccrc, wire.crc_fingerprint(asm.ccrc)
+            f.src, stm, asm.buf, asm.ccrc, wire.crc_fingerprint(asm.ccrc)
         )
     server.stats["receive_stripe_frames"] = (
         server.stats.get("receive_stripe_frames", 0) + asm.frames
+    )
+    server.stats["receive_stripe_inplace_frames"] = (
+        server.stats.get("receive_stripe_inplace_frames", 0)
+        + asm.inplace_frames
+    )
+    server.stats["receive_stripe_inplace_bytes"] = (
+        server.stats.get("receive_stripe_inplace_bytes", 0)
+        + asm.inplace_bytes
     )
     server.stats["receive_striped_payloads"] = (
         server.stats.get("receive_striped_payloads", 0) + 1
@@ -1214,15 +1366,17 @@ def _apply_stripe_frame(
     _tr = telemetry.active()
     if _tr is not None:
         # Multi-rail reassembly completed: one record per striped
-        # payload with how many cross-rail frames built it and how many
+        # payload with how many cross-rail frames built it, how many
         # bytes actually crossed the wire (delta stripes overlay a
-        # cached base).  Ring append only — may run on the loop.
+        # cached base) and how many of them were read in place.  Ring
+        # append only — may run on the loop.
         _tr.emit(
-            "wire.reassemble", party=server._party, peer=src,
+            "wire.reassemble", party=server._party, peer=f.src,
             stream=stm, nbytes=total,
             t_start=time.time() - asm.read_s, dur_s=asm.read_s,
             detail={
                 "frames": asm.frames, "shipped_bytes": asm.shipped,
+                "in_place_bytes": asm.inplace_bytes,
                 "delta": bool(asm.is_delta),
             },
         )

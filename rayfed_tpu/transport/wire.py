@@ -104,9 +104,12 @@ DELTA_CHUNK_BYTES = 4 * 1024 * 1024
 # chunk k is on a socket while chunk k+1 is still being fetched from
 # device and CRC'd — no full-payload serialization barrier — and the
 # receiver reassembles by (stream, chunk index) with the delta-bitmap
-# machinery.  Below it — or when fewer than 2 rails are available
+# machinery.  The receiver reads each whole chunk straight into its
+# slice of the reassembly buffer and verifies it there; only a chunk
+# under its off-loop read size (a payload's short last chunk) is copied.
+# Below it — or when fewer than 2 rails are available
 # (client._default_stripe_rails: striping needs spare cores to pay for
-# the per-frame ACKs and the receiver's reassembly memcpy) — the
+# the per-frame ACKs and the receiver's per-chunk CRC pass) — the
 # single-frame paths (cheaper per-payload header/ACK overhead, zero-
 # copy delivery) are kept.
 STRIPE_MIN_BYTES = 8 * 1024 * 1024

@@ -1,11 +1,13 @@
 """Multi-rail striped send path + wire v4 handshake (PR 5).
 
 Covers: stripe reassembly under shuffled cross-rail arrival (and the
-contiguous-verified-prefix sink feed), rail death mid-payload (clean
-unit-of-payload failure + retry), delta-stream × multi-rail composition,
-the connection HELLO version negotiation, the runtime-mutable message
-cap, loud reporting of ignored transport options, send-arena reuse, and
-byte-identity of streamed aggregation with striping forced on.
+contiguous-verified-prefix sink feed), frames read in place into their
+assembly (and refused frames that never are), rail death mid-payload
+(clean unit-of-payload failure + retry), delta-stream × multi-rail
+composition, the connection HELLO version negotiation, the
+runtime-mutable message cap, loud reporting of ignored transport
+options, send-arena reuse, and byte-identity of streamed aggregation
+with striping forced on.
 
 All tests are in-process (real loopback sockets, toy payloads) — no
 party subprocesses, per the ROADMAP tier-1 budget note.
@@ -29,7 +31,11 @@ from rayfed_tpu.transport.client import (
 )
 from rayfed_tpu.transport.manager import TransportManager
 from rayfed_tpu.transport.rendezvous import Mailbox
-from rayfed_tpu.transport.server import TransportServer, _apply_stripe_frame
+from rayfed_tpu.transport.server import (
+    TransportServer,
+    _apply_stripe_frame,
+    _land_stripe_frame,
+)
 from tests.multiproc import get_free_ports
 
 
@@ -291,6 +297,282 @@ def test_all_seg_stripe_group_is_not_a_delivery():
         loop.close()
 
 
+def _read_in_place(server, header, chunk):
+    """Read one frame as the raw-read path does: into its chunk's slice
+    of the assembly where ``_land_stripe_frame`` grants one.  Returns
+    ``(landed, payload)`` for ``_apply_stripe_frame``."""
+    landed = _land_stripe_frame(server, header, len(chunk))
+    if landed is None:
+        return None, chunk
+    landed[1][:] = chunk
+    return landed, landed[1]
+
+
+def test_in_place_shuffled_arrival_reassembles_and_feeds_verified_prefix():
+    """Every frame read into its assembly before any is verified, then
+    applied in adversarial order: the payload reassembles exactly, no
+    chunk is copied, and the sink sees only the verified prefix though
+    unverified chunks already lie in the buffer.  A second frame for a
+    chunk already reserved or placed is read into a buffer of its own."""
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    data = np.random.default_rng(21).bytes(3 * csz + 777)
+    sink = _RecordingSink()
+    server.register_chunk_sink(("u1", "0"), sink)
+    frames = _stripe_frames(data, sid=1)
+    read = [_read_in_place(server, h, c) for h, c in frames]
+    assert all(landed is not None for landed, _ in read)
+    asm = read[0][0][0]
+    assert all(landed[0] is asm for landed, _ in read)
+    assert asm.landing == {0, 1, 2, 3}
+    assert _land_stripe_frame(server, frames[1][0], len(frames[1][1])) is None
+    final = None
+    for pos in (3, 1, 0, 2):
+        out, _ = _apply_stripe_frame(
+            server, frames[pos][0], read[pos][1], 0.0, read[pos][0]
+        )
+        final = out if out is not None else final
+        if pos == 0:
+            assert _land_stripe_frame(
+                server, frames[0][0], len(frames[0][1])
+            ) is None
+    assert final is asm.buf and bytes(final) == data
+    assert not asm.landing and not server._stripes
+    assert server.stats["receive_stripe_inplace_frames"] == 4
+    assert server.stats["receive_stripe_inplace_bytes"] == len(data)
+    assert [total for _, total in sink.feeds] == [2 * csz]
+    for fed, total in sink.feeds:
+        assert fed == data[:total]
+
+
+def test_in_place_crc_mismatch_kills_assembly_and_retry_delivers():
+    """A corrupt chunk read in place fails the frame and drops the whole
+    assembly with its buffer; the sink never sees the chunk, and the
+    retry under a fresh sid lands in a fresh assembly."""
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    data = np.random.default_rng(22).bytes(3 * csz)
+    sink = _RecordingSink()
+    server.register_chunk_sink(("u1", "0"), sink)
+    frames = _stripe_frames(data, sid=1)
+    landed, view = _read_in_place(server, *frames[0])
+    out, _ = _apply_stripe_frame(server, frames[0][0], view, 0.0, landed)
+    assert out is None
+    header, chunk = frames[1]
+    landed, view = _read_in_place(server, header, b"\x5a" * len(chunk))
+    assert landed is not None
+    dead = landed[0]
+    with pytest.raises(ValueError, match="CRC"):
+        _apply_stripe_frame(server, header, view, 0.0, landed)
+    assert not server._stripes
+    # The dead group's frames still in flight are refused, not landed.
+    header2, chunk2 = frames[2]
+    assert _land_stripe_frame(server, header2, len(chunk2)) is None
+    assert [total for _, total in sink.feeds] == [csz]
+    assert sink.feeds[0][0] == data[:csz]
+    retry = _stripe_frames(data, sid=2)
+    final = None
+    for header, chunk in retry:
+        landed, view = _read_in_place(server, header, chunk)
+        assert landed is not None and landed[0] is not dead
+        out, _ = _apply_stripe_frame(server, header, view, 0.0, landed)
+        final = out if out is not None else final
+    assert final is not None and bytes(final) == data
+    assert all(fed == data[:total] for fed, total in sink.feeds)
+
+
+@pytest.mark.parametrize(
+    "case", ["stale_sid", "evicted_group", "geometry", "oversize"]
+)
+def test_refused_frame_never_writes_a_live_assembly(case):
+    """A frame whose assembly cannot be used (a stale sid, an evicted
+    group, geometry that disagrees, a payload over the cap) is read into
+    a buffer of its own and fails as it always did: the live assembly's
+    buffer stays byte-for-byte as it was, also where a stale frame had
+    been granted its slice before the retry replaced its assembly."""
+    from rayfed_tpu.transport.server import _MAX_STRIPE_ASM, _StripeFatal
+
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    data = np.random.default_rng(23).bytes(2 * csz)
+    junk = b"\xa5" * csz
+    if case == "evicted_group":
+        group_a = _stripe_frames(data, sid=1, up="evict-a")
+        assert _apply_stripe_frame(server, *group_a[0], 0.0)[0] is None
+        for j in range(_MAX_STRIPE_ASM):
+            fill = _stripe_frames(data, sid=1, up=f"evict-fill{j}")
+            _apply_stripe_frame(server, *fill[0], 0.0)
+        assert ("alice", "evict-a", "0") not in server._stripes
+    old = _stripe_frames(data, sid=5)
+    early, early_view = _read_in_place(server, *old[1])
+    new = _stripe_frames(data, sid=6)
+    landed, view = _read_in_place(server, *new[0])
+    live = landed[0]
+    assert live is not early[0]
+    out, _ = _apply_stripe_frame(server, new[0][0], view, 0.0, landed)
+    assert out is None
+    before = bytes(live.buf)
+    early_view[:] = junk  # the stale frame's bytes arrive after all
+    if case == "stale_sid":
+        refused = [(old[1][0], early, early_view), (old[0][0], None, junk)]
+        exc, match = ValueError, "stale"
+    elif case == "evicted_group":
+        refused = [(group_a[1][0], None, junk)]
+        exc, match = ValueError, "dropped under memory pressure"
+    elif case == "geometry":
+        header = dict(new[1][0], stp=wire.make_stripe_marker(6, 3))
+        refused = [(header, None, junk)]
+        exc, match = ValueError, "geometry"
+    else:
+        server._max_message_size = 3 * csz
+        big = _stripe_frames(b"\x00" * (4 * csz), sid=1, up="big")
+        refused = [(big[0][0], None, junk)]
+        exc, match = _StripeFatal, "exceeds max"
+    made = set(server._stripes)
+    for header, grant, payload in refused:
+        assert _land_stripe_frame(server, header, len(payload)) is None
+        with pytest.raises(exc, match=match):
+            _apply_stripe_frame(server, header, payload, 0.0, grant)
+    assert bytes(live.buf) == before
+    assert set(server._stripes) <= made
+
+
+def test_second_frame_for_a_chunk_read_in_place_is_refused():
+    """A second frame naming a chunk that another frame is still reading
+    in place is refused and kills the assembly: it is never placed, so
+    the sink never sees that slice before its own frame is verified."""
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    data = np.random.default_rng(26).bytes(2 * csz)
+    sink = _RecordingSink()
+    server.register_chunk_sink(("u1", "0"), sink)
+    frames = _stripe_frames(data, sid=1)
+    landed = _land_stripe_frame(server, frames[0][0], len(frames[0][1]))
+    assert landed is not None
+    landed[1][: csz // 2] = frames[0][1][: csz // 2]  # read half-way
+    before = bytes(landed[0].buf)
+    with pytest.raises(ValueError, match="sent twice"):
+        _apply_stripe_frame(server, *frames[0], 0.0)
+    assert bytes(landed[0].buf) == before
+    assert 0 not in landed[0].have
+    assert not server._stripes and not sink.feeds
+
+
+def test_in_place_frames_of_many_payloads_on_many_threads():
+    """Stress: the frames of four payloads, shuffled, land and verify on
+    more threads than cores with a short switch interval.  A lost update
+    of the reservation, the chunk set or the counters, or a write into
+    another frame's slice, shows as a payload that never completes or
+    reassembles wrong."""
+    import queue
+    import sys
+    import threading
+
+    server = _mk_server()
+    csz = 64 * 1024
+    rng = np.random.default_rng(25)
+    datas = {f"s{k}": rng.bytes(48 * csz + 999) for k in range(4)}
+    work = queue.Queue()
+    frames = [
+        f for up, d in datas.items()
+        for f in _stripe_frames(d, sid=1, csz=csz, up=up)
+    ]
+    for pos in rng.permutation(len(frames)):
+        work.put(frames[pos])
+    finals, errors = {}, []
+
+    def worker():
+        while True:
+            try:
+                header, chunk = work.get_nowait()
+            except queue.Empty:
+                return
+            try:
+                landed, view = _read_in_place(server, header, chunk)
+                out, _ = _apply_stripe_frame(server, header, view, 0.0, landed)
+                if out is not None:
+                    finals[header["up"]] = bytes(out)
+            except Exception as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
+    assert finals == datas
+    assert not server._stripes
+    assert server.stats["receive_stripe_inplace_frames"] == len(frames)
+    assert server.stats["receive_stripe_inplace_bytes"] == sum(
+        len(d) for d in datas.values()
+    )
+
+
+def test_delta_stripe_frame_lands_in_place_over_copied_base():
+    """A delta stripe frame's chunk is read into the assembly that
+    copied the cached base: the changed chunks land in place, the
+    others are the base's, and the rebuilt payload is the new base."""
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    base = bytearray(np.random.default_rng(24).bytes(3 * csz))
+    ccrc = wire.chunk_crcs(base)
+    fp = wire.crc_fingerprint(ccrc)
+    server._store_delta_base("alice", "s", base, ccrc, fp)
+    new = bytearray(base)
+    new[2 * csz + 17 : 2 * csz + 21] = b"LAND"
+    new[9] ^= 0xFF
+    frames = _stripe_frames(
+        bytes(new), sid=1, stream="s", base_fp=fp, indices=[2, 0]
+    )
+    final = None
+    for header, chunk in frames:
+        landed, view = _read_in_place(server, header, chunk)
+        assert landed is not None
+        out, _ = _apply_stripe_frame(server, header, view, 0.0, landed)
+        final = out if out is not None else final
+    assert final is not None and bytes(final) == bytes(new)
+    assert bytes(server._get_delta_base("alice", "s")["data"]) == bytes(new)
+    assert bytes(base) != bytes(new)  # the cached base was copied, not written
+    assert server.stats["receive_stripe_inplace_bytes"] == 2 * csz
+
+
+def test_repeated_frame_cannot_complete_a_group_read_in_place():
+    """A delta group counted out by a repeated frame while another of
+    its chunks is still being read in place fails instead of handing up
+    a buffer that read would go on writing into; the late frame is then
+    refused like any frame of a dead group."""
+    server = _mk_server()
+    csz = wire.DELTA_CHUNK_BYTES
+    base = bytearray(np.random.default_rng(27).bytes(3 * csz))
+    ccrc = wire.chunk_crcs(base)
+    fp = wire.crc_fingerprint(ccrc)
+    server._store_delta_base("alice", "s", base, ccrc, fp)
+    new = bytearray(base)
+    new[5] ^= 0xFF
+    new[2 * csz + 5] ^= 0xFF
+    frames = _stripe_frames(
+        bytes(new), sid=1, stream="s", base_fp=fp, indices=[0, 2]
+    )
+    landed = _land_stripe_frame(server, frames[0][0], len(frames[0][1]))
+    assert landed is not None
+    assert _apply_stripe_frame(server, *frames[1], 0.0)[0] is None
+    with pytest.raises(ValueError, match="read in place"):
+        _apply_stripe_frame(server, *frames[1], 0.0)
+    assert not server._stripes
+    landed[1][:] = frames[0][1]
+    with pytest.raises(ValueError, match="dropped"):
+        _apply_stripe_frame(server, frames[0][0], landed[1], 0.0, landed)
+    assert bytes(server._get_delta_base("alice", "s")["data"]) == bytes(base)
+    assert "receive_striped_payloads" not in server.stats
+
+
 # ---------------------------------------------------------------------------
 # End-to-end over real sockets
 # ---------------------------------------------------------------------------
@@ -314,6 +596,30 @@ def test_striped_send_roundtrip_and_stats(manager_pair):
     assert bk["socket_ms"] > 0
     bs = b.get_stats()
     assert bs["receive_striped_payloads"] >= 1
+
+
+def test_striped_send_lands_raw_read_frames_in_place(manager_pair):
+    """Over real sockets every stripe frame the receiver reads off-loop
+    (at least ``_RAW_READ_BYTES``) lands in its assembly: the in-place
+    bytes are the payload less its short last chunk, and the delivered
+    bytes are the sent ones."""
+    from rayfed_tpu.transport.server import _RAW_READ_BYTES
+
+    a, b, _ = manager_pair
+    x = _striped_payload(seed=18)
+    assert a.send("bob", x, "ip1", "0").resolve(timeout=60)
+    got = b.recv("alice", "ip1", "0").resolve(timeout=60)
+    np.testing.assert_array_equal(got, x)
+    st = b.get_stats()
+    assert st["receive_striped_payloads"] == 1
+    total = st["receive_bytes"]
+    csz = wire.DELTA_CHUNK_BYTES
+    sizes = [min(csz, total - i * csz) for i in range(-(-total // csz))]
+    big = [s for s in sizes if s >= _RAW_READ_BYTES]
+    assert len(big) == len(sizes) - 1  # a short last chunk, read on the loop
+    assert st["receive_stripe_inplace_frames"] == len(big)
+    assert st["receive_stripe_inplace_bytes"] == sum(big)
+    assert st["receive_stripe_frames"] == len(sizes)
 
 
 def test_rail_death_mid_payload_clean_retry(manager_pair, monkeypatch):
